@@ -1,5 +1,5 @@
 """Dict-loop vs batched-executor CT communication phase, plus the
-bucket-merge / fused-epilogue accounting.
+bucket-merge accounting.
 
 The repo's first multi-grid throughput number: for each scheme, time
 
@@ -13,25 +13,19 @@ The repo's first multi-grid throughput number: for each scheme, time
 Both paths produce the sparse-grid surplus on the common fine grid; the
 benchmark asserts they agree to 1e-12 before timing.
 
-The second table prices the PR-4 levers on the batched path itself:
-
-  * merged vs unmerged — ``build_plan(..., merge=MergeConfig())``:
-    launch counts (plan-derived AND the dispatches actually traced,
-    ``repro.kernels.hierarchize.count_launches``) with the cost-model
-    partition against the exact-canonical one;
-  * fused vs unfused — the scatter-add epilogue: plan-derived
-    gather-phase HBM bytes (the compact-surplus round trip the fused
-    kernels eliminate) and, when XLA reports it, the compiled peak temp
-    bytes (``memory_analysis``).  NOTE on the CPU container the Pallas
-    kernels run in interpret mode, so the compiled peak includes the
-    emulation's staging buffers and CPU wall time prices dispatches at
-    CPU (not TPU) cost — the plan-derived bytes/launches are the tracked
-    metrics, the TPU run is the ROADMAP "TPU validation" item;
-  * every variant is asserted against the unmerged unfused path before
-    timing (eager execution is bit-identical — pinned by
-    ``tests/test_merge_plan.py``; compiled graphs are held to 1e-12 since
-    XLA may FMA a scatter combiner, and the observed bitwise fraction is
-    recorded).
+The second table prices bucket merging on the batched path itself
+(``build_plan(..., merge=MergeConfig())``): launch counts (plan-derived
+AND the dispatches actually traced,
+``repro.kernels.hierarchize.count_launches``) and gather-phase HBM bytes
+with the cost-model partition against the exact-canonical one, plus the
+compiled peak temp bytes when XLA reports them (``memory_analysis``).
+The merged path is asserted against the unmerged one before timing
+(eager execution is bit-identical — pinned by
+``tests/test_merge_plan.py``; compiled graphs are held to 1e-12 since XLA
+may FMA a scatter combiner, and the observed bitwise fraction is
+recorded).  Off the TPU the Pallas kernels run in interpret mode, so
+times and peaks here price the CPU, not the chip: the plan-derived
+bytes/launches are the tracked metrics.
 
 Emits machine-readable ``BENCH_executor_batched.json`` and
 ``BENCH_bucket_merge.json`` next to the tables (``--json-out`` /
@@ -68,9 +62,9 @@ from repro.kernels.ops import hierarchize  # noqa: E402
 # at (d=10, n=3) is 7^10 = 282M points, beyond any embedded representation
 SCHEMES = [(2, 5), (2, 7), (4, 4), (4, 5), (10, 2)]
 
-# merge/fuse table: the d=10 wide diagonal is the launch-bound shape the
-# merge planner exists for; the near-square d=2 set keeps every bucket on
-# the Pallas path, so the fused epilogue engages end to end
+# merge table: the d=10 wide diagonal is the launch-bound shape the
+# merge planner exists for; the near-square d=2 set keeps most of its
+# volume on the Pallas path
 MERGE_SCHEMES = [
     ("d=10 n=2", CombinationScheme(10, 2)),
     ("d=4 n=4", CombinationScheme(4, 4)),
@@ -113,45 +107,35 @@ def bench_merge(reps, json_out):
         grids = {ell: jnp.asarray(rng.standard_normal(grid_shape(ell)))
                  for ell, _ in scheme.grids}
 
-        f_base = jax.jit(lambda g: ct_transform_with_plan(g, plain,
-                                                          fused=False))
-        f_fused = jax.jit(lambda g: ct_transform_with_plan(g, plain))
+        f_base = jax.jit(lambda g: ct_transform_with_plan(g, plain))
         f_merged = jax.jit(lambda g: ct_transform_with_plan(g, merged))
-        # eager results are bit-identical across all variants (pinned by
+        # eager results are bit-identical (pinned by
         # tests/test_merge_plan.py); under jit XLA may fuse a scatter
         # combiner (observed: one FMA'd slot, 1 ulp), so the compiled
         # paths are held to 1e-12 and the bitwise fraction is recorded
         want = np.asarray(f_base(grids))
-        got_fused = np.asarray(f_fused(grids))
         got_merged = np.asarray(f_merged(grids))
-        np.testing.assert_allclose(got_fused, want, rtol=1e-12, atol=1e-12)
         np.testing.assert_allclose(got_merged, want, rtol=1e-12, atol=1e-12)
-        err = max(float(np.max(np.abs(got_fused - want))),
-                  float(np.max(np.abs(got_merged - want))))
-        bitwise = bool((got_fused == want).all() and
-                       (got_merged == want).all())
+        err = float(np.max(np.abs(got_merged - want)))
+        bitwise = bool((got_merged == want).all())
 
         s_plain = plan_launch_stats(plain)
         s_merged = plan_launch_stats(merged)
-        s_plain_unf = plan_launch_stats(plain, fused=False)
-        s_merged_unf = plan_launch_stats(merged, fused=False)
         traced_plain = _traced_launches(plain, grids)
         traced_merged = _traced_launches(merged, grids)
         t_base = time_call(f_base, grids, reps=reps)
-        t_fused = time_call(f_fused, grids, reps=reps)
         t_merged = time_call(f_merged, grids, reps=reps)
-        peak_unf = peak_temp_bytes(f_base, grids)
-        peak_fused = peak_temp_bytes(f_fused, grids)
+        peak_base = peak_temp_bytes(f_base, grids)
         peak_merged = peak_temp_bytes(f_merged, grids)
 
-        fmt_peak = (f"{(peak_unf or 0) / 2**20:>6.2f}"
+        fmt_peak = (f"{(peak_base or 0) / 2**20:>6.2f}"
                     f"->{(peak_merged or 0) / 2**20:<6.2f}"
-                    if peak_unf is not None else f"{'n/a':>13}")
+                    if peak_base is not None else f"{'n/a':>13}")
         print(f"{name:>8} {plain.num_grids:>6} "
               f"{len(plain.buckets):>3}->{len(merged.buckets):<4} "
               f"{s_plain['launches']:>6}->{s_merged['launches']:<6} "
-              f"{s_plain_unf['stack_bytes'] / 1024:>6.1f}"
-              f"->{s_plain['stack_bytes'] / 1024:<6.1f} "
+              f"{s_plain['stack_bytes'] / 1024:>6.1f}"
+              f"->{s_merged['stack_bytes'] / 1024:<6.1f} "
               f"{fmt_peak} {t_base * 1e3:>8.2f} {t_merged * 1e3:>10.2f}")
         rows.append({
             "scheme": name, "grids": plain.num_grids,
@@ -162,23 +146,18 @@ def bench_merge(reps, json_out):
             "launches_traced_unmerged": traced_plain,
             "launches_traced_merged": traced_merged,
             "launch_ratio": s_plain["launches"] / s_merged["launches"],
-            "stack_bytes_unfused": s_plain_unf["stack_bytes"],
-            "stack_bytes_fused": s_plain["stack_bytes"],
-            "stack_bytes_merged_unfused": s_merged_unf["stack_bytes"],
-            "stack_bytes_merged_fused": s_merged["stack_bytes"],
+            "stack_bytes_unmerged": s_plain["stack_bytes"],
+            "stack_bytes_merged": s_merged["stack_bytes"],
             "transform_bytes_unmerged": s_plain["transform_bytes"],
             "transform_bytes_merged": s_merged["transform_bytes"],
-            "compiled_peak_temp_bytes_unfused": peak_unf,
-            "compiled_peak_temp_bytes_fused": peak_fused,
+            "compiled_peak_temp_bytes_unmerged": peak_base,
             "compiled_peak_temp_bytes_merged": peak_merged,
-            "unmerged_unfused_s": t_base, "unmerged_fused_s": t_fused,
-            "merged_fused_s": t_merged, "max_abs_err": err,
+            "unmerged_s": t_base, "merged_s": t_merged,
+            "max_abs_err": err,
             "bitwise_equal_compiled": bitwise,
         })
     wide = next(r for r in rows if r["scheme"] == "d=10 n=2")
     assert wide["launches_unmerged"] >= 2 * wide["launches_merged"], wide
-    sq = next(r for r in rows if r["scheme"] == "sq d=2")
-    assert sq["stack_bytes_fused"] == 0 < sq["stack_bytes_unfused"], sq
     if json_out:
         payload = {"bench": "bucket_merge", "reps": reps,
                    "backend": jax.default_backend(), "rows": rows}
@@ -195,7 +174,7 @@ def main(argv=None):
     ap.add_argument("--merge-json-out", default="BENCH_bucket_merge.json",
                     help="bucket-merge results path ('' disables)")
     ap.add_argument("--skip-dict", action="store_true",
-                    help="only run the merge/fuse table")
+                    help="only run the merge table")
     args = ap.parse_args(argv)
     if args.skip_dict:
         bench_merge(args.reps, args.merge_json_out)
@@ -238,4 +217,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

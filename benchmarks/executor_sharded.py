@@ -54,7 +54,7 @@ jax.config.update("jax_enable_x64", True)
 
 from common import peak_temp_bytes, time_call  # noqa: E402
 
-from repro.compat import AxisType, make_mesh  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 from repro.core.distributed import (ct_transform_psum,  # noqa: E402
                                     ct_transform_sharded)
 from repro.core.executor import (build_plan, ct_transform,  # noqa: E402
@@ -71,14 +71,14 @@ DTYPE = np.float64
 
 
 def _mesh(n):
-    return make_mesh((n,), ("slab",), devices=np.array(jax.devices()[:n]),
-                     axis_types=(AxisType.Auto,))
+    return jax.make_mesh((n,), ("slab",), devices=np.array(jax.devices()[:n]),
+                         axis_types=(AxisType.Auto,))
 
 
 def _mesh2d(m, s):
-    return make_mesh((m, s), ("member", "slab"),
-                     devices=np.array(jax.devices()[:m * s]),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((m, s), ("member", "slab"),
+                         devices=np.array(jax.devices()[:m * s]),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def main(argv=None):
@@ -215,4 +215,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

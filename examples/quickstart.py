@@ -44,8 +44,7 @@ def main():
     nodal_g = {ell: sample_function(g, ell) for ell, _ in scheme.grids}
 
     # --- one ExecSpec drives every execution knob (all defaults here:
-    #     no merging, single device, auto-fused epilogue, backend-default
-    #     interpret mode) ---
+    #     no merging, single device, backend-default interpret mode) ---
     spec = ExecSpec()
     full = ct_transform(nodal_f, scheme, spec=spec)
     print(f"combined surplus buffer: {full.shape}")

@@ -240,7 +240,6 @@ BIT_CRITICAL_FUNC_PREFIXES = (
     "gather_slab_scatter",   # core/distributed.py slab scatter family
     "_finish_slab_gather",
     "_gather_one_bucket",
-    "hier_axis0_scatter",
     "_scatter_surplus",
 )
 

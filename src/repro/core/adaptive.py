@@ -148,7 +148,6 @@ class AdaptiveDriver:
             self.config = _dc.replace(self.config, merge=spec.merge,
                                       interpret=spec.interpret)
         self.spec = spec
-        self._fused = spec.fused if spec is not None else None
         self.solver = solver
         self.scheme = initial
         self._nodal: Dict[LevelVector, jnp.ndarray] = {}
@@ -179,8 +178,7 @@ class AdaptiveDriver:
 
     def _retransform(self) -> None:
         self._surplus = ct_transform_with_plan(
-            self._nodal, self.plan, interpret=self.config.interpret,
-            fused=self._fused)
+            self._nodal, self.plan, interpret=self.config.interpret)
         self._surplus_host = None        # host copy invalidated
 
     # --- scoring ---

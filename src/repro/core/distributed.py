@@ -8,7 +8,7 @@ Parallelism layers (DESIGN.md Sect. 4):
   * within a grid — pole-parallel hierarchization: sharding any non-working
     axis needs NO communication; only the transform along the sharded axis
     itself communicates.  ``hierarchize_sharded`` shards axis 0, runs the
-    fused tail transform locally and realizes the axis-0 transform as
+    tail transform locally and realizes the axis-0 transform as
     (local operator rows) @ (all-gathered poles) — one all-gather of the
     grid per full d-dimensional hierarchization.
   * the communication phase — in the hierarchical basis the gather step is
@@ -28,12 +28,7 @@ Parallelism layers (DESIGN.md Sect. 4):
       sharded consumers).  Per-device embedded memory is
       ``ceil(fine_shape[0] / n) * row_size`` — memory scales with device
       count; only the compact surpluses (the scheme's point count) are
-      replicated.  When every bucket runs the Pallas path,
-      ``gather_slab_scatter_fused`` consumes the executor's fused
-      scatter-add epilogue instead: only the TAIL-transformed stacks are
-      replicated and each device's axis-0 transform + coefficient
-      weighting + scatter-add run in one kernel against its slab-LOCAL
-      index map (the finished compact surpluses never land in HBM).
+      replicated.
     - 2-D (member x slab) mesh (``gather_slab_scatter_2d``): the
       hierarchization ITSELF is sharded too.  The mesh's two axes play
       different roles — flattening them member-major yields
@@ -68,10 +63,6 @@ Surplus shipping contract of the 2-D path (the flat realization of the
     hierarchize + all_to_all + all_gather BEFORE bucket ``b``'s
     scatter-add in program order, so the collectives overlap with the
     scatter work instead of serializing in front of it.
-  * the fused scatter epilogue cannot apply here (shipping sits between
-    the axis-0 transform and the scatter), so the 2-D path is unfused by
-    construction; its win is compute/memory scaling, not stack-HBM
-    avoidance.
 
 Slab partitioning invariants (``repro.core.executor.ShardedPlan``):
 
@@ -106,16 +97,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.levels import (LevelVector, SchemeLike, fine_levels,
                                num_points)
 from repro.kernels.hierarchize import _padded_operator  # shared constant builder
-from repro.kernels.hierarchize import hier_axis0_scatter_batched_pallas
-from repro.kernels.ops import hierarchize as hier_local
 
 __all__ = ["plan_grid_groups", "hierarchize_sharded", "gather_full_psum",
-           "gather_slab_scatter", "gather_slab_scatter_fused",
-           "gather_slab_scatter_2d", "comm_phase_sharded",
+           "gather_slab_scatter", "gather_slab_scatter_2d", "comm_phase_sharded",
            "ct_transform_psum", "ct_transform_sharded"]
 
 
@@ -148,7 +135,7 @@ def hierarchize_sharded(x_padded: jnp.ndarray, level0: int, mesh: Mesh,
     replicated.
 
     Communication: exactly one all-gather of the array (the axis-0
-    transform); the tail axes are transformed locally (fused kernel path).
+    transform); the tail axes are transformed locally.
     """
     n0p = x_padded.shape[0]
     assert n0p == 1 << level0, "axis 0 must be padded to 2**level0"
@@ -177,8 +164,8 @@ def hierarchize_sharded(x_padded: jnp.ndarray, level0: int, mesh: Mesh,
         return x_loc
 
     spec = P(axis_name, *([None] * (x_padded.ndim - 1)))
-    fn = shard_map(partial(local_fn, hmat), mesh=mesh,
-                   in_specs=(spec,), out_specs=spec, check_vma=False)
+    fn = jax.shard_map(partial(local_fn, hmat), mesh=mesh,
+                       in_specs=(spec,), out_specs=spec, check_vma=False)
     return fn(x_padded)
 
 
@@ -199,9 +186,9 @@ def gather_full_psum(embedded: jnp.ndarray, coeff: jnp.ndarray, mesh: Mesh,
         return jax.lax.psum(contrib, axis_name)
 
     in_specs = (P(axis_name, *([None] * (embedded.ndim - 1))), P(axis_name))
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(*([None] * (embedded.ndim - 1))),
-                   check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(*([None] * (embedded.ndim - 1))),
+                       check_vma=False)
     return fn(embedded, coeff)
 
 
@@ -287,70 +274,9 @@ def gather_slab_scatter(alphas, sharded_plan, mesh: Mesh, axis_name: str, *,
     in_specs = tuple([P(axis_name, None, None)] * nb
                      + [rep2] * nb + [rep1] * nb)
     out_specs = P(None) if gather else P(axis_name, None)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out = fn(*idx, *alphas, *coeffs)
-    return _finish_slab_gather(out, splan, mesh, axis_name, gather)
-
-
-def gather_slab_scatter_fused(tails, sharded_plan, mesh: Mesh,
-                              axis_name: str, *, gather: bool = True,
-                              interpret: bool | None = None,
-                              idx_arrays=None,
-                              coeff_arrays=None) -> jnp.ndarray:
-    """Slab-sharded gather with the FUSED scatter-add epilogue: consumes
-    per-bucket TAIL-transformed stacks (``repro.core.executor.
-    bucket_tail_surpluses``, axis 0 still nodal, replicated) and runs the
-    axis-0 transform + coefficient weighting + scatter-add in ONE kernel
-    per bucket per device, writing straight into the device's
-    ``slab_size + 1`` buffer through its slab-LOCAL index map — the same
-    epilogue as the single-device fused gather, just pointed at per-slab
-    maps; the compact surplus stack never lands in HBM here either.
-
-    Per fine slot the adds happen in member order starting from the zero
-    slab buffer (the same left fold as ``gather_slab_scatter``), so the
-    result is BIT-identical to the unfused sharded gather and to the
-    single-device ``ct_transform``.  Same ``gather`` semantics as
-    ``gather_slab_scatter``.
-    """
-    splan = sharded_plan
-    _check_slab_gather_args(splan, mesh, axis_name, len(tails),
-                            "tail-surplus")
-    nb = len(tails)
-    dtype = jnp.result_type(*(t.dtype for t in tails))
-    slab_size = splan.slab_size
-    # slab-local maps in the (G, N0, B) layout of the tail stacks;
-    # idx_arrays/coeff_arrays as in gather_slab_scatter (traced overrides)
-    idx = [jnp.asarray(a).reshape((splan.n_slabs,) + t.shape)
-           for a, t in zip(
-               idx_arrays if idx_arrays is not None
-               else [sb.index for sb in splan.slab_buckets], tails)]
-    coeffs = [jnp.asarray(c).astype(dtype) for c in (
-        coeff_arrays if coeff_arrays is not None
-        else [b.coeffs for b in splan.plan.buckets])]
-    levels0 = [tuple(lv[0] for lv in b.levels) for b in splan.plan.buckets]
-
-    def local_fn(*args):
-        idx_loc = args[:nb]              # (1, G, N0, B) — this device's slab
-        tail = args[nb:2 * nb]           # (G, N0, B) replicated tail stacks
-        cs = args[2 * nb:]               # (G,) replicated coefficients
-        buf = jnp.zeros(slab_size + 1, dtype)       # +1: dump slot
-        for i in range(nb):
-            buf = hier_axis0_scatter_batched_pallas(
-                tail[i], levels0[i], cs[i], idx_loc[i][0], buf,
-                interpret=interpret)
-        buf = buf[:slab_size]
-        if gather:
-            return jax.lax.all_gather(buf, axis_name, tiled=True)
-        return buf[None]
-
-    rep3, rep1 = P(None, None, None), P(None)
-    in_specs = tuple([P(axis_name, None, None, None)] * nb
-                     + [rep3] * nb + [rep1] * nb)
-    out_specs = P(None) if gather else P(axis_name, None)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
-    out = fn(*idx, *tails, *coeffs)
     return _finish_slab_gather(out, splan, mesh, axis_name, gather)
 
 
@@ -367,8 +293,8 @@ def gather_slab_scatter_2d(stacks, sharded_plan, mesh: Mesh,
     ``m * n_slabs + s`` (member-major mesh flattening):
 
     1. batched hierarchization of ONLY its contiguous member shard
-       (``hierarchize_batched_data`` — the per-member predecessor data
-       rides along as G-sharded arrays), coefficients applied at the
+       (``hierarchize_batched`` — the member level table rides along as
+       a G-sharded array), coefficients applied at the
        source;
     2. the surplus all-to-all: gather the per-destination-slab payloads
        through ``SlabBucket.ship_src``, one tiled ``all_to_all`` over
@@ -390,12 +316,10 @@ def gather_slab_scatter_2d(stacks, sharded_plan, mesh: Mesh,
     traced) ``(ship_src, ship_idx)`` pairs and ``coeff_arrays`` the
     coefficients — the signature-shared-executable hook, as in
     ``gather_slab_scatter``.  Same ``gather`` semantics as the 1-D
-    gathers.  The fused epilogue cannot apply here (shipping sits
-    between transform and scatter), so this path is unfused by
-    construction.
+    gathers.
     """
-    from repro.kernels.hierarchize import (hierarchize_batched_data,
-                                           member_pred_arrays)
+    from repro.kernels.hierarchize import (hierarchize_batched,
+                                           member_level_array)
     splan = sharded_plan
     nb = len(stacks)
     _check_slab_gather_args(splan, mesh, axis_name, nb, "nodal-stack")
@@ -428,36 +352,35 @@ def gather_slab_scatter_2d(stacks, sharded_plan, mesh: Mesh,
         else [b.coeffs for b in buckets])]
     gsizes = [sb.group_size for sb in splan.slab_buckets]
     shapes = [b.shape for b in buckets]
-    # per-member predecessor data, padded and G-sharded like the stacks;
+    # member level tables, padded and G-sharded like the stacks;
     # signature-determined (bucket levels), so baked as trace constants
-    preds = []
-    xs, cs = [], []
+    xs, cs, lvs = [], [], []
     for b, a, c, gs in zip(buckets, stacks, coeffs, gsizes):
         g, p = a.shape
         pad = n_groups * gs - g
         xs.append(jnp.pad(a, ((0, pad), (0, 0))))
         cs.append(jnp.pad(c.astype(dtype), (0, pad)))
-        # pad members get all-False masks -> their (zero) rows transform
-        # to zeros; their payload entries are never gathered anyway
-        preds.append(tuple(
-            jnp.asarray(np.pad(arr, ((0, pad), (0, 0))))
-            for arr in member_pred_arrays(b.levels, b.shape)))
-    npred = [len(pr) for pr in preds]
+        # pad members get level 0 (no real nodes) -> their (zero) rows
+        # pass through; their payload entries are never gathered anyway
+        lvs.append(jnp.asarray(np.pad(member_level_array(b.levels),
+                                      ((0, pad), (0, 0)))))
+
+    # one compiled program per bucket shape even when the gather runs
+    # eagerly (an eager shard_map otherwise dispatches the kernel body op
+    # by op); inlined as-is under an outer jit
+    transform = jax.jit(partial(hierarchize_batched, interpret=interpret))
 
     def local_fn(*args):
         src = args[:nb]                  # (1, S, L) this group's gathers
         dst = args[nb:2 * nb]            # (1, n_groups, L) this slab's map
         x = args[2 * nb:3 * nb]          # (gloc, P) this group's members
         cl = args[3 * nb:4 * nb]         # (gloc,) their coefficients
-        pred = args[4 * nb:]             # G-sharded predecessor data
-
-        off = np.cumsum([0] + npred)
+        lv = args[4 * nb:]               # (gloc, d) their level vectors
 
         def ship(i):
             gloc = x[i].shape[0]
             xg = x[i].reshape((gloc,) + shapes[i])
-            alpha = hierarchize_batched_data(
-                xg, pred[off[i]:off[i + 1]], interpret=interpret)
+            alpha = transform(xg, lv[i])
             w = cl[i][:, None] * alpha.reshape(gloc, -1).astype(dtype)
             flat = jnp.concatenate([w.reshape(-1),
                                     jnp.zeros((1,), dtype)])
@@ -485,11 +408,11 @@ def gather_slab_scatter_2d(stacks, sharded_plan, mesh: Mesh,
                      + [P(axis_name, None, None)] * nb  # ship_idx by slab
                      + [P(both, None)] * nb           # stacks by member rows
                      + [P(both)] * nb                 # coefficients
-                     + [P(both, None)] * sum(npred))  # predecessor data
+                     + [P(both, None)] * nb)          # level tables
     out_specs = P(None) if gather else P(axis_name, None)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
-    out = fn(*srcs, *dsts, *xs, *cs, *(a for pr in preds for a in pr))
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
+    out = fn(*srcs, *dsts, *xs, *cs, *lvs)
     return _finish_slab_gather(out, splan, mesh, axis_name, gather)
 
 
@@ -497,7 +420,6 @@ def ct_transform_sharded(nodal_grids, scheme: SchemeLike, mesh: Mesh,
                          axis_name: str, *,
                          full_levels: Sequence[int] | None = None,
                          plan=None, sharded_plan=None, gather: bool = True,
-                         fused: bool | None = None,
                          interpret: bool | None = None,
                          spec=None, member_axis: str | None = None
                          ) -> jnp.ndarray:
@@ -511,37 +433,25 @@ def ct_transform_sharded(nodal_grids, scheme: SchemeLike, mesh: Mesh,
     ``mesh.shape[axis_name]`` slabs.  ``gather=False`` returns the
     slab-sharded fine buffer (see ``gather_slab_scatter``).  ``spec``
     (a ``repro.core.engine.ExecSpec``) consolidates
-    ``fused``/``interpret``/``merge``; the bare ``fused=``/``interpret=``
-    kwargs and the old ``sharded_plan=`` spelling of ``plan=`` remain as
-    deprecation shims.
+    ``interpret``/``merge``; the bare ``interpret=`` kwarg and the old
+    ``sharded_plan=`` spelling of ``plan=`` remain as deprecation shims.
 
     ``member_axis`` (or ``spec.member_axis``) names the SECOND axis of a
     2-D (member x slab) mesh: the ingest then also compute-shards the
     hierarchization over ``members * slabs`` groups and routes through
-    ``gather_slab_scatter_2d`` (bit-identical; unfused by construction —
-    see the module notes).
-
-    ``fused=None`` picks the fused scatter-add epilogue automatically
-    when EVERY bucket runs the Pallas path and the per-device slab buffer
-    fits the epilogue's VMEM budget (``repro.core.executor.
-    plan_fused_ok``); then only the TAIL-transformed stacks are
-    replicated and the axis-0 transform + weighted scatter run fused on
-    each device.  Fused and unfused sharded gathers are bit-identical.
+    ``gather_slab_scatter_2d`` (bit-identical; see the module notes).
     """
     from repro.core.executor import (build_plan, bucket_nodal_stacks,
-                                     bucket_surpluses,
-                                     bucket_tail_surpluses, plan_fused_ok,
-                                     resolve_spec, shard_plan,
-                                     warn_legacy_kwargs)
+                                     bucket_surpluses, resolve_spec,
+                                     shard_plan, warn_legacy_kwargs)
     if sharded_plan is not None:
         if plan is not None:
             raise ValueError("ct_transform_sharded: pass plan= or the "
                              "deprecated sharded_plan=, not both")
         warn_legacy_kwargs("ct_transform_sharded", ("sharded_plan",))
         plan = sharded_plan
-    spec = resolve_spec("ct_transform_sharded", spec,
-                        fused=fused, interpret=interpret)
-    fused, interpret = spec.fused, spec.interpret
+    spec = resolve_spec("ct_transform_sharded", spec, interpret=interpret)
+    interpret = spec.interpret
     if member_axis is None:
         member_axis = spec.member_axis
     n_groups = 1
@@ -560,35 +470,12 @@ def ct_transform_sharded(nodal_grids, scheme: SchemeLike, mesh: Mesh,
             f"sharded_plan embeds into {sharded_plan.full_levels}, caller "
             f"asked for {tuple(int(l) for l in full_levels)}")
     if member_axis is not None and n_groups > 1:
-        # 2-D compute-sharded route; the fused epilogue cannot apply here
-        # (shipping sits between the axis-0 transform and the scatter).
-        # A degenerate 1x1 mesh has nothing to compute-shard and falls
+        # 2-D compute-sharded route.  A degenerate 1x1 mesh has nothing to compute-shard and falls
         # through to the classic slab path.
         stacks = bucket_nodal_stacks(nodal_grids, sharded_plan.plan)
         return gather_slab_scatter_2d(stacks, sharded_plan, mesh,
                                       member_axis, axis_name,
                                       gather=gather, interpret=interpret)
-    if fused is None:
-        dtypes = [jnp.asarray(nodal_grids[ell]).dtype
-                  for b in sharded_plan.buckets for ell in b.ells
-                  if ell in nodal_grids]
-        fused = plan_fused_ok(sharded_plan,
-                              jnp.result_type(*dtypes) if dtypes
-                              else jnp.float64)
-    elif fused:
-        # an explicit fused=True still cannot run jnp-path buckets
-        # through the tail kernel (their tile-pad blowup is the reason
-        # the auto rule excludes them) — same fallback as the
-        # single-device _fuse_bucket, just all-or-nothing
-        from repro.kernels.hierarchize import batched_method
-        fused = all(batched_method(b.shape) == "pallas"
-                    for b in sharded_plan.buckets)
-    if fused:
-        tails = bucket_tail_surpluses(nodal_grids, sharded_plan.plan,
-                                      interpret=interpret)
-        return gather_slab_scatter_fused(tails, sharded_plan, mesh,
-                                         axis_name, gather=gather,
-                                         interpret=interpret)
     alphas = bucket_surpluses(nodal_grids, sharded_plan.plan,
                               interpret=interpret)
     return gather_slab_scatter(alphas, sharded_plan, mesh, axis_name,

@@ -1,7 +1,7 @@
 """Unified CT execution front door: ``ExecSpec`` + multi-tenant ``CTEngine``.
 
 After PRs 1-4 the execution options (bucket merging, mesh/slab sharding,
-fused epilogue, interpret mode) were threaded as ad-hoc kwargs through
+interpret mode) were threaded as ad-hoc kwargs through
 four parallel entry-point families (``ct_transform*``,
 ``ct_transform_psum``/``ct_transform_sharded``, ``CTSurrogate``,
 ``make_ct_step``) — every new capability multiplied the API surface.
@@ -23,7 +23,7 @@ ExecSpec precedence rules
 
 1. **spec wins, conflicts raise.**  An explicit ``spec=`` is
    authoritative; combining it with a non-``None`` legacy kwarg
-   (``merge=``, ``mesh=``, ``fused=``, ``interpret=``, ...) on the same
+   (``merge=``, ``mesh=``, ``interpret=``, ...) on the same
    call raises ``ValueError`` instead of guessing which one the caller
    meant.
 2. **Legacy kwargs construct a spec.**  Called without ``spec=``, the
@@ -37,13 +37,11 @@ ExecSpec precedence rules
    ``n_slabs=None`` means "the mesh axis extent" (``spec.slabs``);
    ``interpret=None`` means "ask ``repro.kernels.hierarchize.
    interpret_default`` at execution time" (never frozen into the spec);
-   ``fused=None`` means the per-bucket auto rule
-   (``repro.core.executor.plan_fused_ok``); ``dtype=None`` means
-   "promote the input dtypes".
+   ``dtype=None`` means "promote the input dtypes".
 4. **A meshed spec routes multi-device.**  ``mesh=`` makes the front
    doors (``ct_transform``, ``CTEngine``, ``CTSurrogate``) run the
    slab-sharded gather over ``mesh.shape[axis_name]`` device groups;
-   everything else (merge, fused, interpret) composes orthogonally.
+   everything else (merge, interpret) composes orthogonally.
 
 Deprecation policy
 ------------------
@@ -188,14 +186,12 @@ from repro.analysis import lockdep as _lockdep
 
 from repro.core.executor import (ExecutorPlan, MergeConfig, ShardedPlan,
                                  _assemble_members, _check_nodal_grids,
-                                 _gather_one_bucket, _tail_transform,
-                                 build_plan, extend_plan, plan_fused_ok,
+                                 _gather_one_bucket, build_plan, extend_plan,
                                  plan_launch_stats, reset_legacy_warnings,
                                  shard_plan)
 from repro.core.interpolation import interpolate_hierarchical
 from repro.core.levels import SchemeLike
-from repro.kernels.hierarchize import (batched_method, hierarchize_batched,
-                                       interpret_default)
+from repro.kernels.hierarchize import hierarchize_batched, interpret_default
 from repro.runtime.durability import DurableStore, RetryPolicy
 
 __all__ = ["ExecSpec", "CTEngine", "CTFuture", "EngineSaturated",
@@ -263,8 +259,6 @@ class ExecSpec:
     axis_name: str = "slab"
     #: slab count override; ``None`` = ``mesh.shape[axis_name]`` (1 off-mesh)
     n_slabs: Optional[int] = None
-    #: fused scatter-add epilogue: ``None`` = per-bucket auto rule
-    fused: Optional[bool] = None
     #: Pallas interpret mode: ``None`` = backend default at execution time
     interpret: Optional[bool] = None
     #: accumulation dtype of engine ingest (name, e.g. ``"float64"``);
@@ -283,8 +277,8 @@ class ExecSpec:
     #: SECOND mesh axis of the 2-D (member x slab) ingest: when set (and
     #: the mesh carries it), the hierarchization itself is compute-
     #: sharded over ``members * slabs`` groups and ingest routes through
-    #: ``repro.core.distributed.gather_slab_scatter_2d`` (bit-identical;
-    #: unfused by construction).  ``None`` = classic slab-only sharding
+    #: ``repro.core.distributed.gather_slab_scatter_2d`` (bit-identical).
+    #: ``None`` = classic slab-only sharding
     #: with replicated compute.  Inert without a mesh (so
     #: ``dataclasses.replace(spec, mesh=None)`` de-meshings stay valid).
     member_axis: Optional[str] = None
@@ -380,7 +374,7 @@ def plan_signature(plan, spec: ExecSpec) -> Tuple:
     buckets = tuple((b.levels, b.perms) for b in base.buckets)
     shard = (plan.n_slabs, plan.n_groups) if sharded else None
     return (base.full_levels, buckets, shard,
-            spec.fused, spec.interpret, spec.dtype, spec.donate,
+            spec.interpret, spec.dtype, spec.donate,
             spec.mesh if sharded else None,
             spec.axis_name if sharded else None,
             spec.member_axis if sharded else None)
@@ -422,7 +416,7 @@ def _build_ingest_executable(plan, spec: ExecSpec) -> Callable:
     base = plan.plan if sharded else plan
     metas = [(b.levels, b.perms, b.shape) for b in base.buckets]
     fine_shape, fine_size = base.fine_shape, base.fine_size
-    interpret, fused, dtype_policy = spec.interpret, spec.fused, spec.dtype
+    interpret, dtype_policy = spec.interpret, spec.dtype
     # zero-copy hand-off: the staged grid parts (argument 0) are donated
     # so the backend may retire them into the transform's intermediates;
     # index maps / coefficients are NOT donated — they are the tenant's
@@ -449,7 +443,7 @@ def _build_ingest_executable(plan, spec: ExecSpec) -> Callable:
             for x, (levels, _, _), idx, cs in zip(_assembled(parts), metas,
                                                   idxs, coeffs):
                 full = _gather_one_bucket(full, x, levels, idx,
-                                          cs.astype(dtype), fused=fused,
+                                          cs.astype(dtype),
                                           interpret=interpret)
             return full[:-1].reshape(fine_shape)
 
@@ -482,23 +476,10 @@ def _build_ingest_executable(plan, spec: ExecSpec) -> Callable:
         return jax.jit(ingest_2d, donate_argnums=donate)
 
     def ingest_sharded(parts, idxs, coeffs):
-        from repro.core.distributed import (gather_slab_scatter,
-                                            gather_slab_scatter_fused)
+        from repro.core.distributed import gather_slab_scatter
         dtype = _acc_dtype(parts)
-        use_fused = fused
-        if use_fused is None:
-            use_fused = plan_fused_ok(splan, dtype)
-        elif use_fused:
-            use_fused = all(batched_method(shape) == "pallas"
-                            for _, _, shape in metas)
         xs = _assembled(parts)
         cs = [c.astype(dtype) for c in coeffs]
-        if use_fused:
-            tails = [_tail_transform(x, levels, interpret)
-                     for x, (levels, _, _) in zip(xs, metas)]
-            return gather_slab_scatter_fused(
-                tails, splan, mesh, axis_name, interpret=interpret,
-                idx_arrays=idxs, coeff_arrays=cs)
         alphas = [hierarchize_batched(x, levels, interpret=interpret)
                   .reshape(len(levels), -1)
                   for x, (levels, _, _) in zip(xs, metas)]
@@ -1964,7 +1945,7 @@ class CTEngine:
                   "scatter_dispatches": 0, "transform_bytes": 0,
                   "stack_bytes": 0}
         for name, t in tenants.items():
-            s = plan_launch_stats(t.plan, fused=t.spec.fused)
+            s = plan_launch_stats(t.plan)
             per_tenant[name] = s
             for k in gather:
                 gather[k] += s[k]

@@ -19,9 +19,10 @@ function:
      the saved kernel-launch overhead outweighs the pad-waste HBM bytes.
      Members below the merged target use the kernel machinery built for
      exactly this: zero-padding to the common ``2**l - 1`` extents,
-     padded ``H (+) I`` operators (identity on the padding, so padded
-     members transform exactly as their unpadded selves), and index-map
-     routing of every pad position to a dump slot.  The planner picks
+     per-member level vectors that mask every ancestor outside the
+     member's own pole (so padded members transform exactly as their
+     unpadded selves), and index-map routing of every pad position to a
+     dump slot.  The planner picks
      the OPTIMAL CONTIGUOUS partition (interval DP) of the descending-
      sorted shape sequence; contiguity preserves the global member
      order, which is what keeps merged results bit-identical to the
@@ -29,28 +30,19 @@ function:
      ``build_plan`` cache key) and survives ``extend_plan`` /
      ``update_plan_coefficients`` / ``shard_plan``.
 
-  3. **Batched hierarchization** — each bucket runs the fused Pallas
-     kernels ONCE with the member index as the leading Pallas grid
-     dimension (``repro.kernels.hierarchize.hierarchize_batched``):
-     kernel launches scale with the number of (super-)buckets, not
-     grids.
+  3. **Batched hierarchization** — each bucket runs the Pallas kernels
+     ONCE with the member index as the leading Pallas grid dimension
+     (``repro.kernels.hierarchize.hierarchize_batched``): kernel
+     launches scale with the number of (super-)buckets, not grids.
 
-  4. **Static index plan + fused scatter-add epilogue** — the
-     per-subspace gather/scatter dict is replaced by a per-bucket
-     ``(G, P)`` int32 index map into the flattened common fine grid,
-     precomputed from the scheme (embed offsets ``(j+1) * 2**(L-l) - 1``
-     and row strides, pad positions pointing at a dump slot).  On the
-     Pallas path the gather's coefficient weighting and scatter-add are
-     FUSED into the axis-0 kernel's tail
-     (``hier_axis0_scatter_batched_pallas``): surpluses are written
-     through the index map while the block is VMEM-resident, so the
-     ``(G, P)`` compact surplus stack never round-trips through HBM —
-     the extra round trip the paper's roofline says dominates.  The
-     unfused scatter-add (one jitted ``.at[idx].add`` per bucket)
-     remains the fallback for jnp-path buckets and fine grids beyond the
-     VMEM budget; both orders are the same per-slot left fold, so fused
-     and unfused results are bit-identical.  The scatter step is the
-     same map read in reverse (``take``).
+  4. **Static index plan** — the per-subspace gather/scatter dict is
+     replaced by a per-bucket ``(G, P)`` int32 index map into the
+     flattened common fine grid, precomputed from the scheme (embed
+     offsets ``(j+1) * 2**(L-l) - 1`` and row strides, pad positions
+     pointing at a dump slot).  The gather is one coefficient-weighted
+     ``.at[idx].add`` per bucket, in bucket order (a per-slot left fold,
+     which is what keeps merged and sharded results bit-identical); the
+     scatter step is the same map read in reverse (``take``).
 
 ``ct_transform`` / ``ct_scatter`` are end-to-end jittable (scheme static),
 reused by the distributed psum path (``repro.core.distributed.
@@ -100,16 +92,13 @@ from repro.analysis import lockdep as _lockdep
 from repro.core.levels import (LevelVector, SchemeLike, canonical_levels,
                                fine_levels, grid_shape)
 from repro.kernels.hierarchize import (batched_method, dehierarchize_batched,
-                                       hier_axis0_scatter_batched_pallas,
-                                       hier_tail_batched_pallas,
                                        hierarchize_batched, tile_volume)
 
 __all__ = ["ExecutorPlan", "Bucket", "ShardedPlan", "SlabBucket",
            "MergeConfig", "build_plan", "shard_plan", "extend_plan",
            "update_plan_coefficients", "ct_transform", "ct_scatter",
            "ct_embedded", "ct_transform_with_plan", "ct_scatter_with_plan",
-           "ct_embedded_with_plan", "bucket_surpluses",
-           "bucket_tail_surpluses", "bucket_nodal_stacks", "plan_fused_ok",
+           "ct_embedded_with_plan", "bucket_surpluses", "bucket_nodal_stacks",
            "plan_launch_stats", "plan_ingest_stats", "clear_plan_cache"]
 
 
@@ -136,7 +125,7 @@ def reset_legacy_warnings() -> None:
 def warn_legacy_kwargs(fn_name: str, kwarg_names: Sequence[str]) -> None:
     """One ``DeprecationWarning`` per (function, kwargs) combination: the
     scattered execution kwargs (``merge=``, ``mesh=``, ``sharded_plan=``,
-    ``fused=``, ``interpret=``, ...) keep working but should be replaced
+    ``interpret=``, ...) keep working but should be replaced
     by one ``spec=repro.core.engine.ExecSpec(...)``.  Thread-safe: the
     first thread to claim the (function, kwargs) key warns; concurrent
     callers of the same family stay silent."""
@@ -495,35 +484,28 @@ class MergeConfig:
     max_members: Optional[int] = None
 
 
-def _bucket_cost(target: LevelVector, n_members: int, merge: MergeConfig,
-                 out_elems: int) -> float:
+def _bucket_cost(target: LevelVector, n_members: int,
+                 merge: MergeConfig) -> float:
     """Modelled HBM cost of one bucket: launch overhead + member traffic.
 
-    Mirrors ``plan_launch_stats`` under the auto-fuse default: a Pallas
-    bucket within the fused VMEM budget (``out_elems`` fine-buffer slots)
-    dispatches tail + axis-0 (one launch when 1-D) with the scatter
-    folded into the axis-0 tail; an UNFUSED bucket (jnp path, or fine
-    buffer over budget) additionally pays its standalone XLA scatter
-    dispatch and the compact-stack write+read round trip."""
+    Mirrors ``plan_launch_stats``: a Pallas bucket dispatches tail +
+    axis-0 (one launch when 1-D), a jnp bucket one pass per axis; every
+    bucket then pays its XLA scatter dispatch and the compact-stack
+    write+read round trip."""
     shape = grid_shape(target)
     p = int(np.prod(shape, dtype=np.int64))
-    fused = False
     if batched_method(shape) == "pallas":
         launches, vol = (1 if len(shape) == 1 else 2), tile_volume(shape)
-        fused = out_elems * merge.dtype_bytes <= _FUSED_OUT_BUDGET_BYTES
     else:
         launches, vol = len(shape), p
-    cost = (launches * merge.launch_cost_bytes
-            + merge.round_trips * n_members * vol * merge.dtype_bytes)
-    if not fused:
-        cost += (merge.launch_cost_bytes
-                 + 2 * n_members * p * merge.dtype_bytes)
-    return cost
+    return ((launches + 1) * merge.launch_cost_bytes
+            + merge.round_trips * n_members * vol * merge.dtype_bytes
+            + 2 * n_members * p * merge.dtype_bytes)
 
 
 def _merge_partition(keys: Sequence[LevelVector],
-                     sizes: Sequence[int], merge: MergeConfig,
-                     out_elems: int) -> Tuple[Tuple[int, int], ...]:
+                     sizes: Sequence[int],
+                     merge: MergeConfig) -> Tuple[Tuple[int, int], ...]:
     """Optimal contiguous partition of the descending-sorted canonical
     keys into super-buckets, as half-open index segments ``(i, j)``.
 
@@ -551,8 +533,7 @@ def _merge_partition(keys: Sequence[LevelVector],
             if merge.max_members is not None and members > merge.max_members \
                     and j - i > 1:
                 break
-            c = best[i] + _bucket_cost(tuple(target), members, merge,
-                                       out_elems)
+            c = best[i] + _bucket_cost(tuple(target), members, merge)
             if c < best[j]:
                 best[j], cut[j] = c, i
     segments = []
@@ -607,20 +588,17 @@ def _group_members(scheme: SchemeLike) -> Dict[LevelVector, list]:
 
 
 def _segment_member_lists(groups: Dict[LevelVector, list],
-                          merge: Optional[MergeConfig],
-                          fine_size: int) -> list:
+                          merge: Optional[MergeConfig]) -> list:
     """Deterministic bucket member lists: canonical groups in descending
-    key order, optionally merged into contiguous super-bucket segments
-    (the cost model needs ``fine_size`` to know whether buckets will take
-    the fused epilogue).  Single construction site for ``build_plan`` and
-    ``extend_plan`` — the same groups, ``merge`` and fine grid always
-    give the same partition and the same member order, which is what
-    makes incremental rebuilds bit-identical to from-scratch builds."""
+    key order, optionally merged into contiguous super-bucket segments.
+    Single construction site for ``build_plan`` and ``extend_plan`` — the
+    same groups and ``merge`` always give the same partition and the same
+    member order, which is what makes incremental rebuilds bit-identical
+    to from-scratch builds."""
     keys = sorted(groups, reverse=True)
     if merge is None:
         return [list(groups[k]) for k in keys]
-    segments = _merge_partition(keys, [len(groups[k]) for k in keys], merge,
-                                fine_size + 1)
+    segments = _merge_partition(keys, [len(groups[k]) for k in keys], merge)
     return [[m for k in keys[i:j] for m in groups[k]]
             for i, j in segments]
 
@@ -769,8 +747,7 @@ def _build_plan_uncached(scheme: SchemeLike, full_levels: LevelVector,
     fine_size = int(np.prod(fine_shape))
     fine_strides = _fine_strides(fine_shape)
 
-    member_lists = _segment_member_lists(_group_members(scheme), merge,
-                                         fine_size)
+    member_lists = _segment_member_lists(_group_members(scheme), merge)
     buckets = tuple(_make_bucket(members, full_levels, fine_strides,
                                  fine_size)
                     for members in member_lists)
@@ -830,8 +807,7 @@ def extend_plan(plan: ExecutorPlan, scheme: SchemeLike,
     old_by_ells = {b.ells: b for b in plan.buckets}
 
     buckets = []
-    for members in _segment_member_lists(_group_members(scheme), plan.merge,
-                                         fine_size):
+    for members in _segment_member_lists(_group_members(scheme), plan.merge):
         target = tuple(max(lv[k] for _, _, lv, _ in members)
                        for k in range(len(full_levels)))
         ells = tuple(m[0] for m in members)
@@ -955,7 +931,7 @@ def ct_transform(nodal_grids: Mapping[LevelVector, jnp.ndarray],
     return ct_transform_with_plan(nodal_grids,
                                   build_plan(scheme, full_levels,
                                              merge=spec.merge),
-                                  interpret=spec.interpret, fused=spec.fused)
+                                  interpret=spec.interpret)
 
 
 def bucket_surpluses(nodal_grids: Mapping[LevelVector, jnp.ndarray],
@@ -977,36 +953,6 @@ def bucket_surpluses(nodal_grids: Mapping[LevelVector, jnp.ndarray],
     return tuple(out)
 
 
-def _tail_transform(x: jnp.ndarray,
-                    member_levels: Tuple[LevelVector, ...],
-                    interpret: Optional[bool]) -> jnp.ndarray:
-    """Tail phase of the batched Pallas path: axes 1..d-1 transformed,
-    axis 0 still nodal, trailing axes flattened to ``(G, N0, B)`` — the
-    fused scatter epilogue's input layout."""
-    g = x.shape[0]
-    if x.ndim == 2:                       # 1-D bucket: no tail axes
-        return x[:, :, None]
-    y = hier_tail_batched_pallas(x, member_levels, interpret=interpret)
-    return y.reshape(g, y.shape[1], -1)
-
-
-def bucket_tail_surpluses(nodal_grids: Mapping[LevelVector, jnp.ndarray],
-                          plan: ExecutorPlan, *,
-                          interpret: Optional[bool] = None
-                          ) -> Tuple[jnp.ndarray, ...]:
-    """Per-bucket TAIL-transformed stacks ``[(G_b, N0, B_b), ...]`` (axis 0
-    untransformed) — what the fused scatter-add epilogue consumes: the
-    axis-0 transform happens inside the epilogue kernel, so the finished
-    compact surpluses never land in HBM.  Only meaningful for buckets on
-    the Pallas path (``plan_fused_ok``)."""
-    if isinstance(plan, ShardedPlan):
-        plan = plan.plan
-    _check_nodal_grids(nodal_grids, plan)
-    return tuple(_tail_transform(_assemble_bucket(nodal_grids, b), b.levels,
-                                 interpret)
-                 for b in plan.buckets)
-
-
 def bucket_nodal_stacks(nodal_grids: Mapping[LevelVector, jnp.ndarray],
                         plan: ExecutorPlan) -> Tuple[jnp.ndarray, ...]:
     """Per-bucket assembled NODAL stacks ``[(G_b, P_b), ...]`` — assembly
@@ -1024,36 +970,9 @@ def bucket_nodal_stacks(nodal_grids: Mapping[LevelVector, jnp.ndarray],
         for b in plan.buckets)
 
 
-#: Fine-buffer byte budget for the fused epilogue's VMEM-resident output
-#: block (half of a v5e core's 16 MiB VMEM markdown, leaving room for the
-#: member block + operator).  Beyond it the executor falls back to the
-#: unfused scatter-add.
-_FUSED_OUT_BUDGET_BYTES = 8 * 1024 * 1024
-
-
-def _fuse_shape(shape: Tuple[int, ...], out_elems: int, itemsize: int,
-                fused: Optional[bool]) -> bool:
-    """Per-bucket fused-epilogue decision from the canonical (padded)
-    bucket shape: ``None`` = auto (Pallas-path bucket AND fine buffer
-    within the VMEM budget), ``True`` forces the epilogue wherever the
-    kernel supports it (jnp-path buckets always fall back), ``False``
-    disables."""
-    if fused is False or batched_method(shape) != "pallas":
-        return False
-    if fused is None and out_elems * itemsize > _FUSED_OUT_BUDGET_BYTES:
-        return False
-    return True
-
-
-def _fuse_bucket(bucket: Bucket, out_elems: int, itemsize: int,
-                 fused: Optional[bool]) -> bool:
-    return _fuse_shape(bucket.shape, out_elems, itemsize, fused)
-
-
 def _gather_one_bucket(full: jnp.ndarray, x: jnp.ndarray,
                        member_levels: Tuple[LevelVector, ...],
-                       idx, cs, *, fused: Optional[bool],
-                       interpret: Optional[bool]) -> jnp.ndarray:
+                       idx, cs, *, interpret: Optional[bool]) -> jnp.ndarray:
     """Accumulate one assembled bucket stack ``x`` (G members, canonical
     padded shape) into the flat fine buffer ``full`` (+1 dump slot).
 
@@ -1064,61 +983,28 @@ def _gather_one_bucket(full: jnp.ndarray, x: jnp.ndarray,
     one compilation; both spellings trace the same ops, so results are
     bit-identical either way."""
     g = len(member_levels)
-    if _fuse_shape(x.shape[1:], full.shape[0],
-                   jnp.dtype(full.dtype).itemsize, fused):
-        y = _tail_transform(x, member_levels, interpret)
-        idx = jnp.asarray(idx).reshape((g,) + y.shape[1:])
-        return hier_axis0_scatter_batched_pallas(
-            y, [lv[0] for lv in member_levels], cs, idx, full,
-            interpret=interpret)
     alpha = hierarchize_batched(x, member_levels, interpret=interpret)
     return full.at[jnp.asarray(idx)].add(cs[:, None] * alpha.reshape(g, -1))
-
-
-def plan_fused_ok(plan: ExecutorPlan, dtype=jnp.float64,
-                  out_elems: Optional[int] = None) -> bool:
-    """True iff EVERY bucket of the plan takes the fused scatter-add
-    epilogue under the auto rule (the all-or-nothing gate of the sharded
-    gather, where the per-device scatter target has ``out_elems`` slots —
-    defaults to the full fine buffer)."""
-    if isinstance(plan, ShardedPlan):
-        if out_elems is None:
-            out_elems = plan.slab_size + 1
-        plan = plan.plan
-    if out_elems is None:
-        out_elems = plan.fine_size + 1
-    itemsize = jnp.dtype(dtype).itemsize
-    return all(_fuse_bucket(b, out_elems, itemsize, None)
-               for b in plan.buckets)
 
 
 def ct_transform_with_plan(nodal_grids: Mapping[LevelVector, jnp.ndarray],
                            plan: ExecutorPlan, *,
                            interpret: Optional[bool] = None,
-                           fused: Optional[bool] = None,
                            spec=None) -> jnp.ndarray:
     """``ct_transform`` against an explicit (possibly incrementally rebuilt)
     plan — the adaptive-refinement / fault-recovery entry point.  A
     ``ShardedPlan`` is accepted and runs through its base plan (the
     single-device fallback; the multi-device execution lives in
     ``repro.core.distributed.ct_transform_sharded``).  ``spec`` (a
-    ``repro.core.engine.ExecSpec``) supplies ``interpret``/``fused``
-    instead of the bare kwargs; a MESHED spec routes the sharded plan
-    through the slab-sharded gather.
-
-    Pallas-path buckets run the FUSED scatter-add epilogue by default
-    (``fused=None``; see ``_fuse_bucket`` for the auto rule): the axis-0
-    kernel weights each member by its combination coefficient and writes
-    through the static index map while the block is VMEM-resident, so the
-    ``(G, P)`` compact stack never round-trips through HBM.  Fused and
-    unfused accumulate per fine slot in the same member order (a left
-    fold), so the results are bit-identical."""
+    ``repro.core.engine.ExecSpec``) supplies ``interpret`` instead of the
+    bare kwarg; a MESHED spec routes the sharded plan through the
+    slab-sharded gather."""
     if spec is not None:
         ensure_spec("ct_transform_with_plan", spec)
-        if interpret is not None or fused is not None:
+        if interpret is not None:
             raise ValueError("ct_transform_with_plan: pass spec or the "
-                             "bare interpret/fused kwargs, not both")
-        interpret, fused = spec.interpret, spec.fused
+                             "bare interpret kwarg, not both")
+        interpret = spec.interpret
         if spec.mesh is not None:
             if not isinstance(plan, ShardedPlan):
                 raise ValueError(
@@ -1142,7 +1028,7 @@ def ct_transform_with_plan(nodal_grids: Mapping[LevelVector, jnp.ndarray],
         x = _assemble_bucket(nodal_grids, bucket)
         full = _gather_one_bucket(full, x, bucket.levels, bucket.index,
                                   jnp.asarray(bucket.coeffs, dtype),
-                                  fused=fused, interpret=interpret)
+                                  interpret=interpret)
     return full[:-1].reshape(plan.fine_shape)
 
 
@@ -1242,46 +1128,32 @@ def ct_embedded_with_plan(nodal_grids: Mapping[LevelVector, jnp.ndarray],
             tuple(order))
 
 
-def plan_launch_stats(plan: ExecutorPlan, *, dtype_bytes: int = 8,
-                      fused: Optional[bool] = None) -> Dict[str, int]:
+def plan_launch_stats(plan: ExecutorPlan, *,
+                      dtype_bytes: int = 8) -> Dict[str, int]:
     """Plan-derived dispatch and gather-phase HBM accounting.
 
     Static mirror of what one ``ct_transform_with_plan`` execution
     dispatches (cross-checked against the traced counts of
-    ``repro.kernels.hierarchize.count_launches`` in the benchmark).
-    ``dtype_bytes`` must be the gather's ACTUAL itemsize (default 8 =
-    f64): it prices the traffic AND feeds the same fused-epilogue VMEM
-    gate the execution uses, so a mismatched value (e.g. the default for
-    an f32 run near the budget boundary) would mis-report which buckets
-    fuse:
+    ``repro.kernels.hierarchize.count_launches`` in the benchmark);
+    ``dtype_bytes`` is the gather's itemsize (default 8 = f64):
 
     * ``pallas_launches`` — Pallas kernel launches (tail + axis-0 per
-      Pallas-path bucket; the fused epilogue replaces the axis-0 launch,
-      so the count is unchanged — fusion saves BYTES, merging saves
-      LAUNCHES);
-    * ``einsum_dispatches`` — stacked-operator dispatches of jnp-path
-      buckets (one per grid axis);
-    * ``scatter_dispatches`` — standalone XLA scatter-adds (one per
-      UNFUSED bucket; fused buckets scatter inside the axis-0 kernel);
+      Pallas-path bucket, axis-0 alone for 1-D buckets);
+    * ``einsum_dispatches`` — per-axis dispatches of jnp-path buckets;
+    * ``scatter_dispatches`` — XLA scatter-adds (one per bucket);
     * ``launches`` — the sum: every device-queue dispatch of the gather;
     * ``transform_bytes`` — modelled HBM traffic of the batched
       transforms (``round_trips=4`` array touches of each member's padded
       volume: 2 launches x read+write; tile volume on the Pallas path);
     * ``stack_bytes`` — the compact-surplus round trip (write the
       ``(G, P)`` stack after the transform + read it back in the
-      scatter) — ZERO for fused buckets: the bytes the fused epilogue
-      removes.
+      scatter).
     """
     if isinstance(plan, ShardedPlan):
-        # the sharded gather's scatter target is the per-slab buffer, so
-        # the fused gate mirrors plan_fused_ok, not the dense transform
-        out_elems = plan.slab_size + 1
         plan = plan.plan
-    else:
-        out_elems = plan.fine_size + 1
     stats = {"buckets": len(plan.buckets), "members": plan.num_grids,
              "pallas_launches": 0, "einsum_dispatches": 0,
-             "scatter_dispatches": 0, "launches": 0,
+             "scatter_dispatches": len(plan.buckets), "launches": 0,
              "transform_bytes": 0, "stack_bytes": 0}
     for b in plan.buckets:
         shape = b.shape
@@ -1294,9 +1166,6 @@ def plan_launch_stats(plan: ExecutorPlan, *, dtype_bytes: int = 8,
             stats["einsum_dispatches"] += len(shape)
             vol = p
         stats["transform_bytes"] += 4 * g * vol * dtype_bytes
-        if _fuse_bucket(b, out_elems, dtype_bytes, fused):
-            continue
-        stats["scatter_dispatches"] += 1
         stats["stack_bytes"] += 2 * g * p * dtype_bytes
     stats["launches"] = (stats["pallas_launches"]
                          + stats["einsum_dispatches"]

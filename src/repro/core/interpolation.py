@@ -16,6 +16,7 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -84,13 +85,17 @@ def interpolate_hierarchical(alpha: jnp.ndarray, points: jnp.ndarray) -> jnp.nda
     b, d = points.shape
     assert d == alpha.ndim
     acc = alpha.astype(jnp.result_type(alpha.dtype, jnp.float32))
+    # full-precision contractions: the TPU's default f32 matmul is one
+    # bf16 pass, ~1e-3 relative error on a served answer
+    hi = jax.lax.Precision.HIGHEST
     # contract one axis at a time: acc starts (N1..Nd), ends (B,)
     for ax in range(d):
         level = _axis_level(alpha.shape[ax])
         basis = _hat_basis_matrix(level, points[:, ax]).astype(acc.dtype)  # (B, N)
         if ax == 0:
-            acc = jnp.tensordot(basis, acc, axes=[[1], [0]])  # (B, N2..Nd)
+            acc = jnp.tensordot(basis, acc, axes=[[1], [0]],
+                                precision=hi)  # (B, N2..Nd)
         else:
             # acc is (B, N_ax, rest...); contract per-row
-            acc = jnp.einsum("bn,bn...->b...", basis, acc)
+            acc = jnp.einsum("bn,bn...->b...", basis, acc, precision=hi)
     return acc

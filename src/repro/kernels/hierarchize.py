@@ -1,38 +1,35 @@
 """Pallas TPU kernels for (de)hierarchization.
 
-TPU adaptation of the paper's BFS-OverVectorized kernel (DESIGN.md Sect. 2):
+TPU adaptation of the paper's BFS-OverVectorized kernel (DESIGN.md Sect. 2).
+Every kernel of this module except the MXU matmul shares ONE in-kernel
+axis pass (``_hier_pass`` / ``_dehier_pass``): the transformed axis lives
+on sublanes or lanes, all other dimensions ride along
+("over-vectorization" with a 128-wide VREG instead of a 4-wide AVX
+register), and the hierarchical predecessors of every node are fetched
+with static rotations of the VMEM-resident block (``pltpu.roll``) plus a
+per-level select — no gather, no strided update, so the pass lowers
+through Mosaic at any extent.
 
-* ``pole``   — the paper-faithful kernel: the working dimension lives on
-  sublanes, *all* other dimensions are flattened onto lanes
-  ("over-vectorization" with a 128-wide VREG instead of a 4-wide AVX
-  register).  The fine-to-coarse level loop is unrolled at trace time and
-  runs entirely in VMEM on a (pole_len x lane_tile) block.
+* ``pole``   — the paper-faithful kernel: one (N, lane_tile) pole bundle
+  per grid step, the whole level loop in VMEM (one HBM round trip).
 
 * ``matmul`` — the beyond-paper MXU formulation: 1-D hierarchization is a
   constant linear operator H with <=3 nonzeros per row, so the whole pole
   transform is one (N x N) @ (N x lanes) matmul.  For N <= ~1900 the dense
   matmul is still HBM-bound on v5e (2*N^2*B flops vs 16*N*B bytes crosses
-  the 197 TFLOP/s / 819 GB/s ridge at N ~ 1924), i.e. the "wasted" flops
-  are free and all gathers/branches disappear.
-
-* ``fused`` — beyond-paper: apply the operator along *several* axes per
-  HBM round-trip while the block is VMEM-resident.  Any d-dimensional grid
-  is hierarchized in 2 round trips (tail axes fused while tiling axis 0,
-  then axis 0 while tiling the lanes) instead of d.
+  the 197 TFLOP/s / 819 GB/s ridge at N ~ 1924).
 
 * ``batched`` — the CT executor's bucket kernels (one launch per bucket,
-  member index on the leading Pallas grid dimension).  FORWARD transforms
-  use the 3-term hierarchical-predecessor gathers (elementwise, bitwise
-  independent of zero-padding — the property bucket merging relies on);
-  the inverse keeps per-member ``H^-1 (+) I`` operator matmuls.  The
-  scatter-add epilogue variant (``hier_axis0_scatter_batched_pallas``)
-  additionally applies each member's combination coefficient and writes
-  the finished surpluses through a static index map into the
-  VMEM-resident fine buffer — the gather phase without the compact-stack
-  HBM round trip.
+  member index on the leading Pallas grid dimension): tail axes fused
+  while tiling axis 0, then axis 0 while tiling the lanes — 2 HBM round
+  trips for any d.  Each member's level vector arrives as scalar-prefetch
+  data (SMEM), so members below the bucket target are masked exactly as
+  their unpadded selves, and the level vectors may be runtime (sharded)
+  arrays.  ``fused`` (``hierarchize_nd_fused``) is this path at G = 1.
 
 All kernels are validated in ``interpret=True`` mode against
-``repro.kernels.ref`` (CPU container; TPU is the compilation target).
+``repro.kernels.ref`` on the CPU; ``tests/test_tpu_compile.py`` compiles
+them for a described v5e.
 """
 
 from __future__ import annotations
@@ -45,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 
@@ -58,11 +56,9 @@ __all__ = [
     "dehierarchize_nd_fused",
     "hier_tail_batched_pallas",
     "hier_axis0_batched_pallas",
-    "hier_axis0_scatter_batched_pallas",
     "hierarchize_batched",
     "hierarchize_batched_jnp",
-    "hierarchize_batched_data",
-    "member_pred_arrays",
+    "member_level_array",
     "dehierarchize_batched",
     "count_launches",
     "pad_blowup",
@@ -73,13 +69,17 @@ __all__ = [
 
 _LANE = 128
 _SUBLANE = 8
+#: per-buffer VMEM budget of one axis-0 block; in + out are double-
+#: buffered and the pass keeps a few block-sized temporaries live, so 1 MiB
+#: blocks stay well inside v5e's 16 MiB scoped-VMEM default
+_AXIS0_BLOCK_BYTES = 1 << 20
 
 # --- kernel-dispatch accounting (benchmarks / merge cost-model validation) --
 #
 # Counters are bumped at TRACE time, so inside jit they count the dispatches
 # the compiled executable will issue per call (each pallas_call is one kernel
-# launch; each stacked-operator einsum of the jnp path is one fused XLA
-# dispatch).  ``count_launches()`` scopes the accounting.
+# launch; each per-axis pass of the jnp path is one fused XLA dispatch).
+# ``count_launches()`` scopes the accounting.
 
 _LAUNCHES = {"pallas": 0, "einsum": 0}
 
@@ -89,8 +89,8 @@ def count_launches():
     """Count kernel dispatches traced inside the block.
 
     Yields a dict, filled when the block EXITS, with keys ``pallas``
-    (pallas_call launches) and ``einsum`` (per-axis stacked-operator
-    dispatches of the jnp fallback path)."""
+    (pallas_call launches) and ``einsum`` (per-axis dispatches of the jnp
+    path)."""
     saved = dict(_LAUNCHES)
     _LAUNCHES["pallas"] = _LAUNCHES["einsum"] = 0
     result: dict = {}
@@ -119,9 +119,6 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-_interpret_default = interpret_default
-
-
 def _level_of(n: int) -> int:
     level = int(np.log2(n + 1))
     if (1 << level) - 1 != n:
@@ -146,52 +143,139 @@ def _padded_operator(level: int, dtype, inverse: bool = False,
 
 
 # ---------------------------------------------------------------------------
+# The shared axis pass (3-term predecessor form)
+# ---------------------------------------------------------------------------
+#
+# FORWARD: ``alpha_j = u_j - u_{j-s}/2 - u_{j+s}/2`` with ``s = lowbit(j)``
+# (1-based j), boundary ancestors zero — H has <= 3 nonzeros per row.  The
+# neighbour offsets ``+-lowbit(j)`` depend only on the position, never on
+# the pole's level; the level only decides which neighbours are REAL, so
+# a pole of ``n = 2**l - 1`` nodes embedded at the head of a longer
+# (padded) axis is handled by masks alone: an ancestor outside ``1..n``
+# and every pad position get a False mask and contribute an exact
+# ``+0.0``.  The result is therefore bitwise independent of the padded
+# extent — what makes a merged super-bucket bit-identical to its unmerged
+# buckets, and the Pallas path bit-identical to the jnp path.
+#
+# INVERSE: the same neighbours, coarse-to-fine (children need their
+# parents' final nodal values), one select per level.
+
+def _hier3(x: jnp.ndarray, xl: jnp.ndarray, xr: jnp.ndarray,
+           lm: jnp.ndarray, rm: jnp.ndarray) -> jnp.ndarray:
+    """THE forward update, shared by every batched path (pallas tail,
+    pallas axis 0, jnp) so they all agree bitwise: fixed evaluation
+    order, elementwise only.  Masked ancestors (boundary / zero-padding)
+    contribute an exact ``+0.0`` regardless of the gathered value."""
+    half = jnp.asarray(0.5, x.dtype)
+    zero = jnp.zeros((), x.dtype)
+    return x - half * jnp.where(lm, xl, zero) - half * jnp.where(rm, xr, zero)
+
+
+def _dehier3(a: jnp.ndarray, ul: jnp.ndarray, ur: jnp.ndarray,
+             lm: jnp.ndarray, rm: jnp.ndarray) -> jnp.ndarray:
+    """THE inverse update (nodal value from surplus + final ancestors)."""
+    half = jnp.asarray(0.5, a.dtype)
+    zero = jnp.zeros((), a.dtype)
+    return a + half * jnp.where(lm, ul, zero) + half * jnp.where(rm, ur, zero)
+
+
+def _pred_masks(j, n):
+    """Lowbit class and left/right ancestor masks of 1-based positions
+    ``j`` in a pole of ``n`` real nodes (``n`` may be traced)."""
+    s = j & -j
+    real = j <= n
+    return s, real & (j > s), real & (j + s <= n)
+
+
+def _strides(n_max: int):
+    """Lowbit classes that can hold an INTERIOR node of a pole of at most
+    ``n_max`` real nodes (the axis' true, unpadded extent)."""
+    k = 1
+    while 2 * k <= n_max:
+        yield k
+        k *= 2
+
+
+def _shift(x: jnp.ndarray, k: int, axis: int) -> jnp.ndarray:
+    """``out[p] = x[(p - k) mod extent]`` along ``axis`` (k >= 0): a
+    sublane/lane rotation on the two minor axes, slices otherwise."""
+    if axis >= x.ndim - 2:
+        return pltpu.roll(x, k, axis)
+    return jnp.roll(x, k, axis)
+
+
+def _axis_masks(x: jnp.ndarray, axis: int, n):
+    j = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) + 1
+    return _pred_masks(j, n)
+
+
+def _hier_pass(x: jnp.ndarray, axis: int, n, n_max: int, *,
+               reduced_op: bool = False) -> jnp.ndarray:
+    """Forward transform of a VMEM value along ``axis`` for a pole of
+    ``n <= n_max`` real nodes (``n_max`` static, the axis' unpadded
+    extent).  ``reduced_op`` is the paper's fused ``x - (xl + xr) / 2``
+    spelling (pole kernel only)."""
+    ext = x.shape[axis]
+    s, lm, rm = _axis_masks(x, axis, n)
+    xl = xr = x
+    for k in _strides(n_max):
+        cls = s == k
+        xl = jnp.where(cls, _shift(x, k, axis), xl)
+        xr = jnp.where(cls, _shift(x, ext - k, axis), xr)
+    if reduced_op:
+        half = jnp.asarray(0.5, x.dtype)
+        zero = jnp.zeros((), x.dtype)
+        return x - half * (jnp.where(lm, xl, zero) + jnp.where(rm, xr, zero))
+    return _hier3(x, xl, xr, lm, rm)
+
+
+def _dehier_pass(a: jnp.ndarray, axis: int, n, n_max: int) -> jnp.ndarray:
+    """Inverse of ``_hier_pass`` (coarse-to-fine, one select per level)."""
+    ext = a.shape[axis]
+    s, lm, rm = _axis_masks(a, axis, n)
+    u = a
+    for k in reversed(list(_strides(n_max))):
+        upd = _dehier3(a, _shift(u, k, axis), _shift(u, ext - k, axis),
+                       lm, rm)
+        u = jnp.where(s == k, upd, u)
+    return u
+
+
+def _axis_pass(x, axis, n, n_max, inverse):
+    if inverse:
+        return _dehier_pass(x, axis, n, n_max)
+    return _hier_pass(x, axis, n, n_max)
+
+
+def _real_nodes(level):
+    """Real node count ``2**level - 1`` of a level (numpy, or traced);
+    level 0 (a padding member) has none."""
+    return (1 << level) - 1
+
+
+# ---------------------------------------------------------------------------
 # Pole kernel (paper-faithful: over-vectorization across lanes)
 # ---------------------------------------------------------------------------
 
-def _pole_kernel(x_ref, o_ref, *, level: int, reduced_op: bool):
-    """Unrolled fine-to-coarse level loop on a (Npad, T) VMEM block.
-
-    The strided level access of the nodal (``Ind``) layout is free inside
-    VMEM; branches are replaced by the static slice structure itself
-    (pre-branching is implicit: the first/last node of each level use the
-    zero-padded predecessor column).
-    """
-    x = x_ref[...]
-    zero = jnp.zeros((1,) + x.shape[1:], x.dtype)
-    for lam in range(level, 1, -1):
-        s = 1 << (level - lam)
-        odd = x[s - 1::2 * s]
-        even = x[2 * s - 1::2 * s][: odd.shape[0] - 1]
-        left = jnp.concatenate([zero, even], axis=0)
-        right = jnp.concatenate([even, zero], axis=0)
-        if reduced_op:
-            upd = odd - 0.5 * (left + right)
-        else:
-            upd = odd - 0.5 * left - 0.5 * right
-        x = x.at[s - 1::2 * s].set(upd)
-    o_ref[...] = x
+def _pole_kernel(x_ref, o_ref, *, n: int, inverse: bool, reduced_op: bool):
+    if inverse:
+        o_ref[...] = _dehier_pass(x_ref[...], 0, n, n)
+    else:
+        o_ref[...] = _hier_pass(x_ref[...], 0, n, n, reduced_op=reduced_op)
 
 
-def hier_pole_pallas(x: jnp.ndarray, *, lane_tile: int = _LANE,
-                     reduced_op: bool = True,
-                     interpret: bool | None = None) -> jnp.ndarray:
-    """Hierarchize along axis 0 of a (N, B) pole bundle.
-
-    N = 2**l - 1 poles points (sublanes), B poles (lanes).  One grid step
-    stages a (Npad, lane_tile) block HBM->VMEM, runs all levels, writes back:
-    exactly one HBM round trip, the paper's flat-performance property.
-    """
+def _pole_call(x, *, inverse: bool, lane_tile: int, reduced_op: bool,
+               interpret: bool | None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     n, b = x.shape
-    level = _level_of(n)
-    if level == 1:
+    if _level_of(n) == 1:
         return x
     npad = _round_up(n, _SUBLANE)
     bpad = _round_up(b, lane_tile)
     xp = jnp.pad(x, ((0, npad - n), (0, bpad - b)))
-    kernel = functools.partial(_pole_kernel, level=level, reduced_op=reduced_op)
+    kernel = functools.partial(_pole_kernel, n=n, inverse=inverse,
+                               reduced_op=reduced_op)
     out = _pallas_call(
         kernel,
         grid=(bpad // lane_tile,),
@@ -203,48 +287,27 @@ def hier_pole_pallas(x: jnp.ndarray, *, lane_tile: int = _LANE,
     return out[:n, :b]
 
 
-def _dehier_pole_kernel(a_ref, o_ref, *, level: int):
-    """Inverse transform: coarse-to-fine level loop on a (Npad, T) block.
+def hier_pole_pallas(x: jnp.ndarray, *, lane_tile: int = _LANE,
+                     reduced_op: bool = True,
+                     interpret: bool | None = None) -> jnp.ndarray:
+    """Hierarchize along axis 0 of a (N, B) pole bundle.
 
-    Unlike hierarchization (embarrassingly parallel across nodes), the
-    inverse is sequential in LEVEL (children need their parents' final
-    values) — but still fully lane-parallel across poles, and the whole
-    log-depth loop runs on one VMEM-resident block (1 HBM round trip)."""
-    a = a_ref[...]
-    zero = jnp.zeros((1,) + a.shape[1:], a.dtype)
-    for lam in range(2, level + 1):
-        s = 1 << (level - lam)
-        odd = a[s - 1::2 * s]
-        even = a[2 * s - 1::2 * s][: odd.shape[0] - 1]
-        left = jnp.concatenate([zero, even], axis=0)
-        right = jnp.concatenate([even, zero], axis=0)
-        a = a.at[s - 1::2 * s].set(odd + 0.5 * (left + right))
-    o_ref[...] = a
+    N = 2**l - 1 pole points (sublanes), B poles (lanes).  One grid step
+    stages a (Npad, lane_tile) block HBM->VMEM, runs all levels, writes back:
+    exactly one HBM round trip, the paper's flat-performance property.
+    """
+    return _pole_call(x, inverse=False, lane_tile=lane_tile,
+                      reduced_op=reduced_op, interpret=interpret)
 
 
 def dehier_pole_pallas(a: jnp.ndarray, *, lane_tile: int = _LANE,
                        interpret: bool | None = None) -> jnp.ndarray:
     """Dehierarchize along axis 0 of a (N, B) pole bundle (inverse of
-    ``hier_pole_pallas``; same BlockSpec tiling, same single round trip)."""
-    if interpret is None:
-        interpret = _interpret_default()
-    n, b = a.shape
-    level = _level_of(n)
-    if level == 1:
-        return a
-    npad = _round_up(n, _SUBLANE)
-    bpad = _round_up(b, lane_tile)
-    ap = jnp.pad(a, ((0, npad - n), (0, bpad - b)))
-    kernel = functools.partial(_dehier_pole_kernel, level=level)
-    out = _pallas_call(
-        kernel,
-        grid=(bpad // lane_tile,),
-        in_specs=[pl.BlockSpec((npad, lane_tile), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((npad, lane_tile), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((npad, bpad), a.dtype),
-        interpret=interpret,
-    )(ap)
-    return out[:n, :b]
+    ``hier_pole_pallas``; same tiling, same single round trip).  Unlike
+    hierarchization the inverse is sequential in LEVEL, but still fully
+    lane-parallel across poles."""
+    return _pole_call(a, inverse=True, lane_tile=lane_tile, reduced_op=False,
+                      interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +324,7 @@ def apply_axis_matmul_pallas(x: jnp.ndarray, *, inverse: bool = False,
                              interpret: bool | None = None) -> jnp.ndarray:
     """(De)hierarchize along axis 0 of a (N, B) bundle via one MXU matmul."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     n, b = x.shape
     level = _level_of(n)
     if level == 1:
@@ -286,83 +349,11 @@ def apply_axis_matmul_pallas(x: jnp.ndarray, *, inverse: bool = False,
     return out[:n, :b]
 
 
-# ---------------------------------------------------------------------------
-# Fused kernels: several axes per HBM round trip
-# ---------------------------------------------------------------------------
-
-def _fused_tail_kernel(x_ref, *refs, inverse: bool):
-    """Apply per-axis operators to axes 1..d-1 of a (R, N2, ..., Nd) block.
-
-    The block stays VMEM-resident across all axis transforms — this is the
-    fusion the paper's CPU caches could not hold (DESIGN.md Sect. 2 item 5).
-    For dehierarchization the axes commute as well (the operator is a tensor
-    product), so order is irrelevant.
-
-    Pallas passes all input refs first, then the output ref.
-    """
-    ops, o_ref = refs[:-1], refs[-1]
-    x = x_ref[...]
-    for axis_off, h_ref in enumerate(ops):
-        axis = 1 + axis_off
-        h = h_ref[...]
-        # contract the operator with axis `axis`; result axis comes first
-        x = jnp.tensordot(h, x, axes=[[1], [axis]])
-        # restore axis order
-        x = jnp.moveaxis(x, 0, axis)
-    o_ref[...] = x
-
-
-def hier_fused_tail_pallas(x: jnp.ndarray, *, inverse: bool = False,
-                           row_tile: int | None = None,
-                           vmem_budget_bytes: int = 4 * 1024 * 1024,
-                           interpret: bool | None = None) -> jnp.ndarray:
-    """(De)hierarchize axes 1..d-1 in ONE pass, tiling over axis 0."""
-    if interpret is None:
-        interpret = _interpret_default()
-    if x.ndim < 2:
-        raise ValueError("need >= 2 dims; use apply_axis_matmul_pallas for 1-D")
-    shape = x.shape
-    levels = [_level_of(s) for s in shape]
-    pads = [_round_up(s, _SUBLANE if i < x.ndim - 1 else _LANE)
-            for i, s in enumerate(shape)]
-    # the per-axis operators must match the padded axis extents
-    op_pads = pads[1:]
-    tail_elems = int(np.prod(pads[1:]))
-    itemsize = jnp.dtype(x.dtype).itemsize
-    if row_tile is None:
-        row_tile = max(1, vmem_budget_bytes // max(1, tail_elems * itemsize * 2))
-        row_tile = min(_round_up(pads[0], 1), max(_SUBLANE, _round_up(row_tile, _SUBLANE)))
-        row_tile = min(row_tile, pads[0])
-    rpad = _round_up(pads[0], row_tile)
-    xp = jnp.pad(x, [(0, rpad - shape[0])] + [(0, p - s) for p, s in zip(pads[1:], shape[1:])])
-    ops_mats = [jnp.asarray(
-        _padded_operator(l, np.float32, inverse=inverse, npad=p),
-        dtype=x.dtype if x.dtype != jnp.bfloat16 else jnp.float32)
-        for l, p in zip(levels[1:], op_pads)]
-    ndim = x.ndim
-
-    def x_index(i):
-        return (i,) + (0,) * (ndim - 1)
-
-    in_specs = [pl.BlockSpec((row_tile,) + tuple(pads[1:]), x_index)]
-    for m in ops_mats:
-        in_specs.append(pl.BlockSpec(m.shape, lambda i: (0, 0)))
-    kernel = functools.partial(_fused_tail_kernel, inverse=inverse)
-    out = _pallas_call(
-        kernel,
-        grid=(rpad // row_tile,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((row_tile,) + tuple(pads[1:]), x_index),
-        out_shape=jax.ShapeDtypeStruct((rpad,) + tuple(pads[1:]), x.dtype),
-        interpret=interpret,
-    )(xp, *ops_mats)
-    return out[tuple(slice(0, s) for s in shape)]
-
-
 def hier_axis0_pallas(x: jnp.ndarray, *, inverse: bool = False,
                       lane_tile: int = 512,
                       interpret: bool | None = None) -> jnp.ndarray:
-    """(De)hierarchize axis 0 only, tiling the flattened trailing axes."""
+    """(De)hierarchize axis 0 only (MXU matmul), tiling the flattened
+    trailing axes."""
     shape = x.shape
     flat = x.reshape(shape[0], -1)
     out = apply_axis_matmul_pallas(flat, inverse=inverse, lane_tile=lane_tile,
@@ -379,173 +370,62 @@ def hier_axis0_pallas(x: jnp.ndarray, *, inverse: bool = False,
 # canonical shape and launches ONE Pallas call per bucket with the grid
 # index as the leading Pallas grid dimension.  Members may sit at a level
 # BELOW the bucket target (cost-driven bucket merging): they are
-# zero-padded to the target extents and carry their own per-member
-# transform data, so padded members transform exactly as their unpadded
-# selves.
-#
-# FORWARD transforms use the 3-term hierarchical-predecessor form
-# (``alpha_m = u_m - u_{m-s}/2 - u_{m+s}/2`` with ``s = lowbit(m)``,
-# boundary ancestors zero — H has <= 3 nonzeros per row), realized as two
-# static gathers + elementwise arithmetic.  Elementwise math is bitwise
-# independent of the padded extent, which is what makes a merged
-# super-bucket's results BIT-identical to the unmerged buckets' — a dense
-# operator matmul re-associates the contraction when npad changes and
-# drifts by an ulp.  The INVERSE (dehierarchization) operator is dense per
-# row, so it keeps the per-member padded-operator matmul stacks
-# (``H^-1 (+) I``, identity on the padding).
+# zero-padded to the target extents and their own level vector (scalar
+# prefetch) masks them, so padded members transform exactly as their
+# unpadded selves.
 
-def _op_stack(member_levels: Sequence[int], npad: int, dtype,
-              inverse: bool) -> np.ndarray:
-    """(G, npad, npad) per-member 1-D operators, identity on padding."""
-    return np.stack([_padded_operator(l, dtype, inverse=inverse, npad=npad)
-                     for l in member_levels])
-
-
-def _pred_index_1d(level: int, npad: int) -> tuple:
-    """Left/right hierarchical-predecessor 0-based index vectors (npad,)
-    plus their validity masks, for a level-``level`` pole embedded at the
-    head of a (possibly padded) axis of extent ``npad >= 2**level - 1``.
-
-    1-based node m has ancestors at ``m -+ lowbit(m)``; a boundary
-    ancestor (0 or 2**level) contributes the homogeneous-zero boundary
-    value and pad positions beyond ``2**level - 1`` must stay zero, so
-    both get a False mask (the gather reads self, the mask zeroes it)."""
-    n = (1 << level) - 1
-    if n > npad:
-        raise ValueError(f"level {level} pole ({n}) exceeds extent {npad}")
-    j = np.arange(1, npad + 1)
-    s = j & -j
-    real = j <= n
-    lm = real & (j - s >= 1)
-    rm = real & (j + s <= n)
-    lp = np.where(lm, j - s, j) - 1
-    rp = np.where(rm, j + s, j) - 1
-    return (lp.astype(np.int32), rp.astype(np.int32), lm, rm)
-
-
-def _pred_stack(member_levels: Sequence[int], npad: int) -> tuple:
-    """Per-member predecessor stacks: ``(idx (2, G, npad) int32,
-    mask (2, G, npad) bool)`` — left then right."""
-    parts = [_pred_index_1d(l, npad) for l in member_levels]
-    idx = np.stack([np.stack([p[0] for p in parts]),
-                    np.stack([p[1] for p in parts])])
-    mask = np.stack([np.stack([p[2] for p in parts]),
-                     np.stack([p[3] for p in parts])])
-    return idx, mask
-
-
-def _pad_pred4(pred, npad: int) -> tuple:
-    """Extend one axis' ``(lp, rp, lm, rm)`` arrays from the true axis
-    extent to the kernel's padded extent.  Pad positions carry a False
-    mask and a self index — exactly what ``_pred_index_1d`` emits for
-    them, so a kernel fed padded-on-the-fly data computes bitwise the
-    same blocks as one fed ``_pred_stack(levels, npad)`` directly."""
-    lp, rp, lm, rm = (jnp.asarray(a) for a in pred)
-    g, n = lp.shape
-    if n == npad:
-        return lp, rp, lm, rm
-    extra = jnp.broadcast_to(jnp.arange(n, npad, dtype=lp.dtype),
-                             (g, npad - n))
-    pad_m = lambda m: jnp.pad(m, ((0, 0), (0, npad - n)))
-    return (jnp.concatenate([lp, extra], axis=1),
-            jnp.concatenate([rp, extra], axis=1), pad_m(lm), pad_m(rm))
-
-
-def member_pred_arrays(member_levels: Sequence[Sequence[int]],
-                       shape: Sequence[int]) -> tuple:
-    """Per-member forward-transform data of a bucket stack as ARRAYS.
-
-    Returns a flat tuple of ``4 * d`` numpy arrays — for each grid axis
-    ``k`` in order, ``lp, rp`` int32 and ``lm, rm`` bool of shape
-    ``(G, shape[k])`` (true extents): member g's left/right
-    hierarchical-predecessor gather indices and validity masks along that
-    axis.  This is the same data the batched kernels derive from
-    ``member_levels`` at trace time, exposed as runtime operands so it
-    can be SHARDED along G — ``hierarchize_batched_data`` consumes it
-    inside the 2-D sharded ingest's shard_map, where each device
+def member_level_array(member_levels) -> np.ndarray:
+    """Per-member level vectors of a bucket stack as ONE ``(G, d)`` int32
+    array — the only per-member data the batched transforms need.  As an
+    array it can be SHARDED along G: the 2-D sharded ingest passes it to
+    ``hierarchize_batched`` inside its shard_map, where each device
     transforms only its member shard and the member set therefore cannot
-    be a trace constant.  Slicing every array (and the stack) along G is
-    bitwise identical to the full-stack ``hierarchize_batched``."""
-    member_levels = [tuple(ml) for ml in member_levels]
-    out = []
-    for k, n in enumerate(shape):
-        idx, mask = _pred_stack([ml[k] for ml in member_levels], n)
-        out += [idx[0], idx[1], mask[0], mask[1]]
-    return tuple(out)
+    be a trace constant.  A row of zeros is a padding member (no real
+    nodes: its rows pass through unchanged)."""
+    return np.asarray([tuple(ml) for ml in member_levels],
+                      np.int32).reshape(len(member_levels), -1)
 
 
-def _hier3(x: jnp.ndarray, xl: jnp.ndarray, xr: jnp.ndarray,
-           lm: jnp.ndarray, rm: jnp.ndarray) -> jnp.ndarray:
-    """THE forward update, shared by every batched path (pallas tail,
-    pallas axis 0, fused scatter epilogue, jnp oracle) so they all agree
-    bitwise: fixed evaluation order, elementwise only.  Masked ancestors
-    (boundary / zero-padding) contribute an exact ``+0.0`` regardless of
-    the gathered value, so the result is independent of the padded
-    extent."""
-    half = jnp.asarray(0.5, x.dtype)
-    zero = jnp.zeros((), x.dtype)
-    return x - half * jnp.where(lm, xl, zero) - half * jnp.where(rm, xr, zero)
+def _level_table(levels):
+    """The ``(G, d)`` int32 level table: a numpy constant when the member
+    levels are static (so masks and operands stay host constants), the
+    array itself when it is a runtime (traced/sharded) one."""
+    if isinstance(levels, jax.Array):
+        return levels.astype(jnp.int32)
+    return member_level_array(levels)
 
 
-def _op_dtype(dtype):
-    return jnp.float32 if dtype == jnp.bfloat16 else dtype
+def _batched_tail_kernel(lv_ref, x_ref, o_ref, *, extents, inverse: bool):
+    """Tail transform of a (1, R, N2..Nd) block: every tail axis in turn
+    while the block stays VMEM-resident (the fusion the paper's CPU
+    caches could not hold)."""
+    gi = pl.program_id(0)
+    x = x_ref[0]
+    ntail = len(extents)
+    for k, n_max in enumerate(extents):
+        x = _axis_pass(x, 1 + k, _real_nodes(lv_ref[gi * ntail + k]),
+                       n_max, inverse)
+    o_ref[0] = x
 
 
-def _batched_tail_kernel(x_ref, *refs):
-    """Per-member INVERSE operators applied to axes 2..d of a
-    (1, R, N2..Nd) block.
-
-    Identical VMEM-resident fusion to ``_fused_tail_kernel``, plus the
-    leading bucket-member axis selected by the Pallas grid."""
-    ops, o_ref = refs[:-1], refs[-1]
-    x = x_ref[...][0]
-    for axis_off, h_ref in enumerate(ops):
-        axis = 1 + axis_off
-        h = h_ref[...][0]
-        x = jnp.moveaxis(jnp.tensordot(h, x, axes=[[1], [axis]]), 0, axis)
-    o_ref[...] = x[None]
-
-
-def _batched_tail_fwd_kernel(x_ref, *refs):
-    """FORWARD tail transform of a (1, R, N2..Nd) block: per axis, two
-    static predecessor gathers + the elementwise 3-term update — same
-    VMEM-resident multi-axis fusion, no reductions, so results are
-    bitwise independent of the padded extents."""
-    preds, o_ref = refs[:-1], refs[-1]
-    x = x_ref[...][0]
-    for axis_off in range(len(preds) // 4):
-        axis = 1 + axis_off
-        lp, rp, lm, rm = (r[...][0] for r in preds[4 * axis_off:
-                                                   4 * axis_off + 4])
-        bc = (None,) * axis + (slice(None),) + (None,) * (x.ndim - 1 - axis)
-        x = _hier3(x, jnp.take(x, lp, axis=axis),
-                   jnp.take(x, rp, axis=axis), lm[bc], rm[bc])
-    o_ref[...] = x[None]
-
-
-def hier_tail_batched_pallas(x: jnp.ndarray,
-                             member_levels: Sequence[Sequence[int]], *,
+def hier_tail_batched_pallas(x: jnp.ndarray, member_levels, *,
                              inverse: bool = False,
                              row_tile: int | None = None,
                              vmem_budget_bytes: int = 4 * 1024 * 1024,
-                             interpret: bool | None = None,
-                             pred=None) -> jnp.ndarray:
+                             interpret: bool | None = None) -> jnp.ndarray:
     """(De)hierarchize grid axes 1..d-1 of a (G, N1, ..., Nd) bucket.
 
-    ``member_levels[g]`` is member g's level vector in bucket axis order;
-    members below the bucket target level get their own predecessor
-    indices (forward) or padded operator (inverse).  ``pred`` (forward
-    only) supplies the per-member predecessor data as runtime arrays
-    instead — ``4 * (d-1)`` arrays at TRUE tail extents in axis order
-    (the tail slice of ``member_pred_arrays``), possibly traced/sharded;
-    ``member_levels`` is then ignored."""
+    ``member_levels`` is the ``(G, d)`` member level table — level
+    vectors in bucket axis order, or a (possibly traced/sharded) int32
+    array as ``member_level_array`` builds it."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     if x.ndim < 3:
         raise ValueError("need (G, N1, N2, ...); use the axis-0 kernel for 1-D")
     g = x.shape[0]
     shape = x.shape[1:]
-    pads = [_round_up(s, _SUBLANE if i < len(shape) - 1 else _LANE)
+    nd = len(shape)
+    pads = [_round_up(s, _SUBLANE if i < nd - 1 else _LANE)
             for i, s in enumerate(shape)]
     tail_elems = int(np.prod(pads[1:]))
     itemsize = jnp.dtype(x.dtype).itemsize
@@ -555,229 +435,101 @@ def hier_tail_batched_pallas(x: jnp.ndarray,
     rpad = _round_up(pads[0], row_tile)
     xp = jnp.pad(x, [(0, 0), (0, rpad - shape[0])] +
                  [(0, p - s) for p, s in zip(pads[1:], shape[1:])])
-    nd = len(shape)
-    if inverse:
-        odt = _op_dtype(x.dtype)
-        operands = [jnp.asarray(_op_stack([ml[1 + k] for ml in member_levels],
-                                          p, np.float64, inverse), odt)
-                    for k, p in enumerate(pads[1:])]
-        op_specs = [pl.BlockSpec((1,) + m.shape[1:], lambda gi, i: (gi, 0, 0))
-                    for m in operands]
-        kernel = _batched_tail_kernel
-    else:
-        operands, op_specs = [], []
-        for k, p in enumerate(pads[1:]):
-            if pred is not None:
-                sides = _pad_pred4(pred[4 * k:4 * k + 4], p)
-            else:
-                idx, mask = _pred_stack([ml[1 + k] for ml in member_levels],
-                                        p)
-                sides = (idx[0], idx[1], mask[0], mask[1])
-            for side in sides:
-                operands.append(jnp.asarray(side))
-                op_specs.append(pl.BlockSpec((1, p), lambda gi, i: (gi, 0)))
-        kernel = _batched_tail_fwd_kernel
+    lv = _level_table(member_levels)[:, 1:].reshape(-1)
 
-    def x_index(gi, i):
+    def x_index(gi, i, lv_ref):
         return (gi, i) + (0,) * (nd - 1)
 
-    in_specs = [pl.BlockSpec((1, row_tile) + tuple(pads[1:]), x_index)]
-    in_specs += op_specs
+    block = pl.BlockSpec((1, row_tile) + tuple(pads[1:]), x_index)
     out = _pallas_call(
-        kernel,
-        grid=(g, rpad // row_tile),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, row_tile) + tuple(pads[1:]), x_index),
+        functools.partial(_batched_tail_kernel, extents=tuple(shape[1:]),
+                          inverse=inverse),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(g, rpad // row_tile),
+            in_specs=[block], out_specs=block),
         out_shape=jax.ShapeDtypeStruct((g, rpad) + tuple(pads[1:]), x.dtype),
         interpret=interpret,
-    )(xp, *operands)
+    )(lv, xp)
     return out[(slice(None),) + tuple(slice(0, s) for s in shape)]
 
 
-def _batched_matmul_kernel(h_ref, x_ref, o_ref):
-    o_ref[...] = jnp.dot(h_ref[...][0], x_ref[...][0],
-                         preferred_element_type=o_ref.dtype)[None]
+def _batched_axis0_kernel(lv_ref, x_ref, o_ref, *, n_max: int,
+                          inverse: bool):
+    """Axis-0 transform of a (1, Npad, T) block."""
+    o_ref[0] = _axis_pass(x_ref[0], 0, _real_nodes(lv_ref[pl.program_id(0)]),
+                          n_max, inverse)
 
 
-def _batched_axis0_fwd_kernel(lp_ref, rp_ref, lm_ref, rm_ref, x_ref, o_ref):
-    """Forward axis-0 transform of a (1, Npad, T) block: two row gathers
-    + the elementwise 3-term update (bitwise padding-independent)."""
-    x = x_ref[...][0]
-    o_ref[...] = _hier3(x, jnp.take(x, lp_ref[...][0], axis=0),
-                        jnp.take(x, rp_ref[...][0], axis=0),
-                        lm_ref[...][0][:, None], rm_ref[...][0][:, None])[None]
-
-
-def hier_axis0_batched_pallas(x: jnp.ndarray, levels0: Sequence[int], *,
+def hier_axis0_batched_pallas(x: jnp.ndarray, levels0, *,
                               inverse: bool = False, lane_tile: int = 512,
-                              interpret: bool | None = None,
-                              pred=None) -> jnp.ndarray:
-    """(De)hierarchize grid axis 0 of a (G, N, B) bucket: predecessor
-    gathers (forward) or MXU matmuls (inverse).
+                              interpret: bool | None = None) -> jnp.ndarray:
+    """(De)hierarchize grid axis 0 of a (G, N, B) bucket.
 
-    ``levels0[g]`` is member g's level along the transformed axis.
-    ``pred`` (forward only) supplies the ``(lp, rp, lm, rm)`` predecessor
-    arrays at the TRUE extent as runtime (possibly sharded) data instead;
-    ``levels0`` is then ignored."""
+    ``levels0[g]`` is member g's level along the transformed axis (a
+    sequence or a possibly traced/sharded int array)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     g, n, b = x.shape
     npad = _round_up(n, _SUBLANE)
-    lane_tile = min(lane_tile, _round_up(b, _LANE))
+    itemsize = jnp.dtype(x.dtype).itemsize
+    fit = max(_LANE, _AXIS0_BLOCK_BYTES // (npad * itemsize) // _LANE * _LANE)
+    lane_tile = min(lane_tile, fit, _round_up(b, _LANE))
     bpad = _round_up(b, lane_tile)
     xp = jnp.pad(x, ((0, 0), (0, npad - n), (0, bpad - b)))
-    if inverse:
-        hmat = jnp.asarray(_op_stack(levels0, npad, np.float64, inverse),
-                           _op_dtype(x.dtype))
-        operands = [hmat]
-        op_specs = [pl.BlockSpec((1, npad, npad), lambda gi, i: (gi, 0, 0))]
-        kernel = _batched_matmul_kernel
-    else:
-        if pred is not None:
-            operands = list(_pad_pred4(pred, npad))
-        else:
-            idx, mask = _pred_stack(levels0, npad)
-            operands = [jnp.asarray(a) for a in (idx[0], idx[1],
-                                                 mask[0], mask[1])]
-        op_specs = [pl.BlockSpec((1, npad), lambda gi, i: (gi, 0))] * 4
-        kernel = _batched_axis0_fwd_kernel
+    lv = (levels0.astype(jnp.int32) if isinstance(levels0, jax.Array)
+          else np.asarray(levels0, np.int32)).reshape(g)
+    block = pl.BlockSpec((1, npad, lane_tile), lambda gi, i, lv_ref: (gi, 0, i))
     out = _pallas_call(
-        kernel,
-        grid=(g, bpad // lane_tile),
-        in_specs=op_specs + [
-            pl.BlockSpec((1, npad, lane_tile), lambda gi, i: (gi, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, npad, lane_tile), lambda gi, i: (gi, 0, i)),
+        functools.partial(_batched_axis0_kernel, n_max=n, inverse=inverse),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(g, bpad // lane_tile),
+            in_specs=[block], out_specs=block),
         out_shape=jax.ShapeDtypeStruct((g, npad, bpad), x.dtype),
         interpret=interpret,
-    )(*operands, xp)
+    )(lv, xp)
     return out[:, :n, :b]
 
 
-def _axis0_scatter_kernel(lp_ref, rp_ref, lm_ref, rm_ref, x_ref, i_ref,
-                          c_ref, acc_ref, o_ref):
-    """Fused epilogue step: member gi's axis-0 transform + weighted scatter.
-
-    The output block is the WHOLE fine buffer with a constant index map, so
-    it stays VMEM-resident across the entire grid (one HBM write at the
-    end) and accumulates: step (gi, ti) adds ``coeff[gi]`` times member
-    gi's finished surpluses (the same 3-term update as the unfused axis-0
-    kernel) of lane tile ti through the static index map.  Each member's
-    map is injective (pad positions alias the dump slot, which absorbs
-    only zeros), so per fine slot the adds happen once per member, in
-    member order — the same left fold as the unfused ``.at[idx].add``
-    gather, which is what keeps the fused path bit-identical."""
-    gi, ti = pl.program_id(0), pl.program_id(1)
-
-    @pl.when((gi == 0) & (ti == 0))
-    def _init():
-        o_ref[...] = acc_ref[...]
-
-    x = x_ref[...][0]
-    alpha = _hier3(x, jnp.take(x, lp_ref[...][0], axis=0),
-                   jnp.take(x, rp_ref[...][0], axis=0),
-                   lm_ref[...][0][:, None], rm_ref[...][0][:, None])
-    contrib = c_ref[...][0] * alpha
-    o_ref[...] = o_ref[...].at[i_ref[...][0].ravel()].add(
-        contrib.ravel().astype(o_ref.dtype))
-
-
-def hier_axis0_scatter_batched_pallas(x: jnp.ndarray, levels0: Sequence[int],
-                                      coeffs: jnp.ndarray, index, acc,
-                                      *, lane_tile: int = 512,
-                                      interpret: bool | None = None
-                                      ) -> jnp.ndarray:
-    """Fused scatter-add epilogue of the batched CT gather: (de)hierarchize
-    grid axis 0 of a (G, N, B) bucket AND scatter-add the coefficient-
-    weighted surpluses straight into the flat fine buffer ``acc`` — the
-    ``(G, P)`` compact surplus stack never round-trips through HBM.
-
-    ``index`` is the bucket's static (G, N, B) int32 map into ``acc``
-    (every pad position points at the dump slot ``len(acc) - 1``);
-    ``coeffs`` the (G,) combination coefficients in the accumulator dtype.
-    Returns ``acc`` plus all members' contributions, accumulated per fine
-    slot in member order (matching the unfused scatter's left fold, so the
-    result is BIT-identical to weighted-scatter-after-materialize).
-
-    VMEM note: the fine buffer is the kernel's resident output block, so
-    the caller gates this path on ``len(acc)`` fitting the VMEM budget
-    (``repro.core.executor`` falls back to the unfused gather otherwise).
-    In-kernel scatter is validated in interpret mode like the rest of this
-    module; on real TPU the same structure lowers through Mosaic's
-    dynamic-update path."""
-    if interpret is None:
-        interpret = _interpret_default()
-    g, n, b = x.shape
-    npad = _round_up(n, _SUBLANE)
-    lane_tile = min(lane_tile, _round_up(b, _LANE))
-    bpad = _round_up(b, lane_tile)
-    f = acc.shape[0]
-    fpad = _round_up(f, _LANE)
-    dump = f - 1
-    idx_s, mask_s = _pred_stack(levels0, npad)
-    xp = jnp.pad(x, ((0, 0), (0, npad - n), (0, bpad - b)))
-    ip = jnp.pad(jnp.asarray(index, jnp.int32),
-                 ((0, 0), (0, npad - n), (0, bpad - b)),
-                 constant_values=dump)
-    accp = jnp.pad(acc, (0, fpad - f))
-    cs = jnp.asarray(coeffs, acc.dtype)
-    pred_spec = pl.BlockSpec((1, npad), lambda gi, ti: (gi, 0))
-    out = _pallas_call(
-        _axis0_scatter_kernel,
-        grid=(g, bpad // lane_tile),
-        in_specs=[
-            pred_spec, pred_spec, pred_spec, pred_spec,
-            pl.BlockSpec((1, npad, lane_tile), lambda gi, ti: (gi, 0, ti)),
-            pl.BlockSpec((1, npad, lane_tile), lambda gi, ti: (gi, 0, ti)),
-            pl.BlockSpec((1,), lambda gi, ti: (gi,)),
-            pl.BlockSpec((fpad,), lambda gi, ti: (0,)),
-        ],
-        out_specs=pl.BlockSpec((fpad,), lambda gi, ti: (0,)),
-        out_shape=jax.ShapeDtypeStruct((fpad,), acc.dtype),
-        interpret=interpret,
-    )(jnp.asarray(idx_s[0]), jnp.asarray(idx_s[1]), jnp.asarray(mask_s[0]),
-      jnp.asarray(mask_s[1]), xp, ip, cs, accp)
-    return out[:f]
-
-
-def hierarchize_batched_jnp(x: jnp.ndarray,
-                            member_levels: Sequence[Sequence[int]], *,
+def hierarchize_batched_jnp(x: jnp.ndarray, member_levels, *,
                             inverse: bool = False) -> jnp.ndarray:
-    """Batched (de)hierarchization as per-axis stacked dispatches:
-    predecessor gathers + the shared 3-term update (forward) or
-    stacked-operator einsums (inverse).
+    """Batched (de)hierarchization as per-axis stacked dispatches: two
+    static neighbour gathers + the shared masked update per axis.
 
     No tile padding at all — the path of choice for high-d grids with
     tiny axis extents (a 3^10 grid would pad to 8^9 x 128 under the TPU
     sublane/lane tiling, a ~36000x blowup) and the interpret-mode oracle
-    for the Pallas kernels.  The forward path shares ``_hier3`` with the
-    Pallas kernels, so both are BITWISE equal (method choice never
-    changes results — a merged bucket that flips a member from the jnp to
-    the Pallas path stays bit-identical)."""
-    member_levels = [tuple(ml) for ml in member_levels]
+    for the Pallas kernels.  Forward results are BITWISE equal to the
+    Pallas path (same neighbours, same masks, same ``_hier3``), so method
+    choice never changes results.  ``member_levels`` as in
+    ``hier_tail_batched_pallas``."""
+    lv = _level_table(member_levels)
     d = x.ndim - 1
-    odt = _op_dtype(x.dtype)
-    for k in range(d):
+    # the Pallas path's axis order (tail axes, then axis 0): per-axis
+    # rounding then happens in the same sequence on both paths
+    for k in [*range(1, d), 0]:
         _count("einsum")
-        axis_levels = [ml[k] for ml in member_levels]
-        if inverse:
-            h = jnp.asarray(_op_stack(axis_levels, x.shape[k + 1],
-                                      np.float64, inverse), odt)
-            xm = jnp.moveaxis(x, k + 1, 1)
-            tail = xm.shape[2:]
-            xm = jnp.einsum("gij,gjt->git", h,
-                            xm.reshape(xm.shape[0], xm.shape[1], -1))
-            x = jnp.moveaxis(xm.reshape(xm.shape[:2] + tail), 1, k + 1)
-        else:
-            idx, mask = _pred_stack(axis_levels, x.shape[k + 1])
-            ishape = [1] * (d + 1)
-            ishape[0], ishape[k + 1] = x.shape[0], x.shape[k + 1]
-            lp = jnp.asarray(idx[0].reshape(ishape))
-            rp = jnp.asarray(idx[1].reshape(ishape))
-            xl = jnp.take_along_axis(x, lp, axis=k + 1)
-            xr = jnp.take_along_axis(x, rp, axis=k + 1)
-            x = _hier3(x, xl, xr, jnp.asarray(mask[0].reshape(ishape)),
-                       jnp.asarray(mask[1].reshape(ishape)))
+        ext = x.shape[k + 1]
+        bshape = [1] * (d + 1)
+        bshape[0], bshape[k + 1] = x.shape[0], ext
+        j = np.arange(1, ext + 1, dtype=np.int32)
+        s, lm, rm = _pred_masks(j[None, :], _real_nodes(lv[:, k])[:, None])
+        lm, rm = lm.reshape(bshape), rm.reshape(bshape)
+        # level-independent neighbour positions; out-of-range ones are
+        # masked, so any in-range stand-in (self) will do
+        sj = j & -j
+        left = np.where(j - sj >= 1, j - sj, j) - 1
+        right = np.where(j + sj <= ext, j + sj, j) - 1
+        if not inverse:
+            x = _hier3(x, jnp.take(x, left, axis=k + 1),
+                       jnp.take(x, right, axis=k + 1), lm, rm)
+            continue
+        cls = sj.reshape([ext if i == k + 1 else 1 for i in range(d + 1)])
+        u = x
+        for stride in reversed(list(_strides(ext))):
+            upd = _dehier3(x, jnp.take(u, left, axis=k + 1),
+                           jnp.take(u, right, axis=k + 1), lm, rm)
+            u = jnp.where(cls == stride, upd, u)
+        x = u
     return x
 
 
@@ -795,8 +547,6 @@ def pad_blowup(shape: Sequence[int]) -> float:
     return float(tile_volume(shape)) / max(1.0, float(np.prod(shape)))
 
 
-_pad_blowup = pad_blowup          # original (pre-public) name
-
 _PALLAS_MAX_BLOWUP = 8.0
 
 
@@ -808,86 +558,41 @@ def batched_method(shape: Sequence[int]) -> str:
             or max(shape) > 2047 else "pallas")
 
 
-def hierarchize_batched(x: jnp.ndarray,
-                        member_levels: Sequence[Sequence[int]], *,
+def hierarchize_batched(x: jnp.ndarray, member_levels, *,
                         inverse: bool = False,
                         interpret: bool | None = None,
                         method: str = "auto") -> jnp.ndarray:
     """Full d-dim (de)hierarchization of a (G, *bucket_shape) bucket.
 
-    ``method="pallas"``: same 2-HBM-round-trip structure as
-    ``hierarchize_nd_fused`` — tail axes fused while tiling axis 1, then
-    axis 1 while tiling the lanes — but ONE kernel launch pair per bucket
-    instead of per grid.  ``"jnp"``: stacked per-axis dispatches, no tile
-    padding (bitwise equal to the pallas path — both run ``_hier3``
-    forward / the operator stacks inverse).  ``"auto"`` picks pallas
+    ``member_levels`` is the ``(G, d)`` member level table: level vectors
+    in bucket axis order, or a (possibly traced/sharded) int32 array as
+    ``member_level_array`` builds it.  Every member's blocks are computed
+    independently of the rest of the batch, so any G-slice of (stack,
+    table) yields the same per-member bits as the full stack — what the
+    2-D member-sharded ingest relies on.
+
+    ``method="pallas"``: tail axes fused while tiling axis 1, then axis 1
+    while tiling the lanes — 2 HBM round trips, ONE kernel launch pair per
+    bucket.  ``"jnp"``: stacked per-axis dispatches, no tile padding
+    (bitwise equal to the pallas path forward).  ``"auto"`` picks pallas
     unless sublane/lane padding would inflate the block volume by more
     than ~8x (high-d tiny-extent grids); see ``batched_method``."""
-    member_levels = [tuple(ml) for ml in member_levels]
     if method == "auto":
         method = batched_method(x.shape[1:])
     if method == "jnp":
         return hierarchize_batched_jnp(x, member_levels, inverse=inverse)
     if method != "pallas":
         raise ValueError(f"unknown method {method!r}")
+    lv = _level_table(member_levels)
     if x.ndim == 2:
-        out = hier_axis0_batched_pallas(x[..., None],
-                                        [ml[0] for ml in member_levels],
+        out = hier_axis0_batched_pallas(x[..., None], lv[:, 0],
                                         inverse=inverse, interpret=interpret)
         return out[..., 0]
-    y = hier_tail_batched_pallas(x, member_levels, inverse=inverse,
-                                 interpret=interpret)
+    y = hier_tail_batched_pallas(x, lv, inverse=inverse, interpret=interpret)
     g = y.shape[0]
     shape = y.shape[1:]
     flat = y.reshape(g, shape[0], -1)
-    flat = hier_axis0_batched_pallas(flat, [ml[0] for ml in member_levels],
-                                     inverse=inverse, interpret=interpret)
-    return flat.reshape((g,) + shape)
-
-
-def hierarchize_batched_data(x: jnp.ndarray, pred, *,
-                             interpret: bool | None = None,
-                             method: str = "auto") -> jnp.ndarray:
-    """FORWARD ``hierarchize_batched`` with the per-member transform data
-    passed as runtime arrays (``member_pred_arrays``) instead of rebuilt
-    from trace-time member levels — the member-sharded ingest spelling:
-    inside the 2-D sharded gather's shard_map every device transforms
-    only its own member shard, so the member set differs per device and
-    cannot be a trace constant, but the predecessor DATA can be sharded
-    along G like the stack itself.
-
-    BIT-identity contract: with ``pred = member_pred_arrays(levels,
-    shape)`` this equals ``hierarchize_batched(x, levels)`` bitwise —
-    the method rule (``batched_method``) depends only on the bucket
-    shape, both methods get the identical per-axis operand content, and
-    every member's blocks are computed independently of the rest of the
-    batch, so any G-slice of (stack, pred) yields the same per-member
-    bits as the full stack."""
-    if method == "auto":
-        method = batched_method(x.shape[1:])
-    if method == "jnp":
-        d = x.ndim - 1
-        for k in range(d):
-            _count("einsum")
-            lp, rp, lm, rm = pred[4 * k:4 * k + 4]
-            ishape = [1] * (d + 1)
-            ishape[0], ishape[k + 1] = x.shape[0], x.shape[k + 1]
-            xl = jnp.take_along_axis(x, lp.reshape(ishape), axis=k + 1)
-            xr = jnp.take_along_axis(x, rp.reshape(ishape), axis=k + 1)
-            x = _hier3(x, xl, xr, lm.reshape(ishape), rm.reshape(ishape))
-        return x
-    if method != "pallas":
-        raise ValueError(f"unknown method {method!r}")
-    if x.ndim == 2:
-        out = hier_axis0_batched_pallas(x[..., None], None, pred=pred[:4],
-                                        interpret=interpret)
-        return out[..., 0]
-    y = hier_tail_batched_pallas(x, None, pred=pred[4:],
-                                 interpret=interpret)
-    g = y.shape[0]
-    shape = y.shape[1:]
-    flat = y.reshape(g, shape[0], -1)
-    flat = hier_axis0_batched_pallas(flat, None, pred=pred[:4],
+    flat = hier_axis0_batched_pallas(flat, lv[:, 0], inverse=inverse,
                                      interpret=interpret)
     return flat.reshape((g,) + shape)
 
@@ -901,25 +606,46 @@ def hier_flops(shape: Sequence[int], g: int = 1) -> int:
     return 4 * g * len(shape) * int(np.prod(shape, dtype=np.int64))
 
 
-def dehierarchize_batched(a: jnp.ndarray,
-                          member_levels: Sequence[Sequence[int]], *,
+def dehierarchize_batched(a: jnp.ndarray, member_levels, *,
                           interpret: bool | None = None,
                           method: str = "auto") -> jnp.ndarray:
     return hierarchize_batched(a, member_levels, inverse=True,
                                interpret=interpret, method=method)
 
 
+# ---------------------------------------------------------------------------
+# Single grids through the batched kernels (G = 1)
+# ---------------------------------------------------------------------------
+
+def _single(x, inverse, interpret):
+    levels = [tuple(_level_of(s) for s in x.shape)]
+    if x.ndim == 1:
+        return hier_axis0_batched_pallas(x[None, :, None], [levels[0][0]],
+                                         inverse=inverse,
+                                         interpret=interpret)[0, :, 0]
+    return hierarchize_batched(x[None], levels, inverse=inverse,
+                               interpret=interpret, method="pallas")[0]
+
+
+def hier_fused_tail_pallas(x: jnp.ndarray, *, inverse: bool = False,
+                           row_tile: int | None = None,
+                           vmem_budget_bytes: int = 4 * 1024 * 1024,
+                           interpret: bool | None = None) -> jnp.ndarray:
+    """(De)hierarchize axes 1..d-1 of one grid in ONE pass, tiling over
+    axis 0 (the batched tail kernel at G = 1)."""
+    if x.ndim < 2:
+        raise ValueError("need >= 2 dims; use apply_axis_matmul_pallas for 1-D")
+    levels = [tuple(_level_of(s) for s in x.shape)]
+    return hier_tail_batched_pallas(x[None], levels, inverse=inverse,
+                                    row_tile=row_tile,
+                                    vmem_budget_bytes=vmem_budget_bytes,
+                                    interpret=interpret)[0]
+
+
 def hierarchize_nd_fused(x: jnp.ndarray, *, interpret: bool | None = None) -> jnp.ndarray:
     """Full d-dim hierarchization in 2 HBM round trips (d>=2), 1 if d==1."""
-    if x.ndim == 1:
-        return apply_axis_matmul_pallas(x[:, None], interpret=interpret)[:, 0]
-    x = hier_fused_tail_pallas(x, interpret=interpret)
-    return hier_axis0_pallas(x, interpret=interpret)
+    return _single(x, False, interpret)
 
 
 def dehierarchize_nd_fused(a: jnp.ndarray, *, interpret: bool | None = None) -> jnp.ndarray:
-    if a.ndim == 1:
-        return apply_axis_matmul_pallas(a[:, None], inverse=True,
-                                        interpret=interpret)[:, 0]
-    a = hier_fused_tail_pallas(a, inverse=True, interpret=interpret)
-    return hier_axis0_pallas(a, inverse=True, interpret=interpret)
+    return _single(a, True, interpret)

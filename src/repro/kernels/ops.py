@@ -7,9 +7,12 @@
   * ``"gather"``    — one-shot linear-operator gather (jnp)
   * ``"pole"``      — Pallas pole kernel (paper-faithful over-vectorization)
   * ``"matmul"``    — Pallas per-axis MXU operator matmul
-  * ``"fused"``     — Pallas fused kernel, 2 HBM round trips for any d
-  * ``"auto"``      — fused when every axis fits the MXU-operator regime
-                      (N <= 2047), else per-axis ref loop
+  * ``"fused"``     — Pallas batched kernels at G = 1, 2 HBM round trips
+                      for any d
+  * ``"auto"``      — the executor's bucket rule at G = 1
+                      (``hierarchize.batched_method``): the ``"fused"``
+                      kernels unless tile padding would blow the grid up,
+                      else the padding-free jnp path
 """
 
 from __future__ import annotations
@@ -19,8 +22,6 @@ import numpy as np
 
 from repro.kernels import hierarchize as hk
 from repro.kernels import ref
-
-_MATMUL_MAX_N = 2047  # largest 2**l - 1 below the v5e compute/memory ridge (~1924)
 
 __all__ = ["hierarchize", "dehierarchize"]
 
@@ -39,12 +40,18 @@ def _per_axis(x, fn):
     return x
 
 
+def _auto(x, inverse, interpret):
+    levels = [tuple(hk._level_of(s) for s in x.shape)]
+    return hk.hierarchize_batched(x[None], levels, inverse=inverse,
+                                  interpret=interpret)[0]
+
+
 def hierarchize(x: jnp.ndarray, method: str = "auto", *,
                 interpret: bool | None = None,
                 reduced_op: bool = True) -> jnp.ndarray:
     """d-dimensional nodal -> hierarchical base change."""
     if method == "auto":
-        method = "fused" if max(x.shape) <= _MATMUL_MAX_N else "ref"
+        return _auto(x, False, interpret)
     if method == "func":
         out = np.asarray(x)
         for axis in range(out.ndim):
@@ -71,7 +78,7 @@ def dehierarchize(a: jnp.ndarray, method: str = "auto", *,
                   interpret: bool | None = None) -> jnp.ndarray:
     """d-dimensional hierarchical -> nodal base change (inverse)."""
     if method == "auto":
-        method = "fused" if max(a.shape) <= _MATMUL_MAX_N else "ref"
+        return _auto(a, True, interpret)
     if method == "func":
         out = np.asarray(a)
         for axis in range(out.ndim):

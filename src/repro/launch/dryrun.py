@@ -34,8 +34,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType, set_mesh
 from repro.configs import ARCH_IDS, SHAPE_BY_NAME, get_config, shape_cells
 from repro.launch import sharding as rules
 from repro.launch.analysis import collective_bytes, roofline_from_artifacts
@@ -133,8 +132,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         dims = tuple(int(x) for x in mesh_shape.split("x"))
         names = ("data", "model") if len(dims) == 2 else \
             ("pod", "data", "model")
-        mesh = make_mesh(dims, names,
-                         axis_types=(AxisType.Auto,) * len(dims))
+        mesh = jax.make_mesh(dims, names,
+                             axis_types=(AxisType.Auto,) * len(dims))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     cfg = cfg.replace(batch_axes=batch_axes(mesh),
@@ -155,7 +154,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
             compiled = lowered.compile()
             t_compile = time.time()
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         # scan-aware accounting (repro.launch.hlo_cost): XLA's cost_analysis
         # counts while bodies ONCE; our programs scan over layers/chunks, so

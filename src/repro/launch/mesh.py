@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "batch_axes", "CHIPS_PER_POD"]
 
@@ -19,15 +19,15 @@ CHIPS_PER_POD = 256
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_smoke_mesh():
     """Whatever devices exist (1 on the CPU container), same axis names."""
     n = jax.device_count()
-    return make_mesh((1, n), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def batch_axes(mesh) -> tuple:

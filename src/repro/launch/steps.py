@@ -136,4 +136,4 @@ def _bind_ct_transform(scheme, spec) -> Callable:
             nodal_grids, scheme, spec.mesh, spec.axis_name, plan=plan,
             spec=inner)
     return lambda nodal_grids: ct_transform_with_plan(
-        nodal_grids, plan, interpret=spec.interpret, fused=spec.fused)
+        nodal_grids, plan, interpret=spec.interpret)

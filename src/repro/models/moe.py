@@ -25,9 +25,20 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh, shard_map
 
 __all__ = ["moe_ffn", "moe_ffn_ep", "router_topk"]
+
+
+def _ambient_mesh():
+    """The mesh of the enclosing ``jax.sharding.set_mesh`` context, else
+    the one a classic ``with mesh:`` block installed (which does not set
+    the abstract mesh), else None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is not None and mesh.shape:
+        return mesh
+    from jax._src import mesh as mesh_lib
+    phys = mesh_lib.thread_resources.env.physical_mesh
+    return None if phys.empty else phys
 
 
 def router_topk(x, w_router, num_experts: int, k: int):
@@ -69,7 +80,7 @@ def moe_ffn_ep(x, params, *, num_experts: int, k: int,
 
     Returns None when no usable mesh context exists (caller falls back).
     """
-    mesh = get_abstract_mesh()
+    mesh = _ambient_mesh()
     if mesh is None or not getattr(mesh, "shape", None) or \
             axis_name not in mesh.shape:
         return None
@@ -144,7 +155,7 @@ def moe_ffn_ep(x, params, *, num_experts: int, k: int,
         return jax.lax.psum(out, axis_name)
 
     bspec = data_axes if data_axes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, None), P(bspec, None),
                   P(bspec, None),

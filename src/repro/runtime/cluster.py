@@ -697,7 +697,6 @@ class CTCluster:
         their host."""
         import jax
 
-        from repro.compat import make_mesh
         devices = list(jax.devices()) if devices is None else list(devices)
         per = len(devices) // n_hosts
         if per < 1:
@@ -711,13 +710,13 @@ class CTCluster:
         for i in range(n_hosts):
             sl = np.array(devices[i * per:(i + 1) * per])
             if members > 1:
-                mesh = make_mesh((members, per // members),
-                                 (member_axis, axis_name), devices=sl)
+                mesh = jax.make_mesh((members, per // members),
+                                     (member_axis, axis_name), devices=sl)
                 specs.append(ExecSpec(mesh=mesh, axis_name=axis_name,
                                       member_axis=member_axis))
             else:
                 specs.append(ExecSpec(
-                    mesh=make_mesh((len(sl),), (axis_name,), devices=sl),
+                    mesh=jax.make_mesh((len(sl),), (axis_name,), devices=sl),
                     axis_name=axis_name))
         return cls(host_specs=specs, **kwargs)
 
@@ -757,7 +756,7 @@ class CTCluster:
 
     def _host_exec_spec(self, host: _Host, tspec: ExecSpec) -> ExecSpec:
         """Placement decides the execution environment: the tenant's
-        exec prefs (merge/fused/dtype/donate) combined with the HOST's
+        exec prefs (merge/dtype/donate) combined with the HOST's
         mesh (or lack of one)."""
         if host.spec.mesh is not None:
             return dataclasses.replace(tspec, mesh=host.spec.mesh,

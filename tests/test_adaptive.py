@@ -6,6 +6,7 @@ oracle: random downward-closed index sets must round-trip through the
 batched executor exactly like the regular schemes do in test_executor.py.
 """
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -362,9 +363,9 @@ def test_ct_surrogate_on_mesh_matches_single_device_and_fault():
     """CTSurrogate with the opt-in ``mesh=`` runs the slab-sharded ingest:
     queries, drop_grid (coefficient-only path) and post-fault updates all
     equal the single-device surrogate bit-for-bit."""
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.launch.serve import CTSurrogate
-    mesh = make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
     gs = GeneralScheme.from_levels([(4, 1), (3, 2), (2, 3), (1, 4)],
                                    close=True)
     u = lambda a, b: jnp.sin(2 * a) * (b - b * b)
@@ -394,9 +395,9 @@ def test_ct_surrogate_on_mesh_fault_fallback_path():
     """The extend_plan fallback (dropping (2,2) activates (1,1)) also works
     on a mesh: failure leaves the surrogate unchanged, success re-shards
     the extended plan and matches the serial recombination."""
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.launch.serve import CTSurrogate
-    mesh = make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
     gs = GeneralScheme.regular(2, 3)
     u = lambda a, b: jnp.sin(2 * a) * (b - b * b)
     grids = {ell: sample_function(u, ell) for ell, _ in gs.grids}

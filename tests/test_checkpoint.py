@@ -76,8 +76,8 @@ def test_restore_with_sharding_placement(tmp_path):
     """Restore accepts NamedSharding for the current (here 1-device) mesh —
     the elastic-resize path."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import AxisType, make_mesh
-    mesh = make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     tree = {"w": jnp.ones((8, 2), jnp.float32)}
     save_checkpoint(str(tmp_path), 2, tree)
     sh = {"w": NamedSharding(mesh, P("data", None))}

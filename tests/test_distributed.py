@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType, set_mesh
 from repro.core import combination as comb
 from repro.core.distributed import (comm_phase_sharded, ct_transform_psum,
                                     ct_transform_sharded,
@@ -40,7 +40,7 @@ def no_x64():
 
 
 def _mesh8():
-    return make_mesh((8,), ("grid",), axis_types=(AxisType.Auto,))
+    return jax.make_mesh((8,), ("grid",), axis_types=(AxisType.Auto,))
 
 
 def test_sharded_hierarchization_matches_local():
@@ -165,8 +165,8 @@ def test_dp_training_step_matches_single_device(no_x64):
     batch = M.make_batch(cfg, ShapeConfig("t", 32, 8, "train"), key)
     step = make_train_step(cfg, constant(1e-3))
     l1 = float(step(params, opt, batch)[2]["loss"])
-    mesh = make_mesh((8, 1), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((8, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     named = lambda t: jax.tree.map(
         lambda s: NamedSharding(mesh, s), t,
         is_leaf=lambda x: isinstance(x, P))
@@ -202,8 +202,8 @@ def test_elastic_remesh_restore(tmp_path, no_x64):
 
     def run_on(n_devs, params, opt, steps, start):
         plan = plan_mesh(n_devs, chips_per_pod=8, preferred_model=2)
-        mesh = make_mesh(plan.shape(), plan.axes(),
-                         axis_types=(AxisType.Auto,) * len(plan.axes()))
+        mesh = jax.make_mesh(plan.shape(), plan.axes(),
+                             axis_types=(AxisType.Auto,) * len(plan.axes()))
         named = lambda t: jax.tree.map(
             lambda s: NamedSharding(mesh, s), t,
             is_leaf=lambda x: isinstance(x, P))
@@ -242,8 +242,8 @@ def test_ep_moe_matches_ragged(no_x64):
     """Expert-parallel shard_map dispatch == exact ragged dispatch at high
     capacity, and gradients flow (the production MoE path, §Perf)."""
     from repro.models.moe import moe_ffn, moe_ffn_ep
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     e, d, f, b, s, k = 8, 16, 32, 4, 12, 2
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     params = {
@@ -281,19 +281,18 @@ def test_ep_moe_no_mesh_fallback():
 def test_dryrun_single_cell_smallpod(no_x64):
     """The dry-run machinery itself (build_cell + analysis) on an 8-chip
     mesh — fast proxy for the 256/512-chip sweep recorded in EXPERIMENTS."""
-    from repro.compat import cost_analysis
     from repro.configs import get_config
     from repro.launch.dryrun import build_cell
     from repro.launch.analysis import collective_bytes
     from repro.models.config import ShapeConfig
     cfg = get_config("smollm_360m")
     shape = ShapeConfig("t", 256, 8, "train")
-    mesh = make_mesh((4, 2), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     fn, args = build_cell(cfg, shape, mesh)
     with mesh:
         compiled = fn.lower(*args).compile()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     assert cost.get("flops", 0) > 0
     coll = collective_bytes(compiled.as_text())
     assert sum(coll.values()) > 0, coll

@@ -53,7 +53,7 @@ def _fresh_caches():
 
 def test_execspec_defaults_and_resolution():
     spec = ExecSpec()
-    assert spec.slabs == 1 and spec.merge is None and spec.fused is None
+    assert spec.slabs == 1 and spec.merge is None
     assert spec.resolve_interpret() == (jax.default_backend() != "tpu")
     assert ExecSpec(dtype=jnp.float32).dtype == "float32"
     assert ExecSpec(n_slabs=4).slabs == 4
@@ -546,11 +546,11 @@ def test_legacy_surrogate_kwargs_warn_once():
 
 @pytest.mark.multidevice
 def test_legacy_mesh_and_sharded_plan_kwargs_warn_once():
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.core.distributed import ct_transform_sharded
     from repro.core.executor import shard_plan
     from repro.launch.serve import CTSurrogate
-    mesh = make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
     scheme = GeneralScheme.regular(2, 4)
     grids = _random_grids(scheme, np.random.default_rng(15))
     splan = shard_plan(build_plan(scheme), 8)
@@ -576,22 +576,22 @@ def test_legacy_mesh_and_sharded_plan_kwargs_warn_once():
 
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
-        fused_legacy = ct_transform_sharded(grids, scheme, mesh, "slab",
-                                            fused=False)
+        interp_legacy = ct_transform_sharded(grids, scheme, mesh, "slab",
+                                             interpret=True)
         assert len(_deprecations(w)) == 1
     np.testing.assert_array_equal(
-        np.asarray(fused_legacy),
+        np.asarray(interp_legacy),
         np.asarray(ct_transform_sharded(grids, scheme, mesh, "slab",
-                                        spec=ExecSpec(fused=False))))
+                                        spec=ExecSpec(interpret=True))))
 
 
 @pytest.mark.multidevice
 def test_make_ct_step_honors_meshed_spec():
     """``make_ct_step(spec=ExecSpec(mesh=...))`` binds the slab-sharded
     gather (precedence rule 4), bit-identical to the single-device step."""
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.launch.steps import make_ct_step
-    mesh = make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
     scheme = GeneralScheme.regular(2, 4)
     grids = _random_grids(scheme, np.random.default_rng(18))
     step = make_ct_step(scheme, spec=ExecSpec(mesh=mesh))
@@ -604,8 +604,8 @@ def test_meshed_spec_routes_ct_transform_and_engine_shares_executable():
     """``ct_transform(spec=ExecSpec(mesh=...))`` routes the slab-sharded
     gather; two meshed tenants with one signature share one executable
     and match the single-device result bit-for-bit."""
-    from repro.compat import AxisType, make_mesh
-    mesh = make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
     scheme = GeneralScheme.regular(2, 4)
     rng = np.random.default_rng(16)
     ga, gb = _random_grids(scheme, rng), _random_grids(scheme, rng)
@@ -804,9 +804,9 @@ def test_rebind_offmesh_reuses_executable_and_surplus():
 def test_rebalance_engine_onto_and_off_a_mesh():
     """The elastic fast lane end to end: tenants move onto a slab mesh
     and back WITHOUT surplus recomputation, bit-identical serving."""
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.runtime.elastic import rebalance_engine
-    mesh = make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
     scheme = GeneralScheme.regular(2, 4)
     rng = np.random.default_rng(27)
     eng = CTEngine()

@@ -266,7 +266,13 @@ def test_batched_pallas_matches_jnp(shape, levels):
     xj = jnp.asarray(x)
     a = np.asarray(hierarchize_batched(xj, levels, method="pallas"))
     b = np.asarray(hierarchize_batched_jnp(xj, levels))
-    np.testing.assert_allclose(a, b, rtol=1e-12, atol=1e-14)
+    # same neighbours, masks, update and axis order: bitwise, both ways
+    np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(
+        np.asarray(hierarchize_batched(jnp.asarray(a), levels, inverse=True,
+                                       method="pallas")),
+        np.asarray(hierarchize_batched_jnp(jnp.asarray(a), levels,
+                                           inverse=True)))
     for g, lv in enumerate(levels):
         sl = tuple(slice(0, (1 << l) - 1) for l in lv)
         want = np.asarray(hierarchize(xj[g][sl], "ref"))

@@ -41,8 +41,7 @@ def test_scan_multiplies_trip_count():
     assert got.flops == pytest.approx(20 * N ** 3, rel=0.02)
     assert 10 in got.while_trips.values()
     # ... and XLA's own cost_analysis does NOT (the reason this module exists)
-    from repro.compat import cost_analysis
-    xla = cost_analysis(c).get("flops", 0.0)
+    xla = c.cost_analysis().get("flops", 0.0)
     assert xla < 0.2 * got.flops
 
 

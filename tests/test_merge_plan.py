@@ -1,4 +1,4 @@
-"""Cost-model-driven bucket merging + fused scatter-add epilogue.
+"""Cost-model-driven bucket merging.
 
 Three layers:
 
@@ -8,12 +8,12 @@ Three layers:
       order, and incremental rebuilds of merged plans are bit-identical
       to from-scratch merged builds.
   (b) seeded end-to-end property tests of below-target (padded) bucket
-      members: merged+fused ``ct_transform`` bit-identical (f64; 1e-6 at
-      f32) to the unmerged unfused path over random downward-closed
-      schemes, ``ct_scatter`` / ``ct_embedded`` through merged plans
-      against the unmerged oracle.
-  (c) the sharded gather consuming the same fused epilogue with per-slab
-      local maps (multidevice tier).
+      members: merged ``ct_transform`` bit-identical (f64; 1e-6 at f32)
+      to the unmerged path over random downward-closed schemes,
+      ``ct_scatter`` / ``ct_embedded`` through merged plans against the
+      unmerged oracle.
+  (c) the sharded gather through merged and Pallas-path plans with
+      per-slab local maps (multidevice tier).
 """
 
 import numpy as np
@@ -23,11 +23,11 @@ import pytest
 from proptest import cases, integers, seeds
 
 from repro.core.executor import (MergeConfig, build_plan, bucket_surpluses,
-                                 bucket_tail_surpluses, ct_embedded_with_plan,
-                                 ct_scatter_with_plan, ct_transform,
-                                 ct_transform_with_plan, extend_plan,
-                                 plan_fused_ok, plan_launch_stats, shard_plan,
+                                 ct_embedded_with_plan, ct_scatter_with_plan,
+                                 ct_transform, ct_transform_with_plan,
+                                 extend_plan, plan_launch_stats, shard_plan,
                                  update_plan_coefficients)
+from repro.kernels.hierarchize import batched_method
 from repro.core.levels import (CombinationScheme, GeneralScheme,
                                admissible_extensions, canonical_levels,
                                grid_shape)
@@ -202,17 +202,17 @@ def test_merged_shard_plan_partitions_like_base():
     lambda r: (integers(r, 2, 3), integers(r, 2, 8),
                ("float32", "float64")[integers(r, 0, 1)], seeds(r)), n=12))
 def test_merged_fused_transform_matches_unmerged(dim, steps, dtype, seed):
-    """Random downward-closed schemes x dtypes: merged plan + fused
-    epilogue == unmerged unfused path — bit-identical at f64, 1e-6 at
-    f32 (the fused epilogue and the 3-term kernels are bitwise exact;
-    the f32 tolerance only covers platforms whose scatter departs)."""
+    """Random downward-closed schemes x dtypes: merged plan == unmerged
+    path — bit-identical at f64, 1e-6 at f32 (the 3-term kernels are
+    bitwise padding-independent; the f32 tolerance only covers
+    platforms whose scatter departs)."""
     gs = _random_general_scheme(seed, dim, steps)
     grids = _random_grids(gs, np.random.default_rng(seed), np.dtype(dtype))
     plain = build_plan(gs)
     merged = build_plan(gs, merge=AGGRESSIVE)
-    want = np.asarray(ct_transform_with_plan(grids, plain, fused=False))
-    for plan, fused in ((plain, True), (merged, None), (merged, False)):
-        got = np.asarray(ct_transform_with_plan(grids, plan, fused=fused))
+    want = np.asarray(ct_transform_with_plan(grids, plain))
+    for plan in (merged, build_plan(gs, merge=MergeConfig())):
+        got = np.asarray(ct_transform_with_plan(grids, plan))
         assert got.dtype == want.dtype
         if dtype == "float64":
             np.testing.assert_array_equal(got, want)
@@ -255,29 +255,30 @@ def test_merged_embedded_matches_unmerged():
         np.testing.assert_array_equal(g0[ell], g1[ell])
 
 
-def test_fused_epilogue_engages_on_pallas_plan():
-    """A near-square scheme takes the Pallas path end to end: the fused
-    default removes the compact-stack round trip from the plan-derived
-    accounting and stays bit-identical to every other path."""
-    gs = GeneralScheme.from_levels([(6, 5), (5, 6)], close=True)
-    plan = build_plan(gs)
-    assert plan_fused_ok(plan)
-    s_unfused = plan_launch_stats(plan, fused=False)
-    s_fused = plan_launch_stats(plan)
-    assert s_fused["stack_bytes"] == 0 < s_unfused["stack_bytes"]
-    assert s_fused["scatter_dispatches"] == 0
-    grids = _random_grids(gs, np.random.default_rng(4))
-    want = np.asarray(ct_transform_with_plan(grids, plan, fused=False))
-    np.testing.assert_array_equal(
-        np.asarray(ct_transform_with_plan(grids, plan)), want)
-    merged = build_plan(gs, merge=MergeConfig())
+#: a near-square scheme whose every bucket takes the Pallas path
+_PALLAS_SCHEME = GeneralScheme.from_levels([(6, 5), (5, 6)], close=True)
+
+
+def test_pallas_plan_accounting_and_merge_bitwise():
+    """The near-square scheme runs the Pallas kernels for most of its
+    volume: the plan-derived accounting counts one scatter per bucket
+    and the kernel launches, and a merged plan stays bit-identical."""
+    plan = build_plan(_PALLAS_SCHEME)
+    assert batched_method(plan.buckets[0].shape) == "pallas"
+    stats = plan_launch_stats(plan)
+    assert stats["scatter_dispatches"] == len(plan.buckets)
+    assert stats["pallas_launches"] >= 2 and stats["stack_bytes"] > 0
+    grids = _random_grids(_PALLAS_SCHEME, np.random.default_rng(4))
+    want = np.asarray(ct_transform_with_plan(grids, plan))
+    merged = build_plan(_PALLAS_SCHEME, merge=MergeConfig())
     np.testing.assert_array_equal(
         np.asarray(ct_transform_with_plan(grids, merged)), want)
 
 
 def test_fused_transform_jits_once():
-    """The fused epilogue keeps the one-trace contract of the executor."""
-    gs = GeneralScheme.from_levels([(6, 5), (5, 6)], close=True)
+    """The Pallas-path transform keeps the one-trace contract of the
+    executor."""
+    gs = _PALLAS_SCHEME
     plan = build_plan(gs, merge=MergeConfig())
     traces = []
 
@@ -293,13 +294,13 @@ def test_fused_transform_jits_once():
 
 
 # ---------------------------------------------------------------------------
-# (c) sharded gather through merged plans / fused epilogue
+# (c) sharded gather through merged plans / Pallas-path plans
 # ---------------------------------------------------------------------------
 
 def _mesh(n, name="slab"):
-    from repro.compat import AxisType, make_mesh
-    return make_mesh((n,), (name,), devices=np.array(jax.devices()[:n]),
-                     axis_types=(AxisType.Auto,))
+    from jax.sharding import AxisType
+    return jax.make_mesh((n,), (name,), devices=np.array(jax.devices()[:n]),
+                         axis_types=(AxisType.Auto,))
 
 
 @pytest.mark.multidevice
@@ -322,20 +323,13 @@ def test_sharded_gather_merged_plan_matches_single_device(dim, steps,
 
 @pytest.mark.multidevice
 @pytest.mark.parametrize("n_groups", [2, 5, 8])
-def test_sharded_fused_epilogue_matches_unfused(n_groups):
-    """gather_slab_scatter_fused (per-slab local maps through the fused
-    kernel) == gather_slab_scatter (compact stacks + .at[].add), bitwise,
-    ragged slabs included."""
-    from repro.core.distributed import (gather_slab_scatter,
-                                        gather_slab_scatter_fused)
-    gs = GeneralScheme.from_levels([(6, 5), (5, 6)], close=True)
-    grids = _random_grids(gs, np.random.default_rng(n_groups))
-    splan = shard_plan(build_plan(gs), n_groups)
-    assert plan_fused_ok(splan)
-    mesh = _mesh(n_groups)
-    want = np.asarray(gather_slab_scatter(
-        bucket_surpluses(grids, splan), splan, mesh, "slab"))
-    got = np.asarray(gather_slab_scatter_fused(
-        bucket_tail_surpluses(grids, splan), splan, mesh, "slab"))
-    np.testing.assert_array_equal(got, want)
-    np.testing.assert_array_equal(want, np.asarray(ct_transform(grids, gs)))
+def test_sharded_pallas_plan_matches_single_device(n_groups):
+    """gather_slab_scatter over a Pallas-path plan (per-slab local maps,
+    ragged slabs included) == single-device ct_transform, bitwise."""
+    from repro.core.distributed import gather_slab_scatter
+    grids = _random_grids(_PALLAS_SCHEME, np.random.default_rng(n_groups))
+    splan = shard_plan(build_plan(_PALLAS_SCHEME), n_groups)
+    got = np.asarray(gather_slab_scatter(
+        bucket_surpluses(grids, splan), splan, _mesh(n_groups), "slab"))
+    np.testing.assert_array_equal(
+        got, np.asarray(ct_transform(grids, _PALLAS_SCHEME)))

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 from proptest import cases, integers, seeds
 
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.core.distributed import ct_transform_sharded
 from repro.core.engine import CTEngine, ExecSpec
 from repro.core.executor import (build_plan, ct_transform,
@@ -40,9 +40,9 @@ def _random_grids(scheme, rng, dtype=np.float64):
 
 
 def _mesh2d(m, s):
-    return make_mesh((m, s), ("member", "slab"),
-                     devices=np.array(jax.devices()[:m * s]),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((m, s), ("member", "slab"),
+                         devices=np.array(jax.devices()[:m * s]),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 # ---------------------------------------------------------------------------

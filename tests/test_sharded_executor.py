@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 from proptest import cases, integers, seeds
 
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.core.distributed import ct_transform_sharded, gather_slab_scatter
 from repro.core.executor import (build_plan, bucket_surpluses, ct_transform,
                                  ct_transform_with_plan, extend_plan,
@@ -40,8 +40,8 @@ def _random_grids(scheme, rng, dtype=np.float64):
 
 
 def _mesh(n, name="slab"):
-    return make_mesh((n,), (name,), devices=np.array(jax.devices()[:n]),
-                     axis_types=(AxisType.Auto,))
+    return jax.make_mesh((n,), (name,), devices=np.array(jax.devices()[:n]),
+                         axis_types=(AxisType.Auto,))
 
 
 # ---------------------------------------------------------------------------
