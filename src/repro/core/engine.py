@@ -128,8 +128,14 @@ point's hat-basis contraction is independent of the batching.  ``refit``
 / ``extend`` / ``drop_grid`` route through the incremental plan paths
 (``extend_plan`` / ``recombine_after_fault``) per tenant; ``rebind``
 re-shards a tenant onto a new mesh/slab layout WITHOUT recomputing its
-surplus (the elastic-rebalance fast lane); ``stats()`` aggregates
-``plan_launch_stats`` with the compile-cache and scheduler counters.
+surplus (the elastic-rebalance fast lane); ``stats()`` reports the
+compile-cache, eval and scheduler counters.
+
+**Host spans.**  The scheduler pass and sleep, each ingest and each
+eval batch, and their phases open named ``jax.profiler`` spans (the
+``SPAN_*`` constants): under a profiler session they land on the
+device trace's clock, so a device-idle gap can be put down to the
+engine step that was running; with no session they record nothing.
 
 ``repro.launch.serve.CTSurrogate`` is a thin single-tenant view over a
 private engine.
@@ -170,6 +176,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import math
 import os
 import threading
@@ -181,14 +188,14 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import lockdep as _lockdep
 
 from repro.core.executor import (ExecutorPlan, MergeConfig, ShardedPlan,
                                  _assemble_members, _check_nodal_grids,
                                  _gather_one_bucket, build_plan, extend_plan,
-                                 plan_launch_stats, reset_legacy_warnings,
-                                 shard_plan)
+                                 reset_legacy_warnings, shard_plan)
 from repro.core.interpolation import interpolate_hierarchical
 from repro.core.levels import SchemeLike
 from repro.kernels.hierarchize import hierarchize_batched, interpret_default
@@ -517,6 +524,44 @@ _EVAL_BATCHED = jax.jit(jax.vmap(interpolate_hierarchical))
 
 #: Jitted device-side finiteness probe for ``check_finite`` ingests.
 _FINITE_CHECK = jax.jit(lambda x: jnp.all(jnp.isfinite(x)))
+
+# Host spans: ``jax.profiler`` TraceMe events, recorded on the profiler's
+# clock (the device trace's) only while a profiler session runs, and a
+# no-op otherwise.  Children nest on their parent's thread.
+#: a scheduler-thread pass that took work: ``_run`` of what it took
+SPAN_SCHED_PASS = "ct.sched.pass"
+#: the scheduler thread waiting on the work condition
+SPAN_SCHED_SLEEP = "ct.sched.sleep"
+#: one ingest on its chain's thread, dispatch through watermark (``seq``)
+SPAN_INGEST = "ct.ingest"
+#: host to device: ``jnp.asarray`` of every component grid
+SPAN_INGEST_TRANSFER = "ct.ingest.transfer"
+#: the call of the ingest executable
+SPAN_INGEST_LAUNCH = "ct.ingest.launch"
+#: ``block_until_ready`` on the new surplus
+SPAN_INGEST_WAIT = "ct.ingest.wait"
+#: the ``check_finite`` round trip
+SPAN_INGEST_CHECK = "ct.ingest.check"
+#: the commit's lock and compare-and-swap
+SPAN_INGEST_COMMIT = "ct.ingest.commit"
+#: one batched eval chunk through its futures (``rows``, ``tpad``)
+SPAN_QUERY_BATCH = "ct.query.batch"
+#: the surplus rows (and zero rows) stacked into the batch
+SPAN_QUERY_STACK = "ct.query.stack"
+#: the padded point array and its ``jnp.asarray``
+SPAN_QUERY_POINTS = "ct.query.points"
+#: the call of the batched eval
+SPAN_QUERY_LAUNCH = "ct.query.launch"
+#: ``block_until_ready`` on the batch's answers
+SPAN_QUERY_WAIT = "ct.query.wait"
+#: one answer's slice and device-to-host copy, on the thread that reads it
+SPAN_QUERY_FETCH = "ct.query.fetch"
+
+
+def _fetch_answer(out, i: int, q: int) -> np.ndarray:
+    """Row ``i`` of a batch's answers, unpadded, on the host."""
+    with TraceAnnotation(SPAN_QUERY_FETCH):
+        return np.asarray(out[i, :q])
 
 #: How long a draining flush waits for another thread's in-flight ingest
 #: before failing the dependent query futures with TimeoutError.
@@ -990,9 +1035,11 @@ class CTEngine:
         _lockdep.note_dispatch("engine._dispatch_ingest")
         base = tenant.base_plan
         _check_nodal_grids(nodal_grids, base)
-        parts = tuple(jnp.asarray(nodal_grids[ell])
-                      for b in base.buckets for ell in b.ells)
-        return tenant.executable(parts, tenant.idxs, tenant.coeffs)
+        with TraceAnnotation(SPAN_INGEST_TRANSFER):
+            parts = tuple(jnp.asarray(nodal_grids[ell])
+                          for b in base.buckets for ell in b.ells)
+        with TraceAnnotation(SPAN_INGEST_LAUNCH):
+            return tenant.executable(parts, tenant.idxs, tenant.coeffs)
 
     # -- thread-safe submission ---------------------------------------------
 
@@ -1243,13 +1290,15 @@ class CTEngine:
                 seq = self._work_seq
                 take, next_wake = self._take_due(now)
             if take:
-                did = self._run(take, drain=False)
+                with TraceAnnotation(SPAN_SCHED_PASS):
+                    did = self._run(take, drain=False)
                 if did == 0:
                     # everything requeued (queries waiting on in-flight
                     # ingests): block briefly instead of spinning
                     with self._work:
                         if self._work_seq == seq:
-                            self._work.wait(0.01)
+                            with TraceAnnotation(SPAN_SCHED_SLEEP):
+                                self._work.wait(0.01)
                 continue
             with self._work:
                 if self._work_seq != seq:
@@ -1257,7 +1306,8 @@ class CTEngine:
                 delay = 0.05
                 if next_wake is not None:
                     delay = min(delay, next_wake - time.monotonic())
-                self._work.wait(max(delay, 0.001))
+                with TraceAnnotation(SPAN_SCHED_SLEEP):
+                    self._work.wait(max(delay, 0.001))
 
     def _take_due(self, now: float) -> Tuple[List[_Request],  # ctlint: holds(engine)
                                              Optional[float]]:
@@ -1376,20 +1426,22 @@ class CTEngine:
         for req in reqs:
             grids, check, tag = req.payload
             committed = None
-            try:
-                surplus = self._ingest_one(req.name, grids, check,
-                                           req.ingest_seq)
-            except Exception as exc:
-                req.future._set_error(exc)
-            else:
-                req.future._set(surplus)
-                committed = surplus
-            finally:
-                with self._work:
-                    if req.ingest_seq > self._ingest_done.get(req.name, 0):
-                        self._ingest_done[req.name] = req.ingest_seq
-                    self._work_seq += 1
-                    self._work.notify_all()
+            with TraceAnnotation(SPAN_INGEST, seq=req.ingest_seq):
+                try:
+                    surplus = self._ingest_one(req.name, grids, check,
+                                               req.ingest_seq)
+                except Exception as exc:
+                    req.future._set_error(exc)
+                else:
+                    req.future._set(surplus)
+                    committed = surplus
+                finally:
+                    with self._work:
+                        if req.ingest_seq > self._ingest_done.get(
+                                req.name, 0):
+                            self._ingest_done[req.name] = req.ingest_seq
+                        self._work_seq += 1
+                        self._work.notify_all()
             if committed is not None:
                 # AFTER the ack and the watermark advance: a snapshot is
                 # an optimization of future recovery, never on the ack
@@ -1427,8 +1479,12 @@ class CTEngine:
             surplus = self._dispatch_ingest(tenant, nodal_grids)
             # device-side failures surface HERE, on the owning request —
             # never from a sibling's flush
-            jax.block_until_ready(surplus)
-            if check_finite and not bool(_FINITE_CHECK(surplus)):
+            with TraceAnnotation(SPAN_INGEST_WAIT):
+                jax.block_until_ready(surplus)
+            if check_finite:
+                with TraceAnnotation(SPAN_INGEST_CHECK):
+                    finite = bool(_FINITE_CHECK(surplus))
+            if check_finite and not finite:
                 if tenant.spec.donate:
                     raise IngestBuffersDonated(
                         f"ingest for tenant {name!r} produced non-finite "
@@ -1438,7 +1494,7 @@ class CTEngine:
                 raise FloatingPointError(
                     f"ingest for tenant {name!r} produced non-finite "
                     f"surplus values")
-            with self._work:
+            with TraceAnnotation(SPAN_INGEST_COMMIT), self._work:
                 cur = self._tenants.get(name)
                 if cur is None:
                     raise KeyError(f"tenant {name!r} was unregistered "
@@ -1564,36 +1620,46 @@ class CTEngine:
                 else:
                     chunks.append([e])
             for chunk in chunks:
-                try:
-                    # pad the BATCH axis to a power of two as well (>= 4):
-                    # under deadline dispatch the group size varies per
-                    # window, and an unpadded T would recompile the
-                    # batched eval for every new size
-                    tpad = max(4, 1 << max(0, len(chunk) - 1).bit_length())
-                    rows = [s for _, s, _ in chunk]
-                    rows += [jnp.zeros_like(rows[0])] * (tpad - len(chunk))
-                    surp = jnp.stack(rows)
-                    dim = chunk[0][2]
-                    padded = np.zeros((tpad, qpad, dim), pts_dtype)
-                    for i, (r, _, _) in enumerate(chunk):
-                        points, q, _ = r.payload
-                        padded[i, :q] = points
-                    out = _EVAL_BATCHED(surp, jnp.asarray(padded))
-                    jax.block_until_ready(out)
-                except Exception as exc:
-                    for r, _, _ in chunk:
-                        r.future._set_error(exc)
-                else:
-                    for i, (r, _, _) in enumerate(chunk):
-                        q = r.payload[1]
-                        r.future._set(
-                            lambda out=out, i=i, q=q: np.asarray(out[i, :q]))
-                    with self._lock:
-                        self._counters["eval_batches"] += 1
-                        self._counters["queries"] += len(chunk)
-                        self._counters["coalesced_queries"] += len(chunk) - 1
+                # pad the BATCH axis to a power of two as well (>= 4):
+                # under deadline dispatch the group size varies per
+                # window, and an unpadded T would recompile the batched
+                # eval for every new size
+                tpad = max(4, 1 << max(0, len(chunk) - 1).bit_length())
+                with TraceAnnotation(SPAN_QUERY_BATCH, rows=len(chunk),
+                                     tpad=tpad):
+                    self._eval_chunk(chunk, tpad, qpad, pts_dtype)
                 count += len(chunk)
         return count
+
+    def _eval_chunk(self, chunk, tpad: int, qpad: int, pts_dtype) -> None:
+        """One batched eval of ``chunk`` padded to ``tpad`` rows; resolves
+        every future of the chunk, with the answers or the error."""
+        try:
+            with TraceAnnotation(SPAN_QUERY_STACK):
+                rows = [s for _, s, _ in chunk]
+                rows += [jnp.zeros_like(rows[0])] * (tpad - len(chunk))
+                surp = jnp.stack(rows)
+            with TraceAnnotation(SPAN_QUERY_POINTS):
+                padded = np.zeros((tpad, qpad, chunk[0][2]), pts_dtype)
+                for i, (r, _, _) in enumerate(chunk):
+                    points, q, _ = r.payload
+                    padded[i, :q] = points
+                pts = jnp.asarray(padded)
+            with TraceAnnotation(SPAN_QUERY_LAUNCH):
+                out = _EVAL_BATCHED(surp, pts)
+            with TraceAnnotation(SPAN_QUERY_WAIT):
+                jax.block_until_ready(out)
+        except Exception as exc:
+            for r, _, _ in chunk:
+                r.future._set_error(exc)
+            return
+        for i, (r, _, _) in enumerate(chunk):
+            r.future._set(functools.partial(_fetch_answer, out, i,
+                                            r.payload[1]))
+        with self._lock:
+            self._counters["eval_batches"] += 1
+            self._counters["queries"] += len(chunk)
+            self._counters["coalesced_queries"] += len(chunk) - 1
 
     # -- synchronous conveniences -------------------------------------------
 
@@ -1929,26 +1995,15 @@ class CTEngine:
     # -- accounting ---------------------------------------------------------
 
     def stats(self) -> Dict[str, Any]:
-        """Aggregated serving statistics: per-tenant and summed
-        ``plan_launch_stats`` (the plan-derived dispatch/HBM accounting
-        of ONE ingest), the shared compile-cache counters, the
-        continuous-batching eval counters, and the scheduler's
-        dispatch/backpressure accounting."""
+        """Aggregated serving statistics: the ingest count, the shared
+        compile-cache counters, the continuous-batching eval counters,
+        the scheduler's dispatch/backpressure accounting and the durable
+        store's."""
         with self._lock:
             tenants = dict(self._tenants)
             counters = dict(self._counters)
             sched = dict(self._sched)
             pending = len(self._pending)
-        per_tenant = {}
-        gather = {"buckets": 0, "members": 0, "launches": 0,
-                  "pallas_launches": 0, "einsum_dispatches": 0,
-                  "scatter_dispatches": 0, "transform_bytes": 0,
-                  "stack_bytes": 0}
-        for name, t in tenants.items():
-            s = plan_launch_stats(t.plan)
-            per_tenant[name] = s
-            for k in gather:
-                gather[k] += s[k]
         # count over the LIVE tenants' executables (dedup by identity) —
         # an executable evicted from the LRU cache keeps serving its
         # tenants and must keep being counted
@@ -1959,8 +2014,6 @@ class CTEngine:
         return {
             "host_id": self.host_id,
             "tenants": len(tenants),
-            "per_tenant": per_tenant,
-            "gather": gather,
             "ingests": counters["ingests"],
             "ingest_cache": {
                 "entries": cache_entries,
