@@ -17,7 +17,7 @@ encode the documented order:
 
     cluster(10) -> engine(20) -> future(30) -> store(40)
         -> plan-cache(50) -> ingest-cache(60) -> shared-pool(61)
-        -> warn-once(62)
+        -> warn-once(62) -> ingest-feed(63)
 
 i.e. the cluster lock is the outermost lock in the system and the
 module-leaf cache locks are leaves: nothing else may be acquired
@@ -83,6 +83,7 @@ LOCK_RANKS = {
     "ingest-cache": 60,  # core/engine.py _INGEST_CACHE_LOCK
     "shared-pool": 61,   # core/engine.py _SHARED_POOL_LOCK
     "warn-once": 62,     # core/executor.py _WARNED_LEGACY_LOCK
+    "ingest-feed": 63,   # core/engine.py CTEngine._feed_lock
 }
 
 #: lock classes backed by an RLock (same-class re-acquire is legal).
@@ -97,6 +98,7 @@ REENTRANT_LOCKS = frozenset({"cluster", "engine", "store"})
 LOCK_PATTERNS = (
     ("core/engine.py", "._work", "engine", True),
     ("core/engine.py", "._space", "engine", True),
+    ("core/engine.py", "._feed_lock", "ingest-feed", False),
     ("core/engine.py", "._lock", "engine", False),
     ("core/engine.py", "_INGEST_CACHE_LOCK", "ingest-cache", False),
     ("core/engine.py", "_SHARED_POOL_LOCK", "shared-pool", False),

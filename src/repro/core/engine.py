@@ -197,7 +197,7 @@ from repro.core.executor import (ExecutorPlan, MergeConfig, ShardedPlan,
                                  _gather_one_bucket, build_plan, extend_plan,
                                  reset_legacy_warnings, shard_plan)
 from repro.core.interpolation import interpolate_hierarchical
-from repro.core.levels import SchemeLike
+from repro.core.levels import SchemeLike, grid_shape
 from repro.kernels.hierarchize import hierarchize_batched, interpret_default
 from repro.runtime.durability import DurableStore, RetryPolicy
 
@@ -401,7 +401,7 @@ def plan_signature(plan, spec: ExecSpec) -> Tuple:
 #: the executable inside the lock is fine because ``jax.jit`` is lazy
 #: (tracing/compilation happen at FIRST CALL, outside any lock).  The
 #: lock is a LEAF: never held while taking an engine lock.
-_INGEST_EXECUTABLES: "collections.OrderedDict[Tuple, Callable]" = \
+_INGEST_EXECUTABLES: "collections.OrderedDict[Tuple, _IngestExecutable]" = \
     collections.OrderedDict()
 _INGEST_CACHE_MAX = 64
 _INGEST_CACHE_LOCK = _lockdep.make_lock("ingest-cache")
@@ -413,8 +413,8 @@ def clear_compile_cache() -> None:
         _INGEST_EXECUTABLES.clear()
 
 
-def _build_ingest_executable(plan, spec: ExecSpec) -> Callable:
-    """Jitted ``(grid_parts, idxs, coeffs) -> surplus`` for one plan
+def _ingest_body(plan, spec: ExecSpec) -> Callable:
+    """Un-jitted ``(grid_parts, idxs, coeffs) -> surplus`` for one plan
     signature.  ``plan`` is a REPRESENTATIVE realization of the
     signature: only signature-determined structure (bucket levels/perms/
     shapes, fine grid, slab metadata) is closed over; index maps and
@@ -424,11 +424,6 @@ def _build_ingest_executable(plan, spec: ExecSpec) -> Callable:
     metas = [(b.levels, b.perms, b.shape) for b in base.buckets]
     fine_shape, fine_size = base.fine_shape, base.fine_size
     interpret, dtype_policy = spec.interpret, spec.dtype
-    # zero-copy hand-off: the staged grid parts (argument 0) are donated
-    # so the backend may retire them into the transform's intermediates;
-    # index maps / coefficients are NOT donated — they are the tenant's
-    # long-lived runtime identity, reused every ingest
-    donate = (0,) if spec.donate else ()
 
     def _acc_dtype(parts):
         if dtype_policy is not None:
@@ -454,7 +449,7 @@ def _build_ingest_executable(plan, spec: ExecSpec) -> Callable:
                                           interpret=interpret)
             return full[:-1].reshape(fine_shape)
 
-        return jax.jit(ingest, donate_argnums=donate)
+        return ingest
 
     if spec.mesh is None:
         raise ValueError(
@@ -480,7 +475,7 @@ def _build_ingest_executable(plan, spec: ExecSpec) -> Callable:
                 interpret=interpret, idx_arrays=idxs, coeff_arrays=cs,
                 dtype=dtype)
 
-        return jax.jit(ingest_2d, donate_argnums=donate)
+        return ingest_2d
 
     def ingest_sharded(parts, idxs, coeffs):
         from repro.core.distributed import gather_slab_scatter
@@ -493,11 +488,89 @@ def _build_ingest_executable(plan, spec: ExecSpec) -> Callable:
         return gather_slab_scatter(alphas, splan, mesh, axis_name,
                                    idx_arrays=idxs, coeff_arrays=cs)
 
-    return jax.jit(ingest_sharded, donate_argnums=donate)
+    return ingest_sharded
+
+
+def _part_shapes(plan) -> Tuple[Tuple[int, ...], ...]:
+    """Each grid part's shape, in the executable's part order.  A
+    member's shape follows from its canonical levels and perm, so every
+    plan of one signature gives the same shapes."""
+    base = plan.plan if isinstance(plan, ShardedPlan) else plan
+    return tuple(grid_shape(ell) for b in base.buckets for ell in b.ells)
+
+
+def _build_ingest_executable(plan, spec: ExecSpec, *,
+                             packed: bool = False) -> Callable:
+    """Jitted ``(grid_parts, idxs, coeffs) -> surplus`` for one plan
+    signature (see ``_ingest_body``).  ``packed=True`` takes ``(flat,
+    idxs, coeffs)`` instead: every part raveled, in part order, into one
+    1-D buffer, cut back into the parts at static offsets before the
+    same body."""
+    body = _ingest_body(plan, spec)
+    if packed:
+        shapes, unpacked = _part_shapes(plan), body
+
+        def body(flat, idxs, coeffs):
+            parts, off = [], 0
+            for shape in shapes:
+                n = math.prod(shape)
+                parts.append(flat[off:off + n].reshape(shape))
+                off += n
+            return unpacked(tuple(parts), idxs, coeffs)
+
+        # the trace names the module ``jit_<name>``: keep ``jit_ingest*``
+        body.__name__ = f"{unpacked.__name__}_packed"
+    # zero-copy hand-off: the staged grid parts or the packed buffer
+    # (argument 0) are donated so the backend may retire them into the
+    # transform's intermediates; index maps / coefficients are NOT
+    # donated — they are the tenant's long-lived runtime identity,
+    # reused every ingest
+    return jax.jit(body, donate_argnums=(0,) if spec.donate else ())
+
+
+class _IngestExecutable:
+    """One plan signature's ingest, in both feeds: ``per_part`` takes the
+    grid parts as they come (device arrays stay where they are),
+    ``packed`` one host-packed buffer (one host-to-device copy, no
+    per-part padding).  ``jax.jit`` compiles only what is called."""
+
+    def __init__(self, plan, spec: ExecSpec):
+        self.per_part = _build_ingest_executable(plan, spec)
+        self.packed = _build_ingest_executable(plan, spec, packed=True)
+        self.part_shapes = _part_shapes(plan)
+        self.packed_size = sum(map(math.prod, self.part_shapes))
+
+    def _cache_size(self) -> int:
+        return self.per_part._cache_size() + self.packed._cache_size()
+
+    def pack(self, grids) -> Optional[np.ndarray]:
+        """``grids`` (in part order) in one host buffer, or ``None`` where
+        they must go part by part: a device array among them (it never
+        makes a host round trip), canonical dtypes that differ, or a
+        shape the executable does not take (the per-part path raises as
+        it always has).  The buffer holds each grid's canonical dtype,
+        the one ``jnp.asarray`` would give it, so the device receives
+        the same values either way."""
+        hosts = []
+        for g, shape in zip(grids, self.part_shapes):
+            if isinstance(g, jax.Array):
+                return None
+            a = np.asarray(g)
+            if a.shape != shape:
+                return None
+            hosts.append(a)
+        dtypes = {jax.dtypes.canonicalize_dtype(a.dtype) for a in hosts}
+        if len(dtypes) != 1:
+            return None
+        flat, off = np.empty(self.packed_size, dtypes.pop()), 0
+        for a in hosts:
+            np.copyto(flat[off:off + a.size].reshape(a.shape), a)
+            off += a.size
+        return flat
 
 
 def _ingest_executable(signature: Tuple, plan,
-                       spec: ExecSpec) -> Tuple[Callable, bool]:
+                       spec: ExecSpec) -> Tuple[_IngestExecutable, bool]:
     """Fetch-or-build the shared executable; returns ``(fn, was_hit)``.
 
     The whole get/build/insert/evict sequence runs under ONE lock, so
@@ -509,7 +582,7 @@ def _ingest_executable(signature: Tuple, plan,
         if fn is not None:
             _INGEST_EXECUTABLES.move_to_end(signature)
             return fn, True
-        fn = _build_ingest_executable(plan, spec)
+        fn = _IngestExecutable(plan, spec)
         _INGEST_EXECUTABLES[signature] = fn
         while len(_INGEST_EXECUTABLES) > _INGEST_CACHE_MAX:
             _INGEST_EXECUTABLES.popitem(last=False)
@@ -534,7 +607,9 @@ SPAN_SCHED_PASS = "ct.sched.pass"
 SPAN_SCHED_SLEEP = "ct.sched.sleep"
 #: one ingest on its chain's thread, dispatch through watermark (``seq``)
 SPAN_INGEST = "ct.ingest"
-#: host to device: ``jnp.asarray`` of every component grid
+#: host to device: host grids packed into one buffer and copied by one
+#: ``jnp.asarray``; else (a device array among them, or mixed dtypes)
+#: ``jnp.asarray`` of every component grid
 SPAN_INGEST_TRANSFER = "ct.ingest.transfer"
 #: the call of the ingest executable
 SPAN_INGEST_LAUNCH = "ct.ingest.launch"
@@ -663,7 +738,7 @@ class _Tenant:
     spec: ExecSpec
     plan: Any                       # ExecutorPlan | ShardedPlan
     signature: Tuple
-    executable: Callable
+    executable: _IngestExecutable
     idxs: Tuple[jnp.ndarray, ...]
     coeffs: Tuple[jnp.ndarray, ...]
     surplus: Optional[jnp.ndarray] = None
@@ -807,6 +882,11 @@ class CTEngine:
         self._counters = {"ingests": 0, "queries": 0, "eval_batches": 0,
                           "coalesced_queries": 0, "cache_hits": 0,
                           "cache_misses": 0}
+        #: how ``_dispatch_ingest`` fed each ingest to the device, under a
+        #: leaf lock of its own: the engine lock is the ingests' contended
+        #: commit lock
+        self._feed = {"packed": 0, "per_part": 0}
+        self._feed_lock = _lockdep.make_lock("ingest-feed")
         self._sched = {"dispatch_deadline": 0, "dispatch_batch_full": 0,
                        "flushes": 0, "rejected": 0, "requeued": 0,
                        "ingest_retries": 0, "promoted": 0}
@@ -1035,11 +1115,18 @@ class CTEngine:
         _lockdep.note_dispatch("engine._dispatch_ingest")
         base = tenant.base_plan
         _check_nodal_grids(nodal_grids, base)
+        exe = tenant.executable
+        grids = [nodal_grids[ell] for b in base.buckets for ell in b.ells]
         with TraceAnnotation(SPAN_INGEST_TRANSFER):
-            parts = tuple(jnp.asarray(nodal_grids[ell])
-                          for b in base.buckets for ell in b.ells)
+            flat = exe.pack(grids)
+            if flat is None:
+                fn, feed = exe.per_part, tuple(jnp.asarray(g) for g in grids)
+            else:
+                fn, feed = exe.packed, jnp.asarray(flat)
+        with self._feed_lock:
+            self._feed["per_part" if flat is None else "packed"] += 1
         with TraceAnnotation(SPAN_INGEST_LAUNCH):
-            return tenant.executable(parts, tenant.idxs, tenant.coeffs)
+            return fn(feed, tenant.idxs, tenant.coeffs)
 
     # -- thread-safe submission ---------------------------------------------
 
@@ -1996,7 +2083,8 @@ class CTEngine:
 
     def stats(self) -> Dict[str, Any]:
         """Aggregated serving statistics: the ingest count, the shared
-        compile-cache counters, the continuous-batching eval counters,
+        compile-cache counters, how each ingest was fed (``packed`` or
+        ``per_part``), the continuous-batching eval counters,
         the scheduler's dispatch/backpressure accounting and the durable
         store's."""
         with self._lock:
@@ -2004,6 +2092,8 @@ class CTEngine:
             counters = dict(self._counters)
             sched = dict(self._sched)
             pending = len(self._pending)
+        with self._feed_lock:
+            feed = dict(self._feed)
         # count over the LIVE tenants' executables (dedup by identity) —
         # an executable evicted from the LRU cache keeps serving its
         # tenants and must keep being counted
@@ -2021,6 +2111,7 @@ class CTEngine:
                 "misses": counters["cache_misses"],
                 "jit_entries": jit_entries,
             },
+            "ingest_feed": feed,
             "eval": {
                 "queries": counters["queries"],
                 "batches": counters["eval_batches"],

@@ -110,3 +110,33 @@ def test_engine_spans_nest_and_leave_the_answers_alone(tmp_path, check_finite):
         assert all(a_end <= b_start
                    for (_, a_end), (b_start, _) in zip(sched, sched[1:]))
 
+
+
+def test_packed_transfer_nests_in_its_ingest(tmp_path):
+    """Host grids take the packed feed; its one copy is still the
+    ``ct.ingest.transfer`` span, inside its ``ct.ingest`` on the pool
+    thread."""
+    scheme = CombinationScheme(2, 4)
+    eng = CTEngine(deadline_ms=2.0)
+    eng.register("a", scheme, _grids(scheme, 0))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.start()
+        ingests = [eng.submit_ingest("a", _grids(scheme, 20 + k))
+                   for k in range(3)]
+        assert all(f.wait(TIMEOUT_S) for f in ingests)
+        eng.stop(drain=True)
+    finally:
+        jax.profiler.stop_trace()
+    assert eng.stats()["ingest_feed"] == {"packed": 4, "per_part": 0}
+    eng.close()
+
+    transfers = 0
+    for line in _host_lines(tmp_path):
+        for name, start, end in line:
+            if name != E.SPAN_INGEST_TRANSFER:
+                continue
+            transfers += 1
+            assert any(p == E.SPAN_INGEST and ps <= start and end <= pe
+                       for p, ps, pe in line)
+    assert transfers == 3

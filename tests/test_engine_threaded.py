@@ -143,6 +143,8 @@ def test_threaded_mixed_load_bit_identical_to_serial_replay():
     st = eng.stats()
     assert st["scheduler"]["pending"] == 0          # nothing left behind
     assert st["ingests"] >= 9 * rounds
+    # every dispatch counted once, on its feed, by 8 racing pool threads
+    assert sum(st["ingest_feed"].values()) == st["ingests"]
 
 
 # ---------------------------------------------------------------------------

@@ -168,6 +168,22 @@ def test_one_chip_ingest_compiles(one_chip, config, pallas):
     assert _on_chip_bytes(c) < HBM_BYTES
 
 
+@pytest.mark.parametrize("config,pallas", [("fig6_2d", True),
+                                           ("prod_3d", False)])
+def test_one_chip_packed_ingest_compiles(one_chip, config, pallas):
+    """The packed feed's executable: one flat f32 buffer of every grid,
+    cut back into the parts on the chip before the same body."""
+    spec = E.ExecSpec(interpret=False, dtype="float32")
+    plan = build_plan(get_ct_config(config).scheme, spec=spec)
+    exe = E._IngestExecutable(plan, spec)
+    _, idxs, coeffs = _ingest_args(plan, one_chip)
+    flat = _f32((exe.packed_size,), one_chip)
+    with jax.enable_x64(False):
+        c = exe.packed.lower(flat, idxs, coeffs).compile()
+    assert ("tpu_custom_call" in c.as_text()) == pallas
+    assert _on_chip_bytes(c) < HBM_BYTES
+
+
 def test_2x2_mesh_prod_3d_ingest_compiles(topo):
     """The (member x slab) mesh ingest over the host's four chips:
     hierarchization compute-sharded, surpluses shipped by collectives."""
