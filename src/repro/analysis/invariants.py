@@ -205,7 +205,7 @@ BLOCKING_CALL_NAMES = frozenset({
 DISPATCH_CALL_NAMES = frozenset({
     "_dispatch_ingest",        # donating ingest executable (engine)
     "_dispatch_query_groups",  # batched eval + block_until_ready
-    "_EVAL_BATCHED",           # jit'd evaluation entry
+    "_EVAL_BATCHED",           # jit'd eval of one surplus (ct.query.eval)
     "hierarchize_batched",
     "interpolate_hierarchical",
 })
@@ -279,7 +279,8 @@ INVARIANTS = {
     ),
     "dispatch-under-lock": (
         "Device dispatch never runs under any lock; workers drop the "
-        "engine lock before _dispatch_ingest/_EVAL_BATCHED and "
+        "engine lock before _dispatch_ingest/_EVAL_BATCHED (the "
+        "one-surplus eval each ct.query.eval span launches) and "
         "reacquire it only to commit (PR 6)."
     ),
     "wait-wrong-lock": (

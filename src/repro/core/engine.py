@@ -121,10 +121,12 @@ or different data — compile ONCE and the results stay bit-identical to
 the constants-baked ``ct_transform`` (both spellings trace the same
 ops; pinned by ``tests/test_engine.py``).
 
-Queries coalesce BY SIGNATURE (surplus shape/dtype + padded batch
-extent) into one vmapped batched eval dispatch per group; per-request
-results are bit-identical to a per-tenant dispatch because each query
-point's hat-basis contraction is independent of the batching.  ``refit``
+Queries coalesce BY SIGNATURE (surplus shape/dtype + padded point
+extent) into chunks of up to ``max_batch``; a chunk runs one eval per
+distinct surplus in it, over the points of every row that reads it, and
+never copies a surplus.  Per-request results match a per-tenant
+dispatch because each query point's hat-basis contraction is
+independent of the batching.  ``refit``
 / ``extend`` / ``drop_grid`` route through the incremental plan paths
 (``extend_plan`` / ``recombine_after_fault``) per tenant; ``rebind``
 re-shards a tenant onto a new mesh/slab layout WITHOUT recomputing its
@@ -589,11 +591,12 @@ def _ingest_executable(signature: Tuple, plan,
         return fn, False
 
 
-#: One process-global jitted batched eval: vmapped hat-basis contraction.
-#: jit caches one executable per (T, surplus shape, Q, dtypes); each
-#: query point is evaluated independently of its batch neighbors, so the
-#: T=1 row equals the unbatched eval BITWISE.
-_EVAL_BATCHED = jax.jit(jax.vmap(interpolate_hierarchical))
+#: One process-global jitted eval: one surplus at a batch of points (the
+#: points of every row of a chunk that reads that surplus).  jit caches
+#: one executable per (surplus shape and sharding, point count, dtypes);
+#: each point's hat-basis contraction is independent of the others, so a
+#: row's answers do not depend on what it is batched with.
+_EVAL_BATCHED = jax.jit(interpolate_hierarchical)
 
 #: Jitted device-side finiteness probe for ``check_finite`` ingests.
 _FINITE_CHECK = jax.jit(lambda x: jnp.all(jnp.isfinite(x)))
@@ -619,24 +622,26 @@ SPAN_INGEST_WAIT = "ct.ingest.wait"
 SPAN_INGEST_CHECK = "ct.ingest.check"
 #: the commit's lock and compare-and-swap
 SPAN_INGEST_COMMIT = "ct.ingest.commit"
-#: one batched eval chunk through its futures (``rows``, ``tpad``)
+#: one eval chunk through its futures (``rows``)
 SPAN_QUERY_BATCH = "ct.query.batch"
-#: the surplus rows (and zero rows) stacked into the batch
-SPAN_QUERY_STACK = "ct.query.stack"
+#: the eval of one distinct surplus of a chunk, over the points of all
+#: its rows (``rows``, ``ppad``: the padded point count)
+SPAN_QUERY_EVAL = "ct.query.eval"
 #: the padded point array and its ``jnp.asarray``
 SPAN_QUERY_POINTS = "ct.query.points"
-#: the call of the batched eval
+#: the call of the eval
 SPAN_QUERY_LAUNCH = "ct.query.launch"
-#: ``block_until_ready`` on the batch's answers
+#: ``block_until_ready`` on the chunk's answers
 SPAN_QUERY_WAIT = "ct.query.wait"
-#: one answer's slice and device-to-host copy, on the thread that reads it
+#: one answer, on the thread that reads it: its eval's answers copied to
+#: the host (once an eval: the array keeps the copy) and sliced there
 SPAN_QUERY_FETCH = "ct.query.fetch"
 
 
-def _fetch_answer(out, i: int, q: int) -> np.ndarray:
-    """Row ``i`` of a batch's answers, unpadded, on the host."""
+def _fetch_answer(out, start: int, q: int) -> np.ndarray:
+    """Points ``start .. start + q`` of an eval's answers, on the host."""
     with TraceAnnotation(SPAN_QUERY_FETCH):
-        return np.asarray(out[i, :q])
+        return np.array(np.asarray(out)[start:start + q])
 
 #: How long a draining flush waits for another thread's in-flight ingest
 #: before failing the dependent query futures with TimeoutError.
@@ -828,8 +833,9 @@ class CTEngine:
     ``flush()`` (everything), ``pump()`` (one deadline/batch-full
     scheduler step) or the ``start()``-ed background scheduler thread.
     Ingests run on a background pool, ordered per tenant by a watermark
-    that queries of the same tenant wait on; queries coalesce into one
-    batched eval dispatch per signature group.  The ingest-executable
+    that queries of the same tenant wait on; queries coalesce into
+    chunks per signature group, each evaluated once per distinct
+    surplus.  The ingest-executable
     cache is process-global (lock-guarded); hit/miss counters are per
     engine.  The queue is bounded (``max_pending``): non-blocking
     submits raise ``EngineSaturated`` when full.
@@ -880,8 +886,12 @@ class CTEngine:
         self._ingest_submitted: Dict[str, int] = {}
         self._ingest_done: Dict[str, int] = {}
         self._counters = {"ingests": 0, "queries": 0, "eval_batches": 0,
-                          "coalesced_queries": 0, "cache_hits": 0,
-                          "cache_misses": 0}
+                          "surplus_evals": 0, "coalesced_queries": 0,
+                          "cache_hits": 0, "cache_misses": 0}
+        #: (surplus shape, dtype, sharding, qpad, point dtype) of every
+        #: eval this engine has warmed at all its row paddings (under the
+        #: engine lock)
+        self._eval_warm: set = set()
         #: how ``_dispatch_ingest`` fed each ingest to the device, under a
         #: leaf lock of its own: the engine lock is the ingests' contended
         #: commit lock
@@ -1221,7 +1231,8 @@ class CTEngine:
         (callable from any thread); the future resolves to the (Q,)
         values once the scheduler dispatches its signature group —
         batch-full, deadline expiry, or any ``flush``.  Same-signature
-        queries across tenants coalesce into one batched dispatch.
+        queries across tenants coalesce into one chunk, evaluated once
+        per distinct surplus.
 
         ``stale_ok=True`` waits only for the ingests already COMMITTED
         (the done watermark), not for every ingest already admitted —
@@ -1707,46 +1718,72 @@ class CTEngine:
                 else:
                     chunks.append([e])
             for chunk in chunks:
-                # pad the BATCH axis to a power of two as well (>= 4):
-                # under deadline dispatch the group size varies per
-                # window, and an unpadded T would recompile the batched
-                # eval for every new size
-                tpad = max(4, 1 << max(0, len(chunk) - 1).bit_length())
-                with TraceAnnotation(SPAN_QUERY_BATCH, rows=len(chunk),
-                                     tpad=tpad):
-                    self._eval_chunk(chunk, tpad, qpad, pts_dtype)
+                with TraceAnnotation(SPAN_QUERY_BATCH, rows=len(chunk)):
+                    self._eval_chunk(chunk, qpad, pts_dtype)
                 count += len(chunk)
         return count
 
-    def _eval_chunk(self, chunk, tpad: int, qpad: int, pts_dtype) -> None:
-        """One batched eval of ``chunk`` padded to ``tpad`` rows; resolves
-        every future of the chunk, with the answers or the error."""
+    def _eval_chunk(self, chunk, qpad: int, pts_dtype) -> None:
+        """Evaluate ``chunk``: one eval per distinct surplus in it, over
+        the points of all the rows that read it, each row's points at
+        ``qpad`` apart and the rows padded to a power of two, so that
+        under deadline dispatch a varying group size compiles nothing
+        new.  Resolves every future of the chunk, with the answers or
+        the error."""
+        by_surplus: Dict[int, List[int]] = {}
+        for i, (_, surplus, _) in enumerate(chunk):
+            by_surplus.setdefault(id(surplus), []).append(i)
+        evals = []
         try:
-            with TraceAnnotation(SPAN_QUERY_STACK):
-                rows = [s for _, s, _ in chunk]
-                rows += [jnp.zeros_like(rows[0])] * (tpad - len(chunk))
-                surp = jnp.stack(rows)
-            with TraceAnnotation(SPAN_QUERY_POINTS):
-                padded = np.zeros((tpad, qpad, chunk[0][2]), pts_dtype)
-                for i, (r, _, _) in enumerate(chunk):
-                    points, q, _ = r.payload
-                    padded[i, :q] = points
-                pts = jnp.asarray(padded)
-            with TraceAnnotation(SPAN_QUERY_LAUNCH):
-                out = _EVAL_BATCHED(surp, pts)
+            for rows in by_surplus.values():
+                _, surplus, dim = chunk[rows[0]]
+                rpad = 1 << (len(rows) - 1).bit_length()
+                with TraceAnnotation(SPAN_QUERY_EVAL, rows=len(rows),
+                                     ppad=rpad * qpad):
+                    self._warm_eval(surplus, qpad, pts_dtype, dim)
+                    with TraceAnnotation(SPAN_QUERY_POINTS):
+                        padded = np.zeros((rpad * qpad, dim), pts_dtype)
+                        for j, i in enumerate(rows):
+                            points, q, _ = chunk[i][0].payload
+                            padded[j * qpad:j * qpad + q] = points
+                        pts = jnp.asarray(padded)
+                    with TraceAnnotation(SPAN_QUERY_LAUNCH):
+                        evals.append((rows, _EVAL_BATCHED(surplus, pts)))
             with TraceAnnotation(SPAN_QUERY_WAIT):
-                jax.block_until_ready(out)
+                jax.block_until_ready([out for _, out in evals])
         except Exception as exc:
             for r, _, _ in chunk:
                 r.future._set_error(exc)
             return
-        for i, (r, _, _) in enumerate(chunk):
-            r.future._set(functools.partial(_fetch_answer, out, i,
-                                            r.payload[1]))
+        for rows, out in evals:
+            for j, i in enumerate(rows):
+                r = chunk[i][0]
+                r.future._set(functools.partial(_fetch_answer, out,
+                                                j * qpad, r.payload[1]))
         with self._lock:
             self._counters["eval_batches"] += 1
+            self._counters["surplus_evals"] += len(evals)
             self._counters["queries"] += len(chunk)
             self._counters["coalesced_queries"] += len(chunk) - 1
+
+    def _warm_eval(self, surplus, qpad: int, pts_dtype, dim: int) -> None:
+        """At the first eval of a surplus shape, dtype and sharding at
+        ``qpad`` points a row, run the eval once at every row padding a
+        chunk of ``max_batch`` rows can reach, so that no later chunk
+        compiles, whatever mix of tenants it holds."""
+        key = (surplus.shape, str(surplus.dtype), surplus.sharding, qpad,
+               str(pts_dtype))
+        with self._lock:
+            seen = key in self._eval_warm
+            self._eval_warm.add(key)
+        if seen:
+            return
+        top = 1 << (self._max_batch - 1).bit_length()
+        rpad = 1
+        while rpad <= top:
+            jax.block_until_ready(_EVAL_BATCHED(
+                surplus, np.zeros((rpad * qpad, dim), pts_dtype)))
+            rpad *= 2
 
     # -- synchronous conveniences -------------------------------------------
 
@@ -2115,6 +2152,7 @@ class CTEngine:
             "eval": {
                 "queries": counters["queries"],
                 "batches": counters["eval_batches"],
+                "surplus_evals": counters["surplus_evals"],
                 "coalesced_queries": counters["coalesced_queries"],
                 "compiles": _EVAL_BATCHED._cache_size(),
             },

@@ -28,9 +28,9 @@ PARENT = {
     E.SPAN_INGEST_WAIT: E.SPAN_INGEST,
     E.SPAN_INGEST_CHECK: E.SPAN_INGEST,
     E.SPAN_INGEST_COMMIT: E.SPAN_INGEST,
-    E.SPAN_QUERY_STACK: E.SPAN_QUERY_BATCH,
-    E.SPAN_QUERY_POINTS: E.SPAN_QUERY_BATCH,
-    E.SPAN_QUERY_LAUNCH: E.SPAN_QUERY_BATCH,
+    E.SPAN_QUERY_EVAL: E.SPAN_QUERY_BATCH,
+    E.SPAN_QUERY_POINTS: E.SPAN_QUERY_EVAL,
+    E.SPAN_QUERY_LAUNCH: E.SPAN_QUERY_EVAL,
     E.SPAN_QUERY_WAIT: E.SPAN_QUERY_BATCH,
     E.SPAN_QUERY_BATCH: E.SPAN_SCHED_PASS,
 }

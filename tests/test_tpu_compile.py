@@ -200,12 +200,28 @@ def test_2x2_mesh_prod_3d_ingest_compiles(topo):
 
 
 def test_served_eval_compiles_full_precision(one_chip):
-    """The batched eval at prod_3d size: its hat-basis contractions must
+    """The served eval at prod_3d size: its hat-basis contractions must
     stay full f32 precision on the chip (the TPU's default f32 matmul is
     one bf16 pass, which a CPU run can never show)."""
     c = _compile(E._EVAL_BATCHED,
-                 _f32((4, 511, 511, 511), one_chip), _f32((4, 64, 3), one_chip))
+                 _f32((511, 511, 511), one_chip), _f32((64, 3), one_chip))
     text = c.as_text()
     assert "operand_precision={highest,highest}" in text
     assert "operand_precision={default" not in text
     assert _on_chip_bytes(c) < HBM_BYTES
+
+
+def test_prod_3d_eval_at_its_largest_padding_fits_beside_8_tenants(one_chip):
+    """One surplus evaluated at the most points a chunk can give it: all
+    ``max_batch`` rows of the engine's default (32) on one tenant, 64
+    points each.  With the other seven tenants' surpluses resident, the
+    chip's 16 GB must hold it, so no group has to be split."""
+    fine = grid_shape(tuple(
+        max(ell[k] for ell, _ in get_ct_config("prod_3d").scheme.grids)
+        for k in range(3)))
+    assert fine == (511, 511, 511)
+    ppad = 32 * E._qpad(64)
+    c = _compile(E._EVAL_BATCHED, _f32(fine, one_chip),
+                 _f32((ppad, 3), one_chip))
+    surplus_bytes = 4 * int(np.prod(fine))
+    assert _on_chip_bytes(c) + 7 * surplus_bytes < HBM_BYTES
