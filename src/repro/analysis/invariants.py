@@ -242,6 +242,7 @@ BIT_CRITICAL_FUNC_PREFIXES = (
     "gather_slab_scatter",   # core/distributed.py slab scatter family
     "_finish_slab_gather",
     "_gather_one_bucket",
+    "_gather_compact",       # core/executor.py one-device compact gather
     "_scatter_surplus",
 )
 

@@ -196,7 +196,7 @@ from repro.analysis import lockdep as _lockdep
 
 from repro.core.executor import (ExecutorPlan, MergeConfig, ShardedPlan,
                                  _assemble_members, _check_nodal_grids,
-                                 _gather_one_bucket, build_plan, extend_plan,
+                                 _gather_compact, build_plan, extend_plan,
                                  reset_legacy_warnings, shard_plan)
 from repro.core.interpolation import interpolate_hierarchical
 from repro.core.levels import SchemeLike, grid_shape
@@ -420,11 +420,13 @@ def _ingest_body(plan, spec: ExecSpec) -> Callable:
     signature.  ``plan`` is a REPRESENTATIVE realization of the
     signature: only signature-determined structure (bucket levels/perms/
     shapes, fine grid, slab metadata) is closed over; index maps and
-    coefficients arrive as traced arguments."""
+    coefficients arrive as traced arguments.  On one device ``idxs`` is
+    ``(compact maps, fine map)`` and the body is the compact gather
+    (``ExecutorPlan.compact``)."""
     sharded = isinstance(plan, ShardedPlan)
     base = plan.plan if sharded else plan
     metas = [(b.levels, b.perms, b.shape) for b in base.buckets]
-    fine_shape, fine_size = base.fine_shape, base.fine_size
+    fine_shape = base.fine_shape
     interpret, dtype_policy = spec.interpret, spec.dtype
 
     def _acc_dtype(parts):
@@ -443,13 +445,11 @@ def _ingest_body(plan, spec: ExecSpec) -> Callable:
     if not sharded:
         def ingest(parts, idxs, coeffs):
             dtype = _acc_dtype(parts)
-            full = jnp.zeros(fine_size + 1, dtype)   # +1: pad dump slot
-            for x, (levels, _, _), idx, cs in zip(_assembled(parts), metas,
-                                                  idxs, coeffs):
-                full = _gather_one_bucket(full, x, levels, idx,
-                                          cs.astype(dtype),
-                                          interpret=interpret)
-            return full[:-1].reshape(fine_shape)
+            maps, fine_map = idxs
+            return _gather_compact(
+                _assembled(parts), [levels for levels, _, _ in metas], maps,
+                [cs.astype(dtype) for cs in coeffs], fine_map, fine_shape,
+                dtype, interpret=interpret)
 
         return ingest
 
@@ -782,7 +782,8 @@ class _Request:
 def _tenant_arrays(plan) -> Tuple[Tuple[jnp.ndarray, ...],
                                   Tuple[jnp.ndarray, ...]]:
     """Upload a plan's index maps + coefficients once per (re)bind — the
-    runtime arguments that distinguish tenants sharing one executable."""
+    runtime arguments that distinguish tenants sharing one executable.
+    One device: the compact gather's bucket maps and its fine map."""
     if isinstance(plan, ShardedPlan):
         if plan.n_groups > 1:
             # 2-D compute-sharded plan: the executable consumes the
@@ -793,7 +794,9 @@ def _tenant_arrays(plan) -> Tuple[Tuple[jnp.ndarray, ...],
             idxs = tuple(jnp.asarray(sb.index) for sb in plan.slab_buckets)
         buckets = plan.plan.buckets
     else:
-        idxs = tuple(jnp.asarray(b.index) for b in plan.buckets)
+        compact = plan.compact
+        idxs = (tuple(jnp.asarray(m) for m in compact.buckets),
+                jnp.asarray(compact.fine))
         buckets = plan.buckets
     coeffs = tuple(jnp.asarray(b.coeffs) for b in buckets)
     return idxs, coeffs

@@ -44,6 +44,15 @@ function:
      which is what keeps merged and sharded results bit-identical); the
      scatter step is the same map read in reverse (``take``).
 
+  5. **Compact gather** (one device) — the buckets accumulate into a
+     compact vector over the sparse grid's distinct fine points
+     (``ExecutorPlan.compact``: each bucket's map into it, and its map
+     into the fine grid), which is then written into the fine grid by
+     one scatter of unique, sorted indices.  Every point still receives
+     its additions in bucket order from 0, so on the CPU the result is
+     bitwise the per-bucket gather into the fine buffer (within a
+     bucket, the order of a scatter's duplicate indices is XLA's).
+
 ``ct_transform`` / ``ct_scatter`` are end-to-end jittable (scheme static),
 reused by the distributed psum path (``repro.core.distributed.
 ct_transform_psum``) and the surrogate-serving driver
@@ -79,6 +88,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import threading
 import warnings
 from dataclasses import dataclass
@@ -219,6 +229,32 @@ class ExecutorPlan:
     @property
     def num_grids(self) -> int:
         return sum(len(b.ells) for b in self.buckets)
+
+    @functools.cached_property
+    def compact(self) -> "CompactMaps":
+        """The single-device gather's maps, built once per plan."""
+        real = np.concatenate([b.index.ravel() for b in self.buckets])
+        fine = np.unique(real[real < self.fine_size]).astype(np.int32)
+        return CompactMaps(
+            fine=fine,
+            buckets=tuple(np.searchsorted(fine, b.index).astype(np.int32)
+                          for b in self.buckets))
+
+
+@dataclass(frozen=True)
+class CompactMaps:
+    """The sparse grid's N distinct fine points, as the compact gather
+    addresses them: ``fine`` (N,) int32, sorted and unique, is each
+    compact slot's flat fine index; ``buckets[i]`` has bucket i's
+    ``index`` shape and gives each position's compact slot, pads the
+    dump slot N.  N follows from the plan's member level vectors."""
+
+    fine: np.ndarray
+    buckets: Tuple[np.ndarray, ...]
+
+    @property
+    def size(self) -> int:
+        return len(self.fine)
 
 
 @dataclass(frozen=True)
@@ -974,7 +1010,8 @@ def _gather_one_bucket(full: jnp.ndarray, x: jnp.ndarray,
                        member_levels: Tuple[LevelVector, ...],
                        idx, cs, *, interpret: Optional[bool]) -> jnp.ndarray:
     """Accumulate one assembled bucket stack ``x`` (G members, canonical
-    padded shape) into the flat fine buffer ``full`` (+1 dump slot).
+    padded shape) into the flat buffer ``full`` (+1 dump slot) that
+    ``idx`` addresses.
 
     ``idx`` (the (G, P) embed index map) and ``cs`` (the (G,) combination
     coefficients, already in ``full.dtype``) may be numpy plan constants
@@ -985,6 +1022,26 @@ def _gather_one_bucket(full: jnp.ndarray, x: jnp.ndarray,
     g = len(member_levels)
     alpha = hierarchize_batched(x, member_levels, interpret=interpret)
     return full.at[jnp.asarray(idx)].add(cs[:, None] * alpha.reshape(g, -1))
+
+
+def _gather_compact(stacks: Sequence[jnp.ndarray],
+                    member_levels: Sequence[Tuple[LevelVector, ...]],
+                    maps, coeffs, fine_map, fine_shape: Tuple[int, ...],
+                    dtype, *, interpret: Optional[bool]) -> jnp.ndarray:
+    """The single-device gather: every assembled bucket stack, in bucket
+    order, into the compact vector over the sparse grid's points
+    (``maps``, ``fine_map``: ``ExecutorPlan.compact``, as numpy plan
+    constants or traced arguments), then one write of the fine grid: a
+    scatter of sorted, unique indices, which XLA fuses with its
+    zero-fill."""
+    comp = jnp.zeros(len(fine_map) + 1, dtype)    # +1: pad dump slot
+    for x, levels, idx, cs in zip(stacks, member_levels, maps, coeffs):
+        comp = _gather_one_bucket(comp, x, levels, idx, cs,
+                                  interpret=interpret)
+    full = jnp.zeros(int(np.prod(fine_shape)), dtype)
+    full = full.at[jnp.asarray(fine_map)].set(
+        comp[:-1], indices_are_sorted=True, unique_indices=True)
+    return full.reshape(fine_shape)
 
 
 def ct_transform_with_plan(nodal_grids: Mapping[LevelVector, jnp.ndarray],
@@ -1023,13 +1080,12 @@ def ct_transform_with_plan(nodal_grids: Mapping[LevelVector, jnp.ndarray],
     _check_nodal_grids(nodal_grids, plan)
     dtype = jnp.result_type(*(jnp.asarray(nodal_grids[ell]).dtype
                               for b in plan.buckets for ell in b.ells))
-    full = jnp.zeros(plan.fine_size + 1, dtype)   # +1: pad dump slot
-    for bucket in plan.buckets:
-        x = _assemble_bucket(nodal_grids, bucket)
-        full = _gather_one_bucket(full, x, bucket.levels, bucket.index,
-                                  jnp.asarray(bucket.coeffs, dtype),
-                                  interpret=interpret)
-    return full[:-1].reshape(plan.fine_shape)
+    compact = plan.compact
+    return _gather_compact(
+        [_assemble_bucket(nodal_grids, b) for b in plan.buckets],
+        [b.levels for b in plan.buckets], compact.buckets,
+        [jnp.asarray(b.coeffs, dtype) for b in plan.buckets],
+        compact.fine, plan.fine_shape, dtype, interpret=interpret)
 
 
 def ct_scatter(full: jnp.ndarray, scheme: SchemeLike, *,

@@ -15,6 +15,7 @@ with x64 off and Pallas interpret mode off, as on the chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,7 +133,8 @@ def test_ops_auto_and_pole_compile(one_chip, shape, inverse):
 # ---------------------------------------------------------------------------
 
 def _ingest_args(plan, sharding):
-    """Shapes of the engine executable's ``(parts, idxs, coeffs)``."""
+    """Shapes of the engine executable's ``(parts, idxs, coeffs)``; on
+    one device ``idxs`` is ``(compact maps, fine map)``."""
     base = plan.plan if isinstance(plan, ShardedPlan) else plan
 
     def sds(shape, dtype):
@@ -145,9 +147,29 @@ def _ingest_args(plan, sharding):
                       sds(sb.ship_idx.shape, jnp.int32))
                      for sb in plan.slab_buckets)
     else:
-        idxs = tuple(sds(b.index.shape, jnp.int32) for b in base.buckets)
+        # the compact gather's bucket maps and its fine map
+        idxs = (tuple(sds(m.shape, jnp.int32)
+                      for m in plan.compact.buckets),
+                sds(plan.compact.fine.shape, jnp.int32))
     coeffs = tuple(sds(b.coeffs.shape, jnp.float32) for b in base.buckets)
     return parts, idxs, coeffs
+
+
+def _scatter_outputs(text):
+    """Length of every f32 buffer a scatter in the compiled program
+    writes."""
+    return [int(n) for n in
+            re.findall(r"= f32\[(\d+)\]\S* scatter\(", text)]
+
+
+def _assert_one_fine_scatter(text, plan):
+    """The compact gather: one scatter writes the flat fine grid (the
+    expansion ``scatter_ms_per_ingest`` reads); every other scatter
+    writes the compact vector of N points and the dump slot."""
+    outs = _scatter_outputs(text)
+    assert outs.count(plan.fine_size) == 1
+    rest = [n for n in outs if n != plan.fine_size]
+    assert rest and set(rest) == {plan.compact.size + 1}
 
 
 def _ingest(plan, spec, sharding):
@@ -160,11 +182,13 @@ def _ingest(plan, spec, sharding):
                                            ("fig6_2d", True)])
 def test_one_chip_ingest_compiles(one_chip, config, pallas):
     """prod_3d runs every bucket on the jnp path; fig6_2d has three
-    Pallas buckets, which must reach the chip as Mosaic kernels."""
+    Pallas buckets, which must reach the chip as Mosaic kernels.  Both
+    take the compact gather, with one scatter into the fine grid."""
     spec = E.ExecSpec(interpret=False, dtype="float32")
     plan = build_plan(get_ct_config(config).scheme, spec=spec)
     c = _ingest(plan, spec, one_chip)
     assert ("tpu_custom_call" in c.as_text()) == pallas
+    _assert_one_fine_scatter(c.as_text(), plan)
     assert _on_chip_bytes(c) < HBM_BYTES
 
 
@@ -181,6 +205,7 @@ def test_one_chip_packed_ingest_compiles(one_chip, config, pallas):
     with jax.enable_x64(False):
         c = exe.packed.lower(flat, idxs, coeffs).compile()
     assert ("tpu_custom_call" in c.as_text()) == pallas
+    _assert_one_fine_scatter(c.as_text(), plan)
     assert _on_chip_bytes(c) < HBM_BYTES
 
 
