@@ -1,22 +1,19 @@
-"""Slab-sharded vs grid-replicated distributed CT gather.
+"""Memory scaling of the slab-sharded distributed CT gather.
 
-The grid-replicated psum (``ct_transform_psum``) materializes the full
-``(G, *fine_shape)`` embedded stack before its one psum — per-device
-embedded memory is ``(G / n) * fine_size`` and does NOT shrink as devices
-are added.  The slab-sharded path (``ct_transform_sharded``) replicates
-only the COMPACT surpluses (the scheme's point count) and scatter-adds
-into a ``ceil(fine_shape[0] / n) * row_size`` slab per device — embedded
-memory scales with ``1 / n_groups``.
+The slab-sharded gather (``ct_transform_sharded``) replicates only the
+COMPACT surpluses (the scheme's point count) and scatter-adds into a
+``ceil(fine_shape[0] / n) * row_size`` slab per device — embedded memory
+scales with ``1 / n_groups``.
 
 For each (scheme, n_groups) this benchmark
 
   * asserts the sharded gather matches single-device ``ct_transform``
     (fp64 here; the multidevice test tier covers fp32 at 1e-6),
-  * records the PER-DEVICE embedded-buffer bytes of both realizations —
-    derived from the plan (the slab buffer is ``slab_size + 1`` elements,
-    measured off the actual scatter target shape) and, when XLA exposes
-    it, the compiled peak temp bytes (``memory_analysis``),
-  * times both paths end to end on the fake-device mesh (8 host CPU
+  * records the PER-DEVICE embedded-buffer bytes — derived from the plan
+    (the slab buffer is ``slab_size + 1`` elements) — beside the one
+    device's full fine buffer and, when XLA exposes it, the compiled
+    peak temp bytes (``memory_analysis``),
+  * times the gather end to end on the fake-device mesh (8 host CPU
     devices; wall time on one physical CPU is a smoke signal, the memory
     accounting is the point).
 
@@ -55,8 +52,7 @@ jax.config.update("jax_enable_x64", True)
 from common import peak_temp_bytes, time_call  # noqa: E402
 
 from jax.sharding import AxisType  # noqa: E402
-from repro.core.distributed import (ct_transform_psum,  # noqa: E402
-                                    ct_transform_sharded)
+from repro.core.distributed import ct_transform_sharded  # noqa: E402
 from repro.core.executor import (build_plan, ct_transform,  # noqa: E402
                                  plan_ingest_stats, shard_plan)
 from repro.core.levels import (CombinationScheme, grid_shape,  # noqa: E402
@@ -93,9 +89,8 @@ def main(argv=None):
 
     itemsize = np.dtype(DTYPE).itemsize
     rows = []
-    print(f"{'scheme':>8} {'groups':>6} {'fine_MB':>8} {'psum_dev_MB':>12} "
-          f"{'slab_dev_MB':>12} {'mem_ratio':>9} {'psum_ms':>9} "
-          f"{'slab_ms':>9}")
+    print(f"{'scheme':>8} {'groups':>6} {'fine_MB':>8} "
+          f"{'slab_dev_MB':>12} {'mem_ratio':>9} {'slab_ms':>9}")
     for dim, level in SCHEMES:
         scheme = CombinationScheme(dim, level)
         plan = build_plan(scheme)
@@ -109,19 +104,16 @@ def main(argv=None):
         for n in GROUPS:
             mesh = _mesh(n)
             splan = shard_plan(plan, n)
-            f_psum = jax.jit(lambda gr, m=mesh: ct_transform_psum(
-                gr, scheme, m, "slab"))
-            f_slab = jax.jit(lambda gr, m=mesh, sp=splan: ct_transform_psum(
-                gr, scheme, m, "slab", plan=sp))
+            f_slab = jax.jit(lambda gr, m=mesh, sp=splan:
+                             ct_transform_sharded(gr, scheme, m, "slab",
+                                                  plan=sp))
             np.testing.assert_allclose(np.asarray(f_slab(grids)), want,
                                        rtol=1e-12, atol=1e-12)
-            np.testing.assert_allclose(np.asarray(f_psum(grids)), want,
-                                       rtol=1e-12, atol=1e-12)
 
-            # per-device EMBEDDED buffer bytes (the memory this PR shards):
-            # psum path stacks ceil(G/n) full fine buffers per device; the
-            # slab path's scatter target is slab_size + 1 elements.
-            psum_dev = -(-g // n) * plan.fine_size * itemsize
+            # per-device EMBEDDED buffer bytes: the slab scatter target is
+            # slab_size + 1 elements, against the one device's full fine
+            # buffer
+            fine_dev = plan.fine_size * itemsize
             slab_dev = (splan.slab_size + 1) * itemsize
             # acceptance bound from the GEOMETRY (not the measured buffer):
             # a perfect 1/n split of the leading axis plus at most one
@@ -132,16 +124,12 @@ def main(argv=None):
                 (slab_dev, max_elems * itemsize)
             slack = max_elems * n / plan.fine_size - 1
 
-            t_psum = time_call(f_psum, grids, reps=args.reps)
             t_slab = time_call(f_slab, grids, reps=args.reps)
-            peak_psum = peak_temp_bytes(f_psum, grids)
             peak_slab = peak_temp_bytes(f_slab, grids)
 
             print(f"{f'd={dim} n={level}':>8} {n:>6} "
-                  f"{plan.fine_size * itemsize / 2**20:>8.2f} "
-                  f"{psum_dev / 2**20:>12.3f} {slab_dev / 2**20:>12.3f} "
-                  f"{psum_dev / slab_dev:>8.1f}x {t_psum * 1e3:>9.2f} "
-                  f"{t_slab * 1e3:>9.2f}")
+                  f"{fine_dev / 2**20:>8.2f} {slab_dev / 2**20:>12.3f} "
+                  f"{fine_dev / slab_dev:>8.1f}x {t_slab * 1e3:>9.2f}")
             rows.append({
                 "mode": "1d",
                 "dim": dim, "level": level, "grids": g,
@@ -149,13 +137,12 @@ def main(argv=None):
                 "fine_size": plan.fine_size, "n_groups": n,
                 "slab_rows": splan.slab_rows, "slab_size": splan.slab_size,
                 "dtype_bytes": itemsize,
-                "psum_per_device_embedded_bytes": psum_dev,
+                "fine_bytes": fine_dev,
                 "sharded_per_device_embedded_bytes": slab_dev,
-                "embedded_bytes_ratio": psum_dev / slab_dev,
+                "embedded_bytes_ratio": fine_dev / slab_dev,
                 "ragged_slack": slack,
-                "compiled_peak_temp_bytes_psum": peak_psum,
                 "compiled_peak_temp_bytes_sharded": peak_slab,
-                "psum_s": t_psum, "sharded_s": t_slab,
+                "sharded_s": t_slab,
             })
 
     if args.mesh_2d:

@@ -9,18 +9,17 @@
    shape-signatures share one compiled ingest executable, and queries
    submitted together coalesce into one batched eval dispatch.
 5. Scatter back (``ct_scatter``) for the iterated-CT round trip.
-6. The pre-ExecSpec keywords still work as deprecation shims (warn once).
+6. Bucket merging is one more ``ExecSpec`` field: fewer kernel
+   launches, the same surplus bit for bit.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
-
-import warnings
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import CTEngine, ExecSpec
-from repro.core.executor import ct_scatter, ct_transform
+from repro.core.executor import MergeConfig, ct_scatter, ct_transform
 from repro.core.interpolation import sample_function
 from repro.core.levels import CombinationScheme, grid_shape
 
@@ -80,17 +79,11 @@ def main():
                 for ell, _ in scheme.grids)
     print(f"round-trip drift on consistent grids: {drift:.2e}")
 
-    # --- the legacy kwargs still work (deprecation shims, warn once) ---
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        legacy = ct_transform(nodal_f, scheme, interpret=None,
-                              merge=None)        # defaults: no warning
-        assert not caught
-        from repro.core.executor import MergeConfig
-        legacy = ct_transform(nodal_f, scheme, merge=MergeConfig())
-    assert np.array_equal(np.asarray(legacy), np.asarray(full))
-    print(f"legacy merge= kwarg: same result bit-for-bit, "
-          f"{len(caught)} DeprecationWarning (then silent)")
+    # --- bucket merging: one more ExecSpec field, same surplus ---
+    merged = ct_transform(nodal_f, scheme,
+                          spec=ExecSpec(merge=MergeConfig()))
+    assert np.array_equal(np.asarray(merged), np.asarray(full))
+    print("ExecSpec(merge=MergeConfig()): same result bit-for-bit")
     print("OK")
 
 

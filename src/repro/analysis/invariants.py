@@ -17,7 +17,7 @@ encode the documented order:
 
     cluster(10) -> engine(20) -> future(30) -> store(40)
         -> plan-cache(50) -> ingest-cache(60) -> shared-pool(61)
-        -> warn-once(62) -> ingest-feed(63)
+        -> ingest-feed(63)
 
 i.e. the cluster lock is the outermost lock in the system and the
 module-leaf cache locks are leaves: nothing else may be acquired
@@ -82,7 +82,6 @@ LOCK_RANKS = {
     "plan-cache": 50,    # core/executor.py _PlanCache._lock
     "ingest-cache": 60,  # core/engine.py _INGEST_CACHE_LOCK
     "shared-pool": 61,   # core/engine.py _SHARED_POOL_LOCK
-    "warn-once": 62,     # core/executor.py _WARNED_LEGACY_LOCK
     "ingest-feed": 63,   # core/engine.py CTEngine._feed_lock
 }
 
@@ -102,7 +101,6 @@ LOCK_PATTERNS = (
     ("core/engine.py", "._lock", "engine", False),
     ("core/engine.py", "_INGEST_CACHE_LOCK", "ingest-cache", False),
     ("core/engine.py", "_SHARED_POOL_LOCK", "shared-pool", False),
-    ("core/executor.py", "_WARNED_LEGACY_LOCK", "warn-once", False),
     ("core/executor.py", "._lock", "plan-cache", False),
     ("runtime/cluster.py", "._flock", "future", False),
     ("runtime/cluster.py", "._lock", "cluster", False),
@@ -235,9 +233,8 @@ DONATION_GUARDS = frozenset({"_check_not_donated", "is_deleted"})
 # Bit-identity (left-fold scatter order, PR 3/4/8).
 # --------------------------------------------------------------------
 
-#: Function-name prefixes on the bit-identical scatter path.  The
-#: documented NON-bit-identical path (``gather_full_psum`` /
-#: ``ct_transform_psum``) is deliberately absent.
+#: Function-name prefixes on the bit-identical scatter path: every
+#: gather a plan runs through.
 BIT_CRITICAL_FUNC_PREFIXES = (
     "gather_slab_scatter",   # core/distributed.py slab scatter family
     "_finish_slab_gather",
@@ -261,7 +258,7 @@ INVARIANTS = {
     "lock-order": (
         "Locks are acquired in strictly increasing rank order: "
         "cluster -> engine -> future -> store -> plan-cache -> "
-        "ingest-cache/shared-pool/warn-once.  Module-leaf cache locks "
+        "ingest-cache/shared-pool/ingest-feed.  Module-leaf cache locks "
         "are leaves; nothing may be acquired while one is held. "
         "(PR 6 engine lock redesign; PR 7 cluster->engine order.)"
     ),
@@ -312,8 +309,7 @@ INVARIANTS = {
         "Surplus scatter is a left fold; reassociating reductions "
         "(jnp.sum, lax.psum, segment_sum, ...) are forbidden on the "
         "scatter path so sharded and single-device runs stay "
-        "bit-identical (PR 3/4/8).  gather_full_psum is the "
-        "documented non-bit-identical path and is out of scope."
+        "bit-identical (PR 3/4/8)."
     ),
 }
 

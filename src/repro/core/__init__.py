@@ -17,7 +17,7 @@ Layer map (DESIGN.md Sect. 3):
   interpolation — nodal / hierarchical-basis evaluation (validation anchor)
   pde           — the black-box solvers of the compute phase
   iterated      — the iterated combination technique driver
-  distributed   — shard_map comm phase + grid-group placement + psum gather
+  distributed   — shard_map comm phase (slab-sharded gathers) + grid-group placement
 """
 
 from repro.core.hierarchize import dehierarchize, hierarchize  # noqa: F401

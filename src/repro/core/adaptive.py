@@ -42,8 +42,9 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.executor import (ExecutorPlan, MergeConfig, build_plan,
-                                 ct_transform_with_plan, extend_plan)
+from repro.core.executor import (ExecutorPlan, build_plan,
+                                 ct_transform_with_plan, ensure_spec,
+                                 extend_plan)
 from repro.core.levels import (GeneralScheme, LevelVector,
                                forward_neighbors, is_admissible, num_points,
                                subspace_slices)
@@ -67,11 +68,6 @@ class AdaptiveConfig:
     max_level: Optional[int] = None  # per-axis refinement cap
     indicator: str = "max"          # 'max' | 'l1' | 'mean' over |surplus|
     dtype_bytes: int = 8
-    interpret: Optional[bool] = None  # forwarded to the Pallas kernels
-    #: bucket-merging cost model (repro.core.executor.MergeConfig) for the
-    #: executor plan; extend_plan re-applies it on every expansion, so the
-    #: merge decision survives the whole refinement trajectory
-    merge: Optional["MergeConfig"] = None
 
 
 @dataclass(frozen=True)
@@ -118,40 +114,26 @@ class AdaptiveDriver:
                 raise ValueError("pass dim or an initial GeneralScheme")
             initial = GeneralScheme.regular(dim, 1)   # {(1, ..., 1)}
         self.config = config or AdaptiveConfig()
-        if spec is not None:
-            # spec is authoritative for the execution policy (merge /
-            # interpret); budgets and indicators stay AdaptiveConfig's.
-            # Per the ExecSpec precedence rules, a CONFLICTING explicit
-            # config raises instead of being silently stomped, and spec
-            # fields this single-device driver cannot honor are rejected.
-            from repro.core.executor import ensure_spec
-            ensure_spec("AdaptiveDriver", spec)
-            if spec.mesh is not None or spec.slabs > 1:
-                raise ValueError(
-                    "AdaptiveDriver runs the gather single-device (the "
-                    "refinement loop re-plans every step); a meshed or "
-                    "slab-sharded spec is not supported here — serve the "
-                    "refined scheme through CTEngine instead")
-            if spec.dtype is not None:
-                raise ValueError(
-                    "AdaptiveDriver: spec.dtype is not supported — the "
-                    "driver scores surpluses in the solver's own dtype; "
-                    "cast the solver output instead")
-            for fld in ("merge", "interpret"):
-                have, want = getattr(self.config, fld), getattr(spec, fld)
-                if have is not None and have != want:
-                    raise ValueError(
-                        f"AdaptiveDriver: config.{fld}={have!r} conflicts "
-                        f"with spec.{fld}={want!r}; set the execution "
-                        f"policy in ONE place (the spec)")
-            import dataclasses as _dc
-            self.config = _dc.replace(self.config, merge=spec.merge,
-                                      interpret=spec.interpret)
+        # the spec carries the execution policy (merge / interpret; the
+        # plan's merge model survives every extend_plan); spec fields this
+        # single-device driver cannot honor are rejected
+        spec = ensure_spec("AdaptiveDriver", spec)
+        if spec.mesh is not None or spec.slabs > 1:
+            raise ValueError(
+                "AdaptiveDriver runs the gather single-device (the "
+                "refinement loop re-plans every step); a meshed or "
+                "slab-sharded spec is not supported here — serve the "
+                "refined scheme through CTEngine instead")
+        if spec.dtype is not None:
+            raise ValueError(
+                "AdaptiveDriver: spec.dtype is not supported — the "
+                "driver scores surpluses in the solver's own dtype; "
+                "cast the solver output instead")
         self.spec = spec
         self.solver = solver
         self.scheme = initial
         self._nodal: Dict[LevelVector, jnp.ndarray] = {}
-        self.plan = build_plan(self.scheme, merge=self.config.merge)
+        self.plan = build_plan(self.scheme, merge=spec.merge)
         self.history: List[RefineRecord] = []
         self.stop_reason: Optional[str] = None
         self._solve_missing()
@@ -177,8 +159,8 @@ class AdaptiveDriver:
                 self._nodal[ell] = jnp.asarray(self.solver(ell))
 
     def _retransform(self) -> None:
-        self._surplus = ct_transform_with_plan(
-            self._nodal, self.plan, interpret=self.config.interpret)
+        self._surplus = ct_transform_with_plan(self._nodal, self.plan,
+                                               spec=self.spec)
         self._surplus_host = None        # host copy invalidated
 
     # --- scoring ---

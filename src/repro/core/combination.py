@@ -15,7 +15,7 @@ Two realizations:
   * subspace-keyed (dict of blocks) — memory-proportional to the sparse
     grid, what a production multi-node run exchanges (one reduce per block);
   * embedded (common fine grid)    — each grid scattered into a level-L
-    buffer so gather is ONE dense sum (psum in the distributed version,
+    buffer so gather is ONE dense sum (slab-sharded across devices in
     ``repro.core.distributed``).
 
 Both realizations here are Python dict loops — one dispatch per grid (per

@@ -5,27 +5,18 @@ Parallelism layers (DESIGN.md Sect. 4):
   * across combination grids — the paper's "very coarse" parallelism: each
     grid is solved by one device group; ``plan_grid_groups`` does the
     load-balanced placement (LPT on grid points).
-  * within a grid — pole-parallel hierarchization: sharding any non-working
-    axis needs NO communication; only the transform along the sharded axis
-    itself communicates.  ``hierarchize_sharded`` shards axis 0, runs the
-    tail transform locally and realizes the axis-0 transform as
-    (local operator rows) @ (all-gathered poles) — one all-gather of the
-    grid per full d-dimensional hierarchization.
   * the communication phase — in the hierarchical basis the gather step is
     a single weighted reduction of surpluses embedded in a common fine
-    grid; the scatter step is a local strided read.  Two realizations:
+    grid; the scatter step is a local strided read.  ``ct_transform_sharded``
+    runs the gather on a mesh in one of two ways:
 
-    - grid-replicated (``gather_full_psum`` / ``ct_transform_psum``): the
-      grid axis is sharded and every device materializes full
-      ``fine_shape`` buffers before ONE psum.  Per-device memory is
-      ``(G / n) * fine_size`` — compute scales, memory does not.
-    - slab-sharded (``gather_slab_scatter`` / ``ct_transform_sharded``):
-      the FINE GRID is partitioned into ``n_groups`` contiguous slabs
-      along its leading axis and each device scatter-adds the compact
-      (unembedded) surpluses into ONLY its own slab, followed by one
-      tiled all-gather (or no gather at all: ``gather=False`` returns the
-      slab-sharded buffer under a ``NamedSharding`` for downstream
-      sharded consumers).  Per-device embedded memory is
+    - slab-sharded (``gather_slab_scatter``): the FINE GRID is
+      partitioned into ``n_groups`` contiguous slabs along its leading
+      axis and each device scatter-adds the compact (unembedded)
+      surpluses into ONLY its own slab, followed by one tiled all-gather
+      (or no gather at all: ``gather=False`` returns the slab-sharded
+      buffer under a ``NamedSharding`` for downstream sharded
+      consumers).  Per-device embedded memory is
       ``ceil(fine_shape[0] / n) * row_size`` — memory scales with device
       count; only the compact surpluses (the scheme's point count) are
       replicated.
@@ -87,7 +78,6 @@ Slab partitioning invariants (``repro.core.executor.ShardedPlan``):
 
 from __future__ import annotations
 
-import dataclasses
 from functools import partial
 from typing import Sequence, Tuple
 
@@ -97,13 +87,10 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.levels import (LevelVector, SchemeLike, fine_levels,
-                               num_points)
-from repro.kernels.hierarchize import _padded_operator  # shared constant builder
+from repro.core.levels import LevelVector, SchemeLike, num_points
 
-__all__ = ["plan_grid_groups", "hierarchize_sharded", "gather_full_psum",
-           "gather_slab_scatter", "gather_slab_scatter_2d", "comm_phase_sharded",
-           "ct_transform_psum", "ct_transform_sharded"]
+__all__ = ["plan_grid_groups", "gather_slab_scatter", "gather_slab_scatter_2d",
+           "ct_transform_sharded"]
 
 
 def plan_grid_groups(scheme: SchemeLike, num_groups: int
@@ -125,72 +112,8 @@ def plan_grid_groups(scheme: SchemeLike, num_groups: int
 
 
 # ---------------------------------------------------------------------------
-# Pole-parallel hierarchization under shard_map
-# ---------------------------------------------------------------------------
-
-def hierarchize_sharded(x_padded: jnp.ndarray, level0: int, mesh: Mesh,
-                        axis_name: str) -> jnp.ndarray:
-    """Hierarchize a d-dim grid whose axis 0 is padded to 2**level0 and
-    sharded over ``axis_name``; remaining axes are unpadded (2**l - 1) and
-    replicated.
-
-    Communication: exactly one all-gather of the array (the axis-0
-    transform); the tail axes are transformed locally.
-    """
-    n0p = x_padded.shape[0]
-    assert n0p == 1 << level0, "axis 0 must be padded to 2**level0"
-    nshards = mesh.shape[axis_name]
-    assert n0p % nshards == 0
-    shard = n0p // nshards
-    hmat = jnp.asarray(_padded_operator(level0, np.float32, npad=n0p),
-                       dtype=x_padded.dtype)
-
-    def local_fn(h, x_loc):
-        # tail axes: pole bundles are fully local -> no communication
-        if x_loc.ndim > 1:
-            x_loc = _hier_tail_local(x_loc)
-        # axis 0: rows of the operator live here, columns are all-gathered
-        xg = jax.lax.all_gather(x_loc, axis_name, axis=0, tiled=True)
-        i = jax.lax.axis_index(axis_name)
-        h_rows = jax.lax.dynamic_slice_in_dim(h, i * shard, shard, axis=0)
-        return jnp.tensordot(h_rows, xg, axes=[[1], [0]]).astype(x_loc.dtype)
-
-    def _hier_tail_local(x_loc):
-        for ax in range(1, x_loc.ndim):
-            moved = jnp.moveaxis(x_loc, ax, 0)
-            from repro.kernels.ref import hierarchize_1d_ref
-            moved = hierarchize_1d_ref(moved, axis=0)
-            x_loc = jnp.moveaxis(moved, 0, ax)
-        return x_loc
-
-    spec = P(axis_name, *([None] * (x_padded.ndim - 1)))
-    fn = jax.shard_map(partial(local_fn, hmat), mesh=mesh,
-                       in_specs=(spec,), out_specs=spec, check_vma=False)
-    return fn(x_padded)
-
-
-# ---------------------------------------------------------------------------
 # Communication phase across grid groups
 # ---------------------------------------------------------------------------
-
-def gather_full_psum(embedded: jnp.ndarray, coeff: jnp.ndarray, mesh: Mesh,
-                     axis_name: str) -> jnp.ndarray:
-    """Gather step over grid groups: combined = psum_g coeff_g * embedded_g.
-
-    ``embedded``: (G, *full_shape) — group g's hierarchized surpluses already
-    embedded in the common fine grid (zero where the grid has no node);
-    sharded over ``axis_name``.  Returns the replicated combined buffer.
-    """
-    def local_fn(e_loc, c_loc):
-        contrib = jnp.tensordot(c_loc, e_loc, axes=[[0], [0]])
-        return jax.lax.psum(contrib, axis_name)
-
-    in_specs = (P(axis_name, *([None] * (embedded.ndim - 1))), P(axis_name))
-    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=P(*([None] * (embedded.ndim - 1))),
-                       check_vma=False)
-    return fn(embedded, coeff)
-
 
 def _check_slab_gather_args(splan, mesh: Mesh, axis_name: str,
                             n_inputs: int, what: str) -> None:
@@ -419,22 +342,19 @@ def gather_slab_scatter_2d(stacks, sharded_plan, mesh: Mesh,
 def ct_transform_sharded(nodal_grids, scheme: SchemeLike, mesh: Mesh,
                          axis_name: str, *,
                          full_levels: Sequence[int] | None = None,
-                         plan=None, sharded_plan=None, gather: bool = True,
-                         interpret: bool | None = None,
+                         plan=None, gather: bool = True,
                          spec=None, member_axis: str | None = None
                          ) -> jnp.ndarray:
     """Memory-scaling distributed gather: bucket-batched hierarchization,
-    then the slab-sharded scatter-add — the multi-device ``ct_transform``
-    whose per-device embedded memory is ``fine_size / n_groups``, not
-    ``G * fine_size``.
+    then the slab-sharded scatter-add — the multi-device ``ct_transform``,
+    whose per-device embedded memory is ``fine_size / n_groups``.
 
     Pass ``plan`` (a ``repro.core.executor.shard_plan`` result) to reuse
     a live plan (the adaptive / fault path); otherwise one is built for
     ``mesh.shape[axis_name]`` slabs.  ``gather=False`` returns the
     slab-sharded fine buffer (see ``gather_slab_scatter``).  ``spec``
-    (a ``repro.core.engine.ExecSpec``) consolidates
-    ``interpret``/``merge``; the bare ``interpret=`` kwarg and the old
-    ``sharded_plan=`` spelling of ``plan=`` remain as deprecation shims.
+    (a ``repro.core.engine.ExecSpec``) supplies ``interpret`` and
+    ``merge``.
 
     ``member_axis`` (or ``spec.member_axis``) names the SECOND axis of a
     2-D (member x slab) mesh: the ingest then also compute-shards the
@@ -442,15 +362,9 @@ def ct_transform_sharded(nodal_grids, scheme: SchemeLike, mesh: Mesh,
     ``gather_slab_scatter_2d`` (bit-identical; see the module notes).
     """
     from repro.core.executor import (build_plan, bucket_nodal_stacks,
-                                     bucket_surpluses, resolve_spec,
-                                     shard_plan, warn_legacy_kwargs)
-    if sharded_plan is not None:
-        if plan is not None:
-            raise ValueError("ct_transform_sharded: pass plan= or the "
-                             "deprecated sharded_plan=, not both")
-        warn_legacy_kwargs("ct_transform_sharded", ("sharded_plan",))
-        plan = sharded_plan
-    spec = resolve_spec("ct_transform_sharded", spec, interpret=interpret)
+                                     bucket_surpluses, ensure_spec,
+                                     shard_plan)
+    spec = ensure_spec("ct_transform_sharded", spec)
     interpret = spec.interpret
     if member_axis is None:
         member_axis = spec.member_axis
@@ -458,128 +372,20 @@ def ct_transform_sharded(nodal_grids, scheme: SchemeLike, mesh: Mesh,
     if member_axis is not None:
         n_groups = (int(mesh.shape[member_axis])
                     * int(mesh.shape[axis_name]))
-    sharded_plan = plan
-    if sharded_plan is None:
-        sharded_plan = shard_plan(build_plan(scheme, full_levels,
-                                             merge=spec.merge),
-                                  mesh.shape[axis_name],
-                                  n_groups=n_groups)
-    elif full_levels is not None and sharded_plan.full_levels != \
+    if plan is None:
+        plan = shard_plan(build_plan(scheme, full_levels, merge=spec.merge),
+                          mesh.shape[axis_name], n_groups=n_groups)
+    elif full_levels is not None and plan.full_levels != \
             tuple(int(l) for l in full_levels):
         raise ValueError(
-            f"sharded_plan embeds into {sharded_plan.full_levels}, caller "
+            f"plan embeds into {plan.full_levels}, caller "
             f"asked for {tuple(int(l) for l in full_levels)}")
     if member_axis is not None and n_groups > 1:
-        # 2-D compute-sharded route.  A degenerate 1x1 mesh has nothing to compute-shard and falls
-        # through to the classic slab path.
-        stacks = bucket_nodal_stacks(nodal_grids, sharded_plan.plan)
-        return gather_slab_scatter_2d(stacks, sharded_plan, mesh,
+        # 2-D compute-sharded route.  A degenerate 1x1 mesh has nothing
+        # to compute-shard and falls through to the slab path.
+        stacks = bucket_nodal_stacks(nodal_grids, plan.plan)
+        return gather_slab_scatter_2d(stacks, plan, mesh,
                                       member_axis, axis_name,
                                       gather=gather, interpret=interpret)
-    alphas = bucket_surpluses(nodal_grids, sharded_plan.plan,
-                              interpret=interpret)
-    return gather_slab_scatter(alphas, sharded_plan, mesh, axis_name,
-                               gather=gather)
-
-
-def comm_phase_sharded(hier_grids, scheme: SchemeLike, mesh: Mesh,
-                       axis_name: str, full_levels: Sequence[int] | None = None,
-                       sharded_plan=None, *, plan=None, spec=None):
-    """Full communication phase: gather + per-grid extract.
-
-    Single-controller convenience wrapper.  Default (``plan=None``)
-    is the grid-replicated psum: embeds every grid, stacks, psums over the
-    grid axis.  With a slab-sharded ``plan`` — or a sharded ``spec``, from
-    which one is built — the gather runs slab-sharded instead: the
-    already-hierarchized grids are packed into compact bucket rows (no
-    ``(G, *fine_shape)`` stack is ever materialized) and scatter-added
-    slab-locally.  In a multi-controller deployment each group computes
-    only its own embed/extract.  ``sharded_plan=`` is the deprecated
-    spelling of ``plan=``.
-    """
-    from repro.core.combination import embed_to_full, extract_from_full
-    from repro.core.executor import (build_plan, ensure_spec,
-                                     warn_legacy_kwargs)
-    ensure_spec("comm_phase_sharded", spec)
-    if sharded_plan is not None:
-        if plan is not None:
-            raise ValueError("comm_phase_sharded: pass plan= or the "
-                             "deprecated sharded_plan=, not both")
-        warn_legacy_kwargs("comm_phase_sharded", ("sharded_plan",))
-    else:
-        sharded_plan = plan
-    if sharded_plan is None and spec is not None and spec.slabs > 1:
-        sharded_plan = build_plan(scheme, full_levels, spec=spec)
-    if full_levels is None:
-        full_levels = fine_levels(scheme)
-    ells = [ell for ell, _ in scheme.grids]
-    if sharded_plan is not None:
-        from repro.core.executor import _assemble_bucket
-        if sharded_plan.full_levels != tuple(full_levels):
-            raise ValueError(
-                f"sharded_plan embeds into {sharded_plan.full_levels}, "
-                f"comm phase asked for {tuple(full_levels)}")
-        alphas = [_assemble_bucket(hier_grids, b).reshape(len(b.ells), -1)
-                  for b in sharded_plan.plan.buckets]
-        combined = gather_slab_scatter(alphas, sharded_plan, mesh, axis_name)
-        return {ell: extract_from_full(combined, ell, full_levels)
-                for ell in ells}
-    coeffs = jnp.asarray([float(c) for _, c in scheme.grids])
-    emb = jnp.stack([embed_to_full(hier_grids[ell], ell, full_levels)
-                     for ell in ells])
-    g = emb.shape[0]
-    nshards = mesh.shape[axis_name]
-    pad = (-g) % nshards
-    if pad:
-        emb = jnp.pad(emb, [(0, pad)] + [(0, 0)] * (emb.ndim - 1))
-        coeffs = jnp.pad(coeffs, (0, pad))
-    combined = gather_full_psum(emb, coeffs, mesh, axis_name)
-    return {ell: extract_from_full(combined, ell, full_levels) for ell in ells}
-
-
-def ct_transform_psum(nodal_grids, scheme: SchemeLike, mesh: Mesh,
-                      axis_name: str,
-                      full_levels: Sequence[int] | None = None,
-                      sharded_plan=None, *, plan=None,
-                      spec=None) -> jnp.ndarray:
-    """Distributed batched gather: the executor's bucket-batched
-    hierarchization + static index plan produce the per-grid embedded
-    surpluses, then ONE weighted psum over grid groups combines them —
-    the multi-node realization of ``repro.core.executor.ct_transform``.
-
-    Returns the replicated sparse-grid surplus on the common fine grid.
-    Pass a slab-sharded ``plan`` (or a spec with ``n_slabs``) to run the
-    memory-scaling slab-sharded gather instead (no ``(G, *fine_shape)``
-    stack is materialized; see ``ct_transform_sharded``) — same result,
-    per-device embedded memory ``fine_size / n_groups``.
-    ``sharded_plan=`` is the deprecated spelling of ``plan=``.
-    """
-    from repro.core.executor import resolve_spec, warn_legacy_kwargs
-    if sharded_plan is not None:
-        if plan is not None:
-            raise ValueError("ct_transform_psum: pass plan= or the "
-                             "deprecated sharded_plan=, not both")
-        warn_legacy_kwargs("ct_transform_psum", ("sharded_plan",))
-        plan = sharded_plan
-    spec = resolve_spec("ct_transform_psum", spec)
-    if plan is None and spec.slabs > 1:
-        from repro.core.executor import build_plan
-        plan = build_plan(scheme, full_levels, spec=spec)
-    if plan is not None:
-        return ct_transform_sharded(nodal_grids, scheme, mesh, axis_name,
-                                    full_levels=full_levels, plan=plan,
-                                    spec=dataclasses.replace(
-                                        spec, mesh=None, n_slabs=None))
-    from repro.core.executor import ct_embedded
-    embedded, coeffs, _ = ct_embedded(nodal_grids, scheme,
-                                      full_levels=full_levels,
-                                      spec=spec)
-    g = embedded.shape[0]
-    nshards = mesh.shape[axis_name]
-    pad = (-g) % nshards
-    if pad:
-        embedded = jnp.pad(embedded,
-                           [(0, pad)] + [(0, 0)] * (embedded.ndim - 1))
-        coeffs = jnp.pad(coeffs, (0, pad))
-    return gather_full_psum(embedded, coeffs.astype(embedded.dtype),
-                            mesh, axis_name)
+    alphas = bucket_surpluses(nodal_grids, plan.plan, interpret=interpret)
+    return gather_slab_scatter(alphas, plan, mesh, axis_name, gather=gather)

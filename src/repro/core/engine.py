@@ -1,18 +1,13 @@
 """Unified CT execution front door: ``ExecSpec`` + multi-tenant ``CTEngine``.
 
-After PRs 1-4 the execution options (bucket merging, mesh/slab sharding,
-interpret mode) were threaded as ad-hoc kwargs through
-four parallel entry-point families (``ct_transform*``,
-``ct_transform_psum``/``ct_transform_sharded``, ``CTSurrogate``,
-``make_ct_step``) — every new capability multiplied the API surface.
-This module consolidates them behind two objects:
+Two objects:
 
 * ``ExecSpec`` — ONE frozen, hashable dataclass carrying every execution
-  policy.  Every consolidated entry point (``build_plan``,
-  ``extend_plan``, ``shard_plan``, ``ct_transform*``,
-  ``ct_transform_psum``, ``ct_transform_sharded``,
+  policy (bucket merging, mesh/slab sharding, interpret mode, dtype).
+  Every entry point (``build_plan``, ``extend_plan``, ``shard_plan``,
+  ``ct_transform*``, ``ct_scatter``, ``ct_transform_sharded``,
   ``recombine_after_fault``, ``AdaptiveDriver``, ``make_ct_step``,
-  ``CTSurrogate``) accepts ``spec=``.
+  ``CTSurrogate``) takes it as ``spec=``; there is no second spelling.
 * ``CTEngine`` — a THREAD-SAFE multi-tenant registry serving N named
   surrogates (scheme + plan + spec each) behind a deadline-aware
   continuous-batching queue, with jitted ingest executables DEDUPED
@@ -21,18 +16,13 @@ This module consolidates them behind two objects:
 ExecSpec precedence rules
 -------------------------
 
-1. **spec wins, conflicts raise.**  An explicit ``spec=`` is
-   authoritative; combining it with a non-``None`` legacy kwarg
-   (``merge=``, ``mesh=``, ``interpret=``, ...) on the same
-   call raises ``ValueError`` instead of guessing which one the caller
-   meant.
-2. **Legacy kwargs construct a spec.**  Called without ``spec=``, the
-   legacy kwargs are folded into the equivalent ``ExecSpec`` and the
-   call proceeds unchanged — plus ONE ``DeprecationWarning`` per
-   (function, kwarg-set) family per process
-   (``reset_deprecation_warnings`` rearms them, for tests; the
-   warn-once registry is lock-guarded, so concurrent legacy callers
-   still warn exactly once per family).
+1. **Conflicts raise.**  Fields that can disagree (``n_slabs`` against
+   the mesh axis extent, ``member_axis`` against ``axis_name``) raise
+   ``ValueError`` at construction instead of guessing which one the
+   caller meant.
+2. **A spec is an ExecSpec.**  ``spec=`` given anything else raises a
+   named ``TypeError``.  ``spec=None`` means ``ExecSpec()`` at a free
+   function and the engine's own spec at ``CTEngine.register``.
 3. **Field-level defaults resolve as late as possible.**
    ``n_slabs=None`` means "the mesh axis extent" (``spec.slabs``);
    ``interpret=None`` means "ask ``repro.kernels.hierarchize.
@@ -42,15 +32,6 @@ ExecSpec precedence rules
    doors (``ct_transform``, ``CTEngine``, ``CTSurrogate``) run the
    slab-sharded gather over ``mesh.shape[axis_name]`` device groups;
    everything else (merge, interpret) composes orthogonally.
-
-Deprecation policy
-------------------
-
-The legacy kwargs keep working for at least one release cycle of this
-repo's PR sequence: they are thin shims that build the equivalent
-``ExecSpec`` and warn ONCE per call-site family — so a long-running
-driver loop does not drown in warnings, while every distinct legacy call
-site still gets flagged.  New capabilities land as ExecSpec fields only.
 
 CTEngine threading contract
 ---------------------------
@@ -103,7 +84,7 @@ registry and every enforced rule live in
 ``python -m repro.analysis`` and at runtime under ``REPRO_LOCKDEP=1``);
 the short version: engine(20) sits between cluster(10) and the
 module-level cache locks (ingest-executable cache, ``build_plan``
-cache, warn-once registry), which are LEAVES — never held while
+cache), which are LEAVES — never held while
 taking an engine lock — and no device dispatch ever runs under ANY
 lock.
 
@@ -197,20 +178,14 @@ from repro.analysis import lockdep as _lockdep
 from repro.core.executor import (ExecutorPlan, MergeConfig, ShardedPlan,
                                  _assemble_members, _check_nodal_grids,
                                  _gather_compact, build_plan, extend_plan,
-                                 reset_legacy_warnings, shard_plan)
+                                 shard_plan)
 from repro.core.interpolation import interpolate_hierarchical
 from repro.core.levels import SchemeLike, grid_shape
 from repro.kernels.hierarchize import hierarchize_batched, interpret_default
 from repro.runtime.durability import DurableStore, RetryPolicy
 
 __all__ = ["ExecSpec", "CTEngine", "CTFuture", "EngineSaturated",
-           "IngestBuffersDonated", "RestoreInfo",
-           "reset_deprecation_warnings", "clear_compile_cache"]
-
-
-def reset_deprecation_warnings() -> None:
-    """Re-arm the once-per-call-site legacy-kwarg warnings (tests)."""
-    reset_legacy_warnings()
+           "IngestBuffersDonated", "RestoreInfo", "clear_compile_cache"]
 
 
 class EngineSaturated(RuntimeError):

@@ -53,12 +53,13 @@ function:
      bitwise the per-bucket gather into the fine buffer (within a
      bucket, the order of a scatter's duplicate indices is XLA's).
 
-``ct_transform`` / ``ct_scatter`` are end-to-end jittable (scheme static),
-reused by the distributed psum path (``repro.core.distributed.
-ct_transform_psum``) and the surrogate-serving driver
-(``repro.launch.serve.CTSurrogate``).  Schemes are duck-typed: the
-classical ``CombinationScheme`` and the downward-closed ``GeneralScheme``
-(adaptive / fault-reduced index sets) both work everywhere.
+``ct_transform`` / ``ct_scatter`` are end-to-end jittable (scheme static);
+a meshed ``ExecSpec`` routes ``ct_transform`` through the slab-sharded
+gather (``repro.core.distributed.ct_transform_sharded``).  Every entry
+point takes its execution policy as one ``spec=repro.core.engine.
+ExecSpec``.  Schemes are duck-typed: the classical ``CombinationScheme``
+and the downward-closed ``GeneralScheme`` (adaptive / fault-reduced
+index sets) both work everywhere.
 
 **Incremental-rebuild contract** (the adaptive/fault hot path):
 
@@ -89,8 +90,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import threading
-import warnings
 from dataclasses import dataclass
 from typing import Dict, Mapping, Optional, Sequence, Tuple
 
@@ -107,86 +106,26 @@ from repro.kernels.hierarchize import (batched_method, dehierarchize_batched,
 __all__ = ["ExecutorPlan", "Bucket", "ShardedPlan", "SlabBucket",
            "MergeConfig", "build_plan", "shard_plan", "extend_plan",
            "update_plan_coefficients", "ct_transform", "ct_scatter",
-           "ct_embedded", "ct_transform_with_plan", "ct_scatter_with_plan",
-           "ct_embedded_with_plan", "bucket_surpluses", "bucket_nodal_stacks",
+           "ct_transform_with_plan", "ct_scatter_with_plan",
+           "bucket_surpluses", "bucket_nodal_stacks",
            "plan_launch_stats", "plan_ingest_stats", "clear_plan_cache"]
 
 
-# ---------------------------------------------------------------------------
-# Legacy-kwarg deprecation shims (ExecSpec consolidation, PR 5)
-# ---------------------------------------------------------------------------
-
-#: (function name, sorted kwarg names) combinations already warned about —
-#: each legacy call-site family warns exactly ONCE per process.  Guarded
-#: by ``_WARNED_LEGACY_LOCK``: the bare check-then-add was a race (two
-#: threads hitting the same legacy call site concurrently both missed the
-#: set and warned twice, breaking the warn-once contract).  Tests reset
-#: via ``repro.core.engine.reset_deprecation_warnings``.
-_WARNED_LEGACY: set = set()
-_WARNED_LEGACY_LOCK = _lockdep.make_lock("warn-once")
-
-
-def reset_legacy_warnings() -> None:
-    """Re-arm every once-per-call-site legacy-kwarg warning (tests)."""
-    with _WARNED_LEGACY_LOCK:
-        _WARNED_LEGACY.clear()
-
-
-def warn_legacy_kwargs(fn_name: str, kwarg_names: Sequence[str]) -> None:
-    """One ``DeprecationWarning`` per (function, kwargs) combination: the
-    scattered execution kwargs (``merge=``, ``mesh=``, ``sharded_plan=``,
-    ``interpret=``, ...) keep working but should be replaced
-    by one ``spec=repro.core.engine.ExecSpec(...)``.  Thread-safe: the
-    first thread to claim the (function, kwargs) key warns; concurrent
-    callers of the same family stay silent."""
-    key = (fn_name, tuple(sorted(kwarg_names)))
-    with _WARNED_LEGACY_LOCK:
-        if key in _WARNED_LEGACY:
-            return
-        _WARNED_LEGACY.add(key)
-    shown = ", ".join(f"{k}=" for k in sorted(kwarg_names))
-    warnings.warn(
-        f"{fn_name}: keyword(s) {shown} are deprecated; pass "
-        f"spec=repro.core.engine.ExecSpec(...) instead (the legacy "
-        f"keywords are folded into an ExecSpec and keep working)",
-        DeprecationWarning, stacklevel=3)
-
-
-def ensure_spec(fn_name: str, spec) -> None:
-    """Named ``TypeError`` when ``spec=`` receives a non-ExecSpec — the
-    API-redesign trap is an old POSITIONAL caller whose third argument
-    (e.g. ``CTSurrogate(scheme, grids, True)``, once ``interpret``) now
-    lands in ``spec`` and would otherwise die on an opaque attribute
-    error deep inside plan construction."""
+def ensure_spec(fn_name: str, spec):
+    """The spec an entry point runs under: ``spec`` itself, or
+    ``ExecSpec()`` for ``None``.  A named ``TypeError`` when ``spec=``
+    receives a non-ExecSpec — e.g. a POSITIONAL caller whose third
+    argument (``CTSurrogate(scheme, grids, True)``) lands in ``spec``
+    and would otherwise die on an opaque attribute error deep inside
+    plan construction."""
     from repro.core.engine import ExecSpec
-    if spec is not None and not isinstance(spec, ExecSpec):
+    if spec is None:
+        return ExecSpec()
+    if not isinstance(spec, ExecSpec):
         raise TypeError(
             f"{fn_name}: spec must be a repro.core.engine.ExecSpec, got "
-            f"{type(spec).__name__}; legacy options go in their (deprecated)"
-            f" keywords, e.g. interpret=..., not positionally")
-
-
-def resolve_spec(fn_name: str, spec, **legacy):
-    """Fold legacy execution kwargs into an ``ExecSpec`` (the deprecation
-    shim behind every consolidated entry point).
-
-    Precedence (documented in ``repro.core.engine``): an explicit
-    ``spec=`` is authoritative — combining it with a non-``None`` legacy
-    kwarg raises instead of guessing; legacy kwargs alone construct the
-    equivalent spec and warn once per call-site family."""
-    from repro.core.engine import ExecSpec
-    ensure_spec(fn_name, spec)
-    given = {k: v for k, v in legacy.items() if v is not None}
-    if spec is None:
-        spec = ExecSpec()
-    elif given:
-        shown = ", ".join(f"{k}=" for k in sorted(given))
-        raise ValueError(
-            f"{fn_name}: pass either spec= or the legacy keyword(s) "
-            f"{shown}, not both (fold them into the ExecSpec)")
-    if given:
-        warn_legacy_kwargs(fn_name, tuple(given))
-        spec = dataclasses.replace(spec, **given)
+            f"{type(spec).__name__}; execution options are ExecSpec "
+            f"fields, e.g. spec=ExecSpec(interpret=...)")
     return spec
 
 
@@ -942,23 +881,18 @@ def _assemble_bucket(nodal_grids: Mapping[LevelVector, jnp.ndarray],
 def ct_transform(nodal_grids: Mapping[LevelVector, jnp.ndarray],
                  scheme: SchemeLike, *,
                  full_levels: Optional[Sequence[int]] = None,
-                 interpret: Optional[bool] = None,
-                 merge: Optional[MergeConfig] = None,
                  spec=None) -> jnp.ndarray:
     """Gather phase, batched: nodal component grids -> sparse-grid surplus
     on the common fine grid.  Equals hierarchize-per-grid + ``combine_full``
     to machine precision, in one jittable computation.
 
-    THE front-door transform of the consolidated API: ``spec`` (a
-    ``repro.core.engine.ExecSpec``) carries every execution policy —
-    ``spec.merge`` opts into cost-model-driven bucket merging
+    ``spec`` (a ``repro.core.engine.ExecSpec``) carries every execution
+    policy — ``spec.merge`` opts into cost-model-driven bucket merging
     (bit-identical result, fewer kernel launches), a meshed spec routes
     through the slab-sharded multi-device gather
-    (``repro.core.distributed.ct_transform_sharded``).  The bare
-    ``interpret``/``merge`` kwargs remain as deprecation shims.
+    (``repro.core.distributed.ct_transform_sharded``).
     """
-    spec = resolve_spec("ct_transform", spec,
-                        interpret=interpret, merge=merge)
+    spec = ensure_spec("ct_transform", spec)
     if spec.mesh is not None:
         from repro.core.distributed import ct_transform_sharded
         return ct_transform_sharded(nodal_grids, scheme, spec.mesh,
@@ -967,7 +901,7 @@ def ct_transform(nodal_grids: Mapping[LevelVector, jnp.ndarray],
     return ct_transform_with_plan(nodal_grids,
                                   build_plan(scheme, full_levels,
                                              merge=spec.merge),
-                                  interpret=spec.interpret)
+                                  spec=spec)
 
 
 def bucket_surpluses(nodal_grids: Mapping[LevelVector, jnp.ndarray],
@@ -1046,35 +980,26 @@ def _gather_compact(stacks: Sequence[jnp.ndarray],
 
 def ct_transform_with_plan(nodal_grids: Mapping[LevelVector, jnp.ndarray],
                            plan: ExecutorPlan, *,
-                           interpret: Optional[bool] = None,
                            spec=None) -> jnp.ndarray:
     """``ct_transform`` against an explicit (possibly incrementally rebuilt)
     plan — the adaptive-refinement / fault-recovery entry point.  A
     ``ShardedPlan`` is accepted and runs through its base plan (the
-    single-device fallback; the multi-device execution lives in
-    ``repro.core.distributed.ct_transform_sharded``).  ``spec`` (a
-    ``repro.core.engine.ExecSpec``) supplies ``interpret`` instead of the
-    bare kwarg; a MESHED spec routes the sharded plan through the
-    slab-sharded gather."""
-    if spec is not None:
-        ensure_spec("ct_transform_with_plan", spec)
-        if interpret is not None:
-            raise ValueError("ct_transform_with_plan: pass spec or the "
-                             "bare interpret kwarg, not both")
-        interpret = spec.interpret
-        if spec.mesh is not None:
-            if not isinstance(plan, ShardedPlan):
-                raise ValueError(
-                    "ct_transform_with_plan: spec has a mesh but the plan "
-                    "is not slab-sharded — build it with build_plan(scheme, "
-                    "spec=spec) (or shard_plan) so the multi-device gather "
-                    "can run; a meshed spec never silently degrades to the "
-                    "single-device path")
-            from repro.core.distributed import ct_transform_sharded
-            return ct_transform_sharded(nodal_grids, None, spec.mesh,
-                                        spec.axis_name, plan=plan,
-                                        spec=dataclasses.replace(spec,
-                                                                 mesh=None))
+    single-device fallback); a MESHED ``spec`` routes the sharded plan
+    through the slab-sharded gather
+    (``repro.core.distributed.ct_transform_sharded``)."""
+    spec = ensure_spec("ct_transform_with_plan", spec)
+    if spec.mesh is not None:
+        if not isinstance(plan, ShardedPlan):
+            raise ValueError(
+                "ct_transform_with_plan: spec has a mesh but the plan "
+                "is not slab-sharded — build it with build_plan(scheme, "
+                "spec=spec) (or shard_plan) so the multi-device gather "
+                "can run; a meshed spec never silently degrades to the "
+                "single-device path")
+        from repro.core.distributed import ct_transform_sharded
+        return ct_transform_sharded(nodal_grids, None, spec.mesh,
+                                    spec.axis_name, plan=plan,
+                                    spec=dataclasses.replace(spec, mesh=None))
     if isinstance(plan, ShardedPlan):
         plan = plan.plan
     _check_nodal_grids(nodal_grids, plan)
@@ -1085,21 +1010,18 @@ def ct_transform_with_plan(nodal_grids: Mapping[LevelVector, jnp.ndarray],
         [_assemble_bucket(nodal_grids, b) for b in plan.buckets],
         [b.levels for b in plan.buckets], compact.buckets,
         [jnp.asarray(b.coeffs, dtype) for b in plan.buckets],
-        compact.fine, plan.fine_shape, dtype, interpret=interpret)
+        compact.fine, plan.fine_shape, dtype, interpret=spec.interpret)
 
 
 def ct_scatter(full: jnp.ndarray, scheme: SchemeLike, *,
                full_levels: Optional[Sequence[int]] = None,
-               interpret: Optional[bool] = None,
-               merge: Optional[MergeConfig] = None,
                spec=None) -> Dict[LevelVector, jnp.ndarray]:
     """Scatter phase, batched: sparse-grid surplus -> nodal values of the
     combined solution on every component grid (truncating projection +
     batched dehierarchization; inverse-direction read of the index plan).
-    ``spec`` consolidates the execution kwargs; the bare
-    ``interpret``/``merge`` remain as deprecation shims.
+    ``spec`` supplies ``merge`` and ``interpret``.
     """
-    spec = resolve_spec("ct_scatter", spec, interpret=interpret, merge=merge)
+    spec = ensure_spec("ct_scatter", spec)
     return ct_scatter_with_plan(full,
                                 build_plan(scheme, full_levels,
                                            merge=spec.merge),
@@ -1127,61 +1049,6 @@ def ct_scatter_with_plan(full: jnp.ndarray, plan: ExecutorPlan, *,
             inv = np.argsort(np.asarray(perm))
             out[ell] = jnp.transpose(nodal[i][sl], tuple(inv))
     return out
-
-
-def ct_embedded(nodal_grids: Mapping[LevelVector, jnp.ndarray],
-                scheme: SchemeLike, *,
-                full_levels: Optional[Sequence[int]] = None,
-                interpret: Optional[bool] = None,
-                spec=None
-                ) -> Tuple[jnp.ndarray, jnp.ndarray, Tuple[LevelVector, ...]]:
-    """Per-grid UNWEIGHTED embedded surpluses, batched: the distributed
-    gather input (``core.distributed.ct_transform_psum`` psums
-    ``coeffs @ embedded`` over grid groups).
-
-    Returns ``(embedded (G, *fine_shape), coeffs (G,), grid order)``.
-    """
-    spec = resolve_spec("ct_embedded", spec, interpret=interpret)
-    return ct_embedded_with_plan(nodal_grids,
-                                 build_plan(scheme, full_levels,
-                                            merge=spec.merge),
-                                 interpret=spec.interpret)
-
-
-def ct_embedded_with_plan(nodal_grids: Mapping[LevelVector, jnp.ndarray],
-                          plan: ExecutorPlan, *,
-                          interpret: Optional[bool] = None
-                          ) -> Tuple[jnp.ndarray, jnp.ndarray,
-                                     Tuple[LevelVector, ...]]:
-    """``ct_embedded`` against an explicit plan.
-
-    The per-bucket embed is ONE flat scatter vectorized over the member
-    axis: the static index map is offset per member row at plan-read time
-    (``g * (fine_size + 1) + index[g]``, a numpy constant under jit), so
-    the map is materialized once per bucket instead of once per member and
-    the write lowers as a single 1-D scatter instead of a 2-D advanced-
-    indexing update."""
-    if isinstance(plan, ShardedPlan):
-        plan = plan.plan
-    _check_nodal_grids(nodal_grids, plan)
-    dtype = jnp.result_type(*(jnp.asarray(v).dtype
-                              for v in nodal_grids.values()))
-    row = plan.fine_size + 1                      # +1: per-member dump slot
-    chunks, coeffs, order = [], [], []
-    for bucket in plan.buckets:
-        g = len(bucket.ells)
-        x = _assemble_bucket(nodal_grids, bucket)
-        alpha = hierarchize_batched(x, bucket.levels, interpret=interpret)
-        flat_idx = (np.arange(g, dtype=np.int64)[:, None] * row
-                    + bucket.index).ravel()
-        buf = jnp.zeros(g * row, dtype)
-        buf = buf.at[jnp.asarray(flat_idx)].set(alpha.reshape(-1))
-        chunks.append(buf.reshape(g, row)[:, :-1]
-                      .reshape((g,) + plan.fine_shape))
-        coeffs.append(bucket.coeffs)
-        order.extend(bucket.ells)
-    return (jnp.concatenate(chunks), jnp.asarray(np.concatenate(coeffs)),
-            tuple(order))
 
 
 def plan_launch_stats(plan: ExecutorPlan, *,

@@ -62,8 +62,7 @@ def build_comm_phase(ct, mesh):
 
     # axis 0 is padded to 2**l so it shards over the model axis (2**l - 1
     # is never divisible by a power of two); the operator is identity on
-    # the pad rows, exactly like the pole-parallel path in
-    # core/distributed.py
+    # the pad rows
     n0_pad = 1 << fine[0]
     ops = [jnp.asarray(_padded_operator(fine[0], np.float32, npad=n0_pad))]
     ops += [jnp.asarray(operator_matrix(l), jnp.float32) for l in fine[1:]]

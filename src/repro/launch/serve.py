@@ -12,7 +12,7 @@ sampling, per-request stop lengths.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -117,9 +117,7 @@ class CTSurrogate:
     served surplus stays replicated, so the query path is unchanged),
     ``ExecSpec(merge=...)`` turns on cost-model-driven bucket merging,
     and both survive ``refit`` / ``drop_grid`` through the incremental
-    rebuilds.  The pre-ExecSpec keywords (``interpret=``, ``mesh=``,
-    ``axis_name=``, ``merge=``) keep working as deprecation shims that
-    fold into a spec and warn once.
+    rebuilds.
 
     The backing engine is thread-safe: ``submit_query`` /
     ``submit_update`` enqueue from any thread and return ``CTFuture``
@@ -150,11 +148,9 @@ class CTSurrogate:
 
     def __init__(self, scheme, nodal_grids, spec=None, *,
                  engine=None, cluster=None, name: str = "surrogate",
-                 store=None, snapshot_interval: int = 16,
-                 interpret: Optional[bool] = None,
-                 mesh=None, axis_name: Optional[str] = None, merge=None):
+                 store=None, snapshot_interval: int = 16):
         from repro.core.engine import CTEngine
-        from repro.core.executor import resolve_spec
+        from repro.core.executor import ensure_spec
         if engine is not None and cluster is not None:
             raise ValueError("pass engine= or cluster=, not both")
         if store is not None and (engine is not None or cluster is not None):
@@ -162,8 +158,7 @@ class CTSurrogate:
                 "store= applies to the surrogate's own engine; a shared "
                 "engine= / cluster= carries its own durability "
                 "(CTEngine(store=...) / CTCluster(durability_dir=...))")
-        spec = resolve_spec("CTSurrogate", spec, interpret=interpret,
-                            mesh=mesh, axis_name=axis_name, merge=merge)
+        spec = ensure_spec("CTSurrogate", spec)
         if cluster is not None:
             self._engine = cluster      # duck-typed CTEngine surface
         else:
@@ -182,10 +177,10 @@ class CTSurrogate:
         that never crashed.  Raises ``KeyError`` when the store holds no
         tenant ``name``."""
         from repro.core.engine import CTEngine
-        from repro.core.executor import resolve_spec
+        from repro.core.executor import ensure_spec
         engine = CTEngine(store=store, snapshot_interval=snapshot_interval)
         specs = None if spec is None \
-            else {name: resolve_spec("CTSurrogate", spec)}
+            else {name: ensure_spec("CTSurrogate", spec)}
         if engine.restore(store, names=[name], specs=specs).get(name) is None:
             raise KeyError(f"durable store holds no tenant {name!r}")
         self = cls.__new__(cls)

@@ -79,8 +79,7 @@ def make_serve_step(cfg: ModelConfig) -> Callable:
     return step
 
 
-def make_ct_step(scheme, *, interpret: bool | None = None,
-                 merge=None, spec=None) -> Callable:
+def make_ct_step(scheme, *, spec=None) -> Callable:
     """ONE jitted function for the whole CT communication phase:
     ``{ell: nodal}`` -> sparse-grid surplus on the common fine grid.
 
@@ -89,29 +88,21 @@ def make_ct_step(scheme, *, interpret: bool | None = None,
     executor's bucket plan and index maps are trace-time constants:
     re-calling with new grid VALUES never retraces (one jit cache entry
     per scheme shape signature).  ``spec`` (a ``repro.core.engine.
-    ExecSpec``) consolidates the execution policy — ``spec.merge`` opts
-    the bound plan into cost-model-driven bucket merging (fewer launches
-    per step, bit-identical surpluses); the bare ``interpret``/``merge``
-    kwargs remain as deprecation shims.  For steps DEDUPED across many
+    ExecSpec``) carries the execution policy — ``spec.merge`` opts the
+    bound plan into cost-model-driven bucket merging (fewer launches per
+    step, bit-identical surpluses).  For steps DEDUPED across many
     schemes by shape signature, serve through ``repro.core.engine.
     CTEngine`` instead — this helper compiles per scheme.
     """
-    from repro.core.executor import resolve_spec
-    spec = resolve_spec("make_ct_step", spec, interpret=interpret,
-                        merge=merge)
     return jax.jit(_bind_ct_transform(scheme, spec))
 
 
-def make_ct_eval_step(scheme, *, interpret: bool | None = None,
-                      merge=None, spec=None) -> Callable:
+def make_ct_eval_step(scheme, *, spec=None) -> Callable:
     """Jitted CT surrogate evaluation: ``({ell: nodal}, points (Q, d))`` ->
     combined-interpolant values (Q,) — transform + hierarchical-basis
     evaluation fused into one computation (the serving hot path).
-    ``spec``/legacy-kwarg semantics as in ``make_ct_step``."""
-    from repro.core.executor import resolve_spec
+    ``spec`` as in ``make_ct_step``."""
     from repro.core.interpolation import interpolate_hierarchical
-    spec = resolve_spec("make_ct_eval_step", spec, interpret=interpret,
-                        merge=merge)
     transform = _bind_ct_transform(scheme, spec)
 
     @jax.jit
@@ -126,14 +117,7 @@ def _bind_ct_transform(scheme, spec) -> Callable:
     constant — honoring the WHOLE spec: a meshed spec binds the
     slab-sharded multi-device gather (``repro.core.engine`` precedence
     rule 4), everything else the single-device plan gather."""
-    import dataclasses
     from repro.core.executor import build_plan, ct_transform_with_plan
     plan = build_plan(scheme, spec=spec)     # ShardedPlan when spec shards
-    if spec.mesh is not None:
-        from repro.core.distributed import ct_transform_sharded
-        inner = dataclasses.replace(spec, mesh=None)
-        return lambda nodal_grids: ct_transform_sharded(
-            nodal_grids, scheme, spec.mesh, spec.axis_name, plan=plan,
-            spec=inner)
-    return lambda nodal_grids: ct_transform_with_plan(
-        nodal_grids, plan, interpret=spec.interpret)
+    return lambda nodal_grids: ct_transform_with_plan(nodal_grids, plan,
+                                                      spec=spec)

@@ -16,6 +16,7 @@ from repro.core import combination as comb
 from repro.core.adaptive import (AdaptiveConfig, AdaptiveDriver,
                                  interpolation_error,
                                  make_anisotropic_target, nodal_sampler)
+from repro.core.engine import ExecSpec
 from repro.core.executor import (build_plan, ct_scatter, ct_transform,
                                  ct_transform_with_plan, extend_plan,
                                  update_plan_coefficients)
@@ -141,9 +142,6 @@ def test_executor_input_validation():
     del grids[(1, 2)]
     with pytest.raises(ValueError, match=r"\(1, 2\)"):
         ct_transform(grids, gs)
-    with pytest.raises(ValueError, match=r"\(1, 2\)"):
-        from repro.core.executor import ct_embedded
-        ct_embedded(grids, gs)
 
 
 def test_build_plan_cache_normalization():
@@ -360,7 +358,7 @@ def test_ct_surrogate_general_scheme_and_fault():
 
 @pytest.mark.multidevice
 def test_ct_surrogate_on_mesh_matches_single_device_and_fault():
-    """CTSurrogate with the opt-in ``mesh=`` runs the slab-sharded ingest:
+    """CTSurrogate under ``ExecSpec(mesh=)`` runs the slab-sharded ingest:
     queries, drop_grid (coefficient-only path) and post-fault updates all
     equal the single-device surrogate bit-for-bit."""
     from jax.sharding import AxisType
@@ -370,7 +368,7 @@ def test_ct_surrogate_on_mesh_matches_single_device_and_fault():
                                    close=True)
     u = lambda a, b: jnp.sin(2 * a) * (b - b * b)
     grids = {ell: sample_function(u, ell) for ell, _ in gs.grids}
-    srv = CTSurrogate(gs, grids, mesh=mesh)
+    srv = CTSurrogate(gs, grids, spec=ExecSpec(mesh=mesh))
     ref = CTSurrogate(gs, grids)
     pts = np.random.default_rng(8).random((32, 2))
     np.testing.assert_array_equal(np.asarray(srv.surplus),
@@ -403,7 +401,7 @@ def test_ct_surrogate_on_mesh_fault_fallback_path():
     grids = {ell: sample_function(u, ell) for ell, _ in gs.grids}
     pts = np.random.default_rng(9).random((32, 2))
 
-    srv = CTSurrogate(gs, grids, mesh=mesh)
+    srv = CTSurrogate(gs, grids, spec=ExecSpec(mesh=mesh))
     before = srv.query(pts)
     with pytest.raises(ValueError, match=r"\(1, 1\)"):
         srv.drop_grid([(2, 2)], grids)      # (1, 1) data not supplied

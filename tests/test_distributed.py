@@ -11,12 +11,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from jax.sharding import AxisType, set_mesh
 from repro.core import combination as comb
-from repro.core.distributed import (comm_phase_sharded, ct_transform_psum,
-                                    ct_transform_sharded,
-                                    hierarchize_sharded)
-from repro.core.executor import build_plan, ct_transform, shard_plan
+from repro.core.distributed import ct_transform_sharded
+from repro.core.executor import (build_plan, ct_scatter_with_plan,
+                                 ct_transform, shard_plan)
 from repro.core.levels import (CombinationScheme, GeneralScheme, grid_shape)
-from repro.kernels.ops import hierarchize
+from repro.kernels.ops import dehierarchize, hierarchize
 
 pytestmark = pytest.mark.multidevice
 
@@ -43,68 +42,32 @@ def _mesh8():
     return jax.make_mesh((8,), ("grid",), axis_types=(AxisType.Auto,))
 
 
-def test_sharded_hierarchization_matches_local():
-    mesh = _mesh8()
-    level0 = 5
-    x = np.random.default_rng(0).standard_normal((1 << level0, 15, 7))
-    x[-1] = 0.0
-    out = hierarchize_sharded(jnp.asarray(x), level0, mesh, "grid")
-    want = hierarchize(jnp.asarray(x[:-1]), "ref")
-    np.testing.assert_allclose(np.asarray(out)[:-1], np.asarray(want),
-                               rtol=1e-9, atol=1e-10)
-
-
-def test_distributed_comm_phase_matches_serial():
-    mesh = _mesh8()
-    scheme = CombinationScheme(2, 5)
-    rng = np.random.default_rng(1)
-    hier = {ell: hierarchize(jnp.asarray(
-        rng.standard_normal(grid_shape(ell))), "ref")
-        for ell, _ in scheme.grids}
-    combined = comb.gather_subspaces(hier, scheme)
-    want = comb.scatter_subspaces(combined, scheme)
-    got = comm_phase_sharded(hier, scheme, mesh, "grid")
-    for ell in got:
-        np.testing.assert_allclose(np.asarray(got[ell]),
-                                   np.asarray(want[ell]),
-                                   rtol=1e-8, atol=1e-9)
-
-
 def test_comm_phase_slab_sharded_matches_serial():
-    """The same comm phase through the slab-sharded gather (no
-    ``(G, *fine_shape)`` stack) == the psum realization == serial."""
+    """The whole comm phase on the mesh: the slab-sharded gather
+    (``ct_transform_sharded``, no ``(G, *fine_shape)`` stack) then the
+    plan scatter == the serial dict comm phase, grid by grid."""
     mesh = _mesh8()
     scheme = CombinationScheme(2, 5)
     rng = np.random.default_rng(1)
-    hier = {ell: hierarchize(jnp.asarray(
-        rng.standard_normal(grid_shape(ell))), "ref")
-        for ell, _ in scheme.grids}
+    grids = {ell: jnp.asarray(rng.standard_normal(grid_shape(ell)))
+             for ell, _ in scheme.grids}
+    hier = {ell: hierarchize(g, "ref") for ell, g in grids.items()}
     combined = comb.gather_subspaces(hier, scheme)
     want = comb.scatter_subspaces(combined, scheme)
     splan = shard_plan(build_plan(scheme), 8)
-    got = comm_phase_sharded(hier, scheme, mesh, "grid", sharded_plan=splan)
+    full = ct_transform_sharded(grids, scheme, mesh, "grid", plan=splan)
+    got = ct_scatter_with_plan(full, splan)
+    assert set(got) == set(want)
     for ell in got:
         np.testing.assert_allclose(np.asarray(got[ell]),
-                                   np.asarray(want[ell]),
+                                   np.asarray(dehierarchize(want[ell], "ref")),
                                    rtol=1e-8, atol=1e-9)
-
-
-def test_ct_transform_psum_matches_serial():
-    """Batched executor + psum gather == single-process ct_transform."""
-    mesh = _mesh8()
-    scheme = CombinationScheme(3, 4)
-    rng = np.random.default_rng(2)
-    grids = {ell: jnp.asarray(rng.standard_normal(grid_shape(ell)))
-             for ell, _ in scheme.grids}
-    want = ct_transform(grids, scheme)
-    got = ct_transform_psum(grids, scheme, mesh, "grid")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-12, atol=1e-12)
 
 
 def test_ct_transform_psum_general_scheme():
     """The distributed gather accepts a GeneralScheme (adaptive index set)
-    unchanged: psum path == single-process executor path."""
+    unchanged: slab-sharded path == single-process executor path,
+    bit for bit."""
     mesh = _mesh8()
     scheme = GeneralScheme.from_levels(
         [(5, 1, 1), (3, 3, 1), (2, 2, 2), (1, 4, 1)], close=True)
@@ -112,22 +75,7 @@ def test_ct_transform_psum_general_scheme():
     grids = {ell: jnp.asarray(rng.standard_normal(grid_shape(ell)))
              for ell, _ in scheme.grids}
     want = ct_transform(grids, scheme)
-    got = ct_transform_psum(grids, scheme, mesh, "grid")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-12, atol=1e-12)
-
-
-def test_ct_transform_sharded_through_psum_entry_point():
-    """``ct_transform_psum(..., sharded_plan=)`` routes through the
-    slab-sharded gather and is bit-identical to the serial transform."""
-    mesh = _mesh8()
-    scheme = CombinationScheme(3, 4)
-    rng = np.random.default_rng(2)
-    grids = {ell: jnp.asarray(rng.standard_normal(grid_shape(ell)))
-             for ell, _ in scheme.grids}
-    splan = shard_plan(build_plan(scheme), 8)
-    want = ct_transform(grids, scheme)
-    got = ct_transform_psum(grids, scheme, mesh, "grid", sharded_plan=splan)
+    got = ct_transform_sharded(grids, scheme, mesh, "grid")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -141,7 +89,7 @@ def test_ct_transform_sharded_keeps_sharding():
              for ell, _ in scheme.grids}
     splan = shard_plan(build_plan(scheme), 8)
     out = ct_transform_sharded(grids, scheme, mesh, "grid",
-                               sharded_plan=splan, gather=False)
+                               plan=splan, gather=False)
     assert out.shape[0] == 8 * splan.slab_rows
     assert isinstance(out.sharding, NamedSharding)
     assert out.sharding.spec[0] == "grid"

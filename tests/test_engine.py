@@ -1,7 +1,8 @@
 """CTEngine / ExecSpec front door: compile-cache sharing across tenants,
 continuous-batching query coalescing, multi-tenant bit-identity against
 the per-scheme executor + dict oracle, lifecycle routing through the
-incremental plan paths, and the legacy-kwarg deprecation shims.
+incremental plan paths, and the one ``spec=`` spelling of the
+execution policy at every entry point.
 """
 
 import warnings
@@ -43,7 +44,6 @@ def _random_grids(scheme, rng, dtype=np.float64):
 def _fresh_caches():
     """Deterministic compile-cache counters per test."""
     clear_compile_cache()
-    E.reset_deprecation_warnings()
     yield
 
 
@@ -68,13 +68,6 @@ def test_execspec_is_hashable_and_plan_constructor():
     assert s1 == s2 and hash(s1) == hash(s2)
     scheme = CombinationScheme(2, 3)
     assert s1.plan(scheme) is build_plan(scheme, merge=MergeConfig())
-
-
-def test_spec_plus_legacy_kwarg_conflict_raises():
-    scheme = CombinationScheme(2, 3)
-    grids = _random_grids(scheme, np.random.default_rng(0))
-    with pytest.raises(ValueError, match="not both"):
-        ct_transform(grids, scheme, spec=ExecSpec(), merge=MergeConfig())
 
 
 # ---------------------------------------------------------------------------
@@ -313,7 +306,7 @@ def test_positional_non_spec_raises_named_type_error():
     scheme = CombinationScheme(2, 3)
     grids = _random_grids(scheme, np.random.default_rng(78))
     from repro.launch.serve import CTSurrogate
-    with pytest.raises(TypeError, match="ExecSpec.*interpret"):
+    with pytest.raises(TypeError, match=r"spec=ExecSpec\(interpret"):
         CTSurrogate(scheme, grids, True)
     with pytest.raises(TypeError, match="ExecSpec"):
         ct_transform(grids, scheme, spec=True)
@@ -369,15 +362,12 @@ def test_ingest_executable_cache_is_lru_bounded():
 def test_adaptive_driver_spec_config_conflict_raises():
     from repro.core.adaptive import AdaptiveConfig, AdaptiveDriver
     solver = lambda ell: np.zeros(grid_shape(ell))
-    with pytest.raises(ValueError, match="ONE place"):
-        AdaptiveDriver(solver, dim=2,
-                       config=AdaptiveConfig(merge=MergeConfig()),
-                       spec=ExecSpec())
     with pytest.raises(ValueError, match="CTEngine instead"):
         AdaptiveDriver(solver, dim=2, spec=ExecSpec(n_slabs=4))
-    # non-conflicting spec is applied
-    drv = AdaptiveDriver(solver, dim=2, spec=ExecSpec(merge=MergeConfig()))
-    assert drv.config.merge == MergeConfig()
+    # a single-device spec is applied; the budget config is separate
+    drv = AdaptiveDriver(solver, dim=2, config=AdaptiveConfig(),
+                         spec=ExecSpec(merge=MergeConfig()))
+    assert drv.spec.merge == MergeConfig()
     assert drv.plan.merge == MergeConfig()
 
 
@@ -493,96 +483,63 @@ def test_query_point_validation_named_errors():
 
 
 # ---------------------------------------------------------------------------
-# Deprecation shims: every legacy kwarg keeps working, warns ONCE
+# One spelling: every front door takes its execution policy as spec= only
 # ---------------------------------------------------------------------------
 
-def _deprecations(w):
-    return [x for x in w if issubclass(x.category, DeprecationWarning)]
-
-
-def test_legacy_kwargs_warn_once_and_match_spec():
-    from repro.launch.steps import make_ct_step
-    scheme = CombinationScheme(2, 4)
-    grids = _random_grids(scheme, np.random.default_rng(13))
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        legacy = ct_transform(grids, scheme, merge=MergeConfig())
-        legacy2 = ct_transform(grids, scheme, merge=MergeConfig())
-        assert len(_deprecations(w)) == 1          # once per call site family
-    spec_way = ct_transform(grids, scheme, spec=ExecSpec(merge=MergeConfig()))
-    np.testing.assert_array_equal(np.asarray(legacy), np.asarray(spec_way))
-    np.testing.assert_array_equal(np.asarray(legacy2), np.asarray(spec_way))
-
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        step = make_ct_step(scheme, interpret=True)
-        assert len(_deprecations(w)) == 1
-    np.testing.assert_array_equal(
-        np.asarray(step(grids)),
-        np.asarray(make_ct_step(scheme, spec=ExecSpec(interpret=True))(grids)))
-
-    # distinct call-site families warn independently
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        ct_transform(grids, scheme, interpret=True)
-        assert len(_deprecations(w)) == 1          # (ct_transform, interpret)
-
-
-def test_legacy_surrogate_kwargs_warn_once():
-    from repro.launch.serve import CTSurrogate
-    scheme = CombinationScheme(2, 3)
-    grids = _random_grids(scheme, np.random.default_rng(14))
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        a = CTSurrogate(scheme, grids, merge=MergeConfig())
-        b = CTSurrogate(scheme, grids, merge=MergeConfig())
-        assert len(_deprecations(w)) == 1
-    spec_way = CTSurrogate(scheme, grids,
-                           ExecSpec(merge=MergeConfig()))
-    pts = np.random.default_rng(140).random((16, 2))
-    np.testing.assert_array_equal(a.query(pts), spec_way.query(pts))
-    np.testing.assert_array_equal(b.query(pts), spec_way.query(pts))
-
-
-@pytest.mark.multidevice
-def test_legacy_mesh_and_sharded_plan_kwargs_warn_once():
+def _front_door(name):
+    """Entry point ``name`` bound to a small valid input; the returned
+    callable forwards its keywords to the call."""
     from jax.sharding import AxisType
+    from repro.core.adaptive import AdaptiveConfig
     from repro.core.distributed import ct_transform_sharded
-    from repro.core.executor import shard_plan
+    from repro.core.executor import (ct_scatter, ct_transform_with_plan,
+                                     shard_plan)
     from repro.launch.serve import CTSurrogate
-    mesh = jax.make_mesh((8,), ("slab",), axis_types=(AxisType.Auto,))
-    scheme = GeneralScheme.regular(2, 4)
+    from repro.launch.steps import make_ct_eval_step, make_ct_step
+    scheme = CombinationScheme(2, 3)
     grids = _random_grids(scheme, np.random.default_rng(15))
-    splan = shard_plan(build_plan(scheme), 8)
+    mesh = jax.make_mesh((1,), ("slab",), devices=jax.devices()[:1],
+                         axis_types=(AxisType.Auto,))
+    return {
+        "ct_transform": lambda **kw: ct_transform(grids, scheme, **kw),
+        "ct_scatter": lambda **kw: ct_scatter(
+            jnp.zeros(build_plan(scheme).fine_shape), scheme, **kw),
+        "ct_transform_with_plan": lambda **kw: ct_transform_with_plan(
+            grids, build_plan(scheme), **kw),
+        "ct_transform_sharded": lambda **kw: ct_transform_sharded(
+            grids, scheme, mesh, "slab", **kw),
+        "make_ct_step": lambda **kw: make_ct_step(scheme, **kw),
+        "make_ct_eval_step": lambda **kw: make_ct_eval_step(scheme, **kw),
+        "CTSurrogate": lambda **kw: CTSurrogate(scheme, grids, **kw),
+        "AdaptiveConfig": lambda **kw: AdaptiveConfig(**kw),
+    }[name], mesh, shard_plan(build_plan(scheme), 1)
 
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        srv = CTSurrogate(scheme, grids, mesh=mesh)
-        CTSurrogate(scheme, grids, mesh=mesh)
-        assert len(_deprecations(w)) == 1
-    ref = CTSurrogate(scheme, grids, ExecSpec(mesh=mesh))
-    np.testing.assert_array_equal(np.asarray(srv.surplus),
-                                  np.asarray(ref.surplus))
 
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        legacy = ct_transform_sharded(grids, scheme, mesh, "slab",
-                                      sharded_plan=splan)
-        ct_transform_sharded(grids, scheme, mesh, "slab",
-                             sharded_plan=splan)
-        assert len(_deprecations(w)) == 1
-    new = ct_transform_sharded(grids, scheme, mesh, "slab", plan=splan)
-    np.testing.assert_array_equal(np.asarray(legacy), np.asarray(new))
+#: (entry point, keyword) pairs whose option lives only in ExecSpec
+_SPEC_ONLY = [("ct_transform", "interpret"), ("ct_transform", "merge"),
+              ("ct_scatter", "interpret"), ("ct_scatter", "merge"),
+              ("ct_transform_with_plan", "interpret"),
+              ("ct_transform_sharded", "interpret"),
+              ("ct_transform_sharded", "sharded_plan"),
+              ("make_ct_step", "interpret"), ("make_ct_step", "merge"),
+              ("make_ct_eval_step", "interpret"),
+              ("make_ct_eval_step", "merge"),
+              ("CTSurrogate", "interpret"), ("CTSurrogate", "mesh"),
+              ("CTSurrogate", "axis_name"), ("CTSurrogate", "merge"),
+              ("AdaptiveConfig", "interpret"), ("AdaptiveConfig", "merge")]
 
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        interp_legacy = ct_transform_sharded(grids, scheme, mesh, "slab",
-                                             interpret=True)
-        assert len(_deprecations(w)) == 1
-    np.testing.assert_array_equal(
-        np.asarray(interp_legacy),
-        np.asarray(ct_transform_sharded(grids, scheme, mesh, "slab",
-                                        spec=ExecSpec(interpret=True))))
+
+@pytest.mark.parametrize("entry,kw", _SPEC_ONLY,
+                         ids=[f"{e}-{k}" for e, k in _SPEC_ONLY])
+def test_execution_keyword_outside_spec_raises(entry, kw):
+    """An execution option passed beside ``spec=`` is refused at the call:
+    the option exists only as an ``ExecSpec`` field."""
+    call, mesh, splan = _front_door(entry)
+    value = {"interpret": True, "merge": MergeConfig(), "mesh": mesh,
+             "axis_name": "slab", "sharded_plan": splan}[kw]
+    with pytest.raises(TypeError,
+                       match=f"unexpected keyword argument '{kw}'"):
+        call(**{kw: value})
 
 
 @pytest.mark.multidevice
@@ -624,18 +581,6 @@ def test_meshed_spec_routes_ct_transform_and_engine_shares_executable():
                                   np.asarray(ct_transform(ga, scheme)))
     np.testing.assert_array_equal(np.asarray(eng.surplus("b")),
                                   np.asarray(ct_transform(gb, scheme)))
-
-    # comm_phase_sharded accepts the same spec (builds the sharded plan)
-    from repro.core.distributed import comm_phase_sharded
-    from repro.core.hierarchize import hierarchize
-    hier = {ell: hierarchize(u) for ell, u in ga.items()}
-    got = comm_phase_sharded(hier, scheme, mesh, "slab",
-                             spec=ExecSpec(n_slabs=8))
-    want = comm_phase_sharded(hier, scheme, mesh, "slab")
-    for ell in want:
-        np.testing.assert_allclose(np.asarray(got[ell]),
-                                   np.asarray(want[ell]),
-                                   rtol=0, atol=1e-12)
 
 
 # ---------------------------------------------------------------------------

@@ -3,15 +3,13 @@
 Every test here hammers the engine (or the process-global caches) from
 many threads and asserts the serving contract holds: no dropped or hung
 futures, exact cache accounting, bit-identical results to a
-single-threaded replay, warn-once semantics under contention.  The tier
-runs in its own CI job (``pytest -m threaded``) with
-``PYTHONFAULTHANDLER=1`` so a deadlock dumps stacks instead of timing
-out silently.
+single-threaded replay.  The tier runs in its own CI job
+(``pytest -m threaded``) with ``PYTHONFAULTHANDLER=1`` so a deadlock
+dumps stacks instead of timing out silently.
 """
 
 import threading
 import time
-import warnings
 from concurrent.futures import ThreadPoolExecutor
 
 import jax.numpy as jnp
@@ -19,7 +17,6 @@ import numpy as np
 import pytest
 
 from repro.core import engine as E
-from repro.core import executor as X
 from repro.core.engine import CTEngine, clear_compile_cache, plan_signature
 from repro.core.executor import build_plan, clear_plan_cache
 from repro.core.levels import CombinationScheme, GeneralScheme, grid_shape
@@ -34,7 +31,6 @@ RESULT_TIMEOUT = 120.0
 def _fresh_caches():
     clear_compile_cache()
     clear_plan_cache()
-    E.reset_deprecation_warnings()
     yield
 
 
@@ -346,28 +342,6 @@ def test_refit_racing_queued_ingests_commits_consistently():
             pass
     surp = eng.surplus("t")
     assert np.all(np.isfinite(np.asarray(surp)))
-
-
-# ---------------------------------------------------------------------------
-# Satellite: warn-once deprecation state under threads
-# ---------------------------------------------------------------------------
-
-def test_legacy_warning_fires_once_per_family_under_threads():
-    E.reset_deprecation_warnings()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        _run_threads([
-            (lambda: [X.warn_legacy_kwargs("stress_fn", ["mesh"])
-                      for _ in range(100)])
-            for _ in range(N_THREADS)])
-        deps = [x for x in w if issubclass(x.category, DeprecationWarning)]
-        assert len(deps) == 1, \
-            f"warn-once family fired {len(deps)} times under threads"
-        # reset re-arms exactly once more
-        E.reset_deprecation_warnings()
-        X.warn_legacy_kwargs("stress_fn", ["mesh"])
-        deps = [x for x in w if issubclass(x.category, DeprecationWarning)]
-        assert len(deps) == 2
 
 
 # ---------------------------------------------------------------------------

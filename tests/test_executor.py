@@ -12,8 +12,7 @@ import pytest
 from proptest import cases, integers, seeds
 
 from repro.core import combination as comb
-from repro.core.executor import (build_plan, ct_embedded, ct_scatter,
-                                 ct_transform)
+from repro.core.executor import build_plan, ct_scatter, ct_transform
 from repro.core.levels import (CombinationScheme, LevelVector,
                                canonical_levels, grid_shape)
 from repro.kernels.hierarchize import (hierarchize_batched,
@@ -67,26 +66,6 @@ def test_ct_scatter_matches_dict_path(dim, level):
         np.testing.assert_allclose(np.asarray(got[ell]),
                                    np.asarray(want[ell]),
                                    rtol=1e-11, atol=1e-12)
-
-
-def test_ct_embedded_matches_embed_loop():
-    """Unweighted per-grid embedded surpluses == embed_to_full per grid,
-    and their coefficient-weighted sum == ct_transform."""
-    scheme = CombinationScheme(3, 3)
-    grids = _random_grids(scheme, np.random.default_rng(2))
-    embedded, coeffs, order = ct_embedded(grids, scheme)
-    assert embedded.shape[0] == len(order) == len(scheme.grids)
-    full_levels = build_plan(scheme).full_levels
-    for g, ell in enumerate(order):
-        want = comb.embed_to_full(hierarchize(grids[ell], "ref"), ell,
-                                  full_levels)
-        np.testing.assert_allclose(np.asarray(embedded[g]), np.asarray(want),
-                                   rtol=1e-12, atol=1e-12)
-    via_sum = jnp.tensordot(coeffs.astype(embedded.dtype), embedded,
-                            axes=[[0], [0]])
-    np.testing.assert_allclose(np.asarray(via_sum),
-                               np.asarray(ct_transform(grids, scheme)),
-                               rtol=1e-12, atol=1e-12)
 
 
 @pytest.mark.slow

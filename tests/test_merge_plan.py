@@ -10,8 +10,7 @@ Three layers:
   (b) seeded end-to-end property tests of below-target (padded) bucket
       members: merged ``ct_transform`` bit-identical (f64; 1e-6 at f32)
       to the unmerged path over random downward-closed schemes,
-      ``ct_scatter`` / ``ct_embedded`` through merged plans against the
-      unmerged oracle.
+      ``ct_scatter`` through merged plans against the unmerged oracle.
   (c) the sharded gather through merged and Pallas-path plans with
       per-slab local maps (multidevice tier).
 """
@@ -23,7 +22,7 @@ import pytest
 from proptest import cases, integers, seeds
 
 from repro.core.executor import (MergeConfig, build_plan, bucket_surpluses,
-                                 ct_embedded_with_plan, ct_scatter_with_plan,
+                                 ct_scatter_with_plan,
                                  ct_transform, ct_transform_with_plan,
                                  extend_plan, plan_launch_stats, shard_plan,
                                  update_plan_coefficients)
@@ -237,24 +236,6 @@ def test_merged_scatter_matches_unmerged(dim, level):
                                    rtol=1e-12, atol=1e-12)
 
 
-def test_merged_embedded_matches_unmerged():
-    """The vectorized member-axis embed: per-grid embedded surpluses off a
-    merged plan (pads -> dump) == the unmerged plan's, grid for grid."""
-    scheme = CombinationScheme(3, 3)
-    grids = _random_grids(scheme, np.random.default_rng(2))
-    e0, c0, o0 = ct_embedded_with_plan(grids, build_plan(scheme))
-    e1, c1, o1 = ct_embedded_with_plan(grids,
-                                       build_plan(scheme, merge=AGGRESSIVE))
-    g0 = {ell: np.asarray(e0[i]) for i, ell in enumerate(o0)}
-    g1 = {ell: np.asarray(e1[i]) for i, ell in enumerate(o1)}
-    cc0 = {ell: c0[i] for i, ell in enumerate(o0)}
-    cc1 = {ell: c1[i] for i, ell in enumerate(o1)}
-    assert set(g0) == set(g1)
-    for ell in g0:
-        assert cc0[ell] == cc1[ell]
-        np.testing.assert_array_equal(g0[ell], g1[ell])
-
-
 #: a near-square scheme whose every bucket takes the Pallas path
 _PALLAS_SCHEME = GeneralScheme.from_levels([(6, 5), (5, 6)], close=True)
 
@@ -317,7 +298,7 @@ def test_sharded_gather_merged_plan_matches_single_device(dim, steps,
     splan = shard_plan(build_plan(gs, merge=AGGRESSIVE), n_groups)
     want = np.asarray(ct_transform(grids, gs))
     got = np.asarray(ct_transform_sharded(grids, gs, _mesh(n_groups), "slab",
-                                          sharded_plan=splan))
+                                          plan=splan))
     np.testing.assert_array_equal(got, want)
 
 
